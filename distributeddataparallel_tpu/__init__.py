@@ -26,11 +26,6 @@ the reference's usage (``python dpp.py``) with a ``--device`` selector.
 
 __version__ = "0.1.0"
 
-# Must run before any submodule touches jax.shard_map / lax.axis_size:
-# bridges this environment's jax 0.4.37 to the API level the framework
-# targets (no-op on newer jax).
-import distributeddataparallel_tpu.compat  # noqa: F401  isort: skip
-
 from distributeddataparallel_tpu.runtime.distributed import (  # noqa: F401
     init_process_group,
     destroy_process_group,

@@ -1,89 +1,16 @@
-"""jax version compatibility shims (installed by the package __init__).
-
-The framework targets the current jax public API; this container pins
-jax 0.4.37, where three of those surfaces don't exist yet.  Per the
-repo's no-new-deps rule the gap is bridged here, in one place, instead
-of scattering version branches through every call site:
-
-- ``jax.shard_map`` — public alias landed after 0.4.37; the same
-  function lives at ``jax.experimental.shard_map.shard_map`` with the
-  replication-check kwarg under its old name (``check_rep``, later
-  renamed ``check_vma`` with the varying-manual-axes rework).  The shim
-  adapts the new-style call (keyword mesh/specs, ``check_vma=``) onto
-  the experimental entry point.
-- ``lax.axis_size`` — newer trace-time axis-size lookup; 0.4.37 exposes
-  the same fact through the axis env (``get_axis_env().axis_size``),
-  still static at trace time, which is what the bucketed all-reduce's
-  static mean divisor depends on.
-- ``jax.tree.flatten_with_path`` — the ``jax.tree`` namespace predates
-  its path variants here; ``jax.tree_util.tree_flatten_with_path`` is
-  the same function.
-
-Each shim is gated on ``hasattr``, so on a newer jax this module is a
-no-op and the native implementations win.
-"""
+"""CPU device-count configuration for tests and ``--fake-devices``."""
 
 from __future__ import annotations
 
 import jax
-from jax import lax
-
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-
-    jax.shard_map = shard_map
-
-if not hasattr(lax, "axis_size"):
-
-    def axis_size(axis_name):
-        from jax._src.core import get_axis_env
-
-        names = (
-            axis_name if isinstance(axis_name, (tuple, list))
-            else (axis_name,)
-        )
-        size = 1
-        for name in names:
-            size *= get_axis_env().axis_size(name)
-        return size
-
-    lax.axis_size = axis_size
-
-if not hasattr(jax.tree, "flatten_with_path"):
-    jax.tree.flatten_with_path = jax.tree_util.tree_flatten_with_path
 
 
 def configure_cpu_devices(n: int) -> None:
-    """Force ``n`` fake CPU devices, portable across jax versions.
+    """Run this process on ``n`` virtual CPU devices.
 
-    Newer jax has the ``jax_num_cpu_devices`` config option; 0.4.37 only
-    honors the pre-backend-init XLA flag.  Either way this must run
-    before the first device query creates the CPU client (the callers —
-    conftest, ``dpp.py --device cpu``, spawned test workers — all run it
-    at interpreter startup).
+    Must run before the first device query creates the CPU client (the
+    callers — conftest, ``dpp.py --fake-devices``, spawned test workers —
+    all run it at interpreter startup).
     """
-    import os
-    import re
-
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        # REPLACE any inherited count rather than keeping it: a child
-        # process asking for 4 devices under a parent that exported 8
-        # (the elastic-resume tests) must win.
-        flags = re.sub(
-            r"--xla_force_host_platform_device_count=\d+",
-            "",
-            os.environ.get("XLA_FLAGS", ""),
-        )
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}"
-        ).strip()
+    jax.config.update("jax_num_cpu_devices", n)

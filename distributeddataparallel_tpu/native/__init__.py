@@ -6,15 +6,20 @@ copies; this package is the TPU framework's equivalent native layer
 float32 transform, CHW→HWC layout conversion, and DDP-style gradient
 bucket planning.
 
-The library is compiled on first use with the repo's Makefile (g++).
+The library is compiled on first use with the repo's Makefile (g++),
+into a file named after the hash of its sources: a library is only ever
+loaded under the name of the ``ddp_native.cpp`` + ``Makefile`` that are
+on disk, so a stale or foreign ``.so`` (the file is ignored by git and
+travels with copies of the tree) is never picked up — it is rebuilt.
 Everything here degrades gracefully: ``available()`` is False when the
-toolchain or .so is missing and callers fall back to NumPy — features
-never depend on native code, only speed does.
+toolchain is missing and callers fall back to NumPy — features never
+depend on native code, only speed does.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,7 +30,20 @@ _CSRC = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "csrc",
 )
-_SO = os.path.join(_CSRC, "libddp_native.so")
+
+
+def _source_hash() -> str:
+    h = hashlib.sha256()
+    for name in ("ddp_native.cpp", "Makefile"):
+        try:
+            with open(os.path.join(_CSRC, name), "rb") as fh:
+                h.update(fh.read())
+        except OSError:
+            return "nosource"
+    return h.hexdigest()[:16]
+
+
+_SO = os.path.join(_CSRC, f"libddp_native.{_source_hash()}.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -46,10 +64,9 @@ def _log_build_failure(stderr: str) -> None:
 
 
 def _build() -> bool:
-    src = os.path.join(_CSRC, "ddp_native.cpp")
-    if not os.path.exists(src):
+    if not os.path.exists(os.path.join(_CSRC, "ddp_native.cpp")):
         return False
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(src):
+    if os.path.exists(_SO):
         return True
     # Build to a private temp name, then atomically rename into place —
     # concurrent builders can't see a half-written .so, and an interrupted

@@ -5,8 +5,8 @@ Three complementary views of where the bytes went:
 
 - ``device_memory_stats()`` — the runtime allocator's own accounting
   (``device.memory_stats()``: bytes_in_use, peak_bytes_in_use, ...).
-  TPU backends report it; the CPU backend returns None and the caller
-  degrades to the live-array view.
+  TPU backends report it (its absence there is an error); the CPU
+  backend returns None and the caller degrades to the live-array view.
 - ``executable_memory_analysis()`` — the compiler's static budget for
   one executable (argument/output/temp/code bytes from
   ``compiled.memory_analysis()``): how much HBM the step NEEDS, known
@@ -26,23 +26,25 @@ from __future__ import annotations
 
 
 def device_memory_stats(devices=None) -> list[dict] | None:
-    """Per-device allocator stats for the process-local devices, or None
-    when the backend doesn't report them (CPU).  Keys are normalized to
-    the ones every consumer needs; the raw dict is not exposed so a
-    backend adding fields can't bloat every event record."""
+    """Per-device allocator stats for the process-local devices.  None
+    only on the CPU backend, which reports none; on an accelerator a
+    missing ``memory_stats()`` raises — memory numbers must not silently
+    become live-array estimates there.  Keys are normalized to the ones
+    every consumer needs; the raw dict is not exposed so a backend
+    adding fields can't bloat every event record."""
     import jax
 
     devices = devices if devices is not None else jax.local_devices()
     out = []
     for d in devices:
-        try:
-            stats = d.memory_stats()
-        # ddplint: allow[broad-except] — memory_stats raises (not just
-        # returns None) on some PJRT plugins; telemetry must degrade
-        except Exception:
-            stats = None
+        stats = d.memory_stats()
         if not stats:
-            return None
+            if d.platform == "cpu":
+                return None
+            raise RuntimeError(
+                f"{d.platform} device {d.id} ({d.device_kind}) reports no "
+                "memory_stats()"
+            )
         out.append({
             "device": d.id,
             "bytes_in_use": int(stats.get("bytes_in_use", 0)),
@@ -164,15 +166,18 @@ class MemoryTelemetry:
         self.live_perdevice_hwm_bytes = 0
         self.device_peak_bytes = 0
 
-    def note_executable(self, compiled, *, label: str = "train_step"):
+    def note_executable(
+        self, compiled, *, label: str = "train_step", **facts
+    ):
         """Record one executable's compiler memory budget (emits a
-        single ``exec_memory`` event); safe to call with anything —
+        single ``exec_memory`` event, with any other ``facts`` the caller
+        read off the same executable); safe to call with anything —
         backends without the API degrade to a no-op."""
         analysis = executable_memory_analysis(compiled)
         if analysis is None:
             return None
         if self.events is not None:
-            self.events.emit("exec_memory", label=label, **analysis)
+            self.events.emit("exec_memory", label=label, **analysis, **facts)
         if self.registry is not None:
             self.registry.gauge("exec_temp_bytes").set(
                 analysis.get("temp_bytes")
@@ -203,6 +208,11 @@ class MemoryTelemetry:
             self.device_peak_bytes = max(self.device_peak_bytes, peak)
             out["device_bytes_in_use"] = in_use
             out["device_peak_bytes"] = self.device_peak_bytes
+            # One entry per device: a replica that never ran shows up
+            # as a zero here and nowhere in the max above.
+            out["device_peak_bytes_each"] = [
+                s["peak_bytes_in_use"] for s in stats
+            ]
         if self.registry is not None:
             g = self.registry.gauge
             g("mem_live_bytes").set(live)

@@ -122,56 +122,6 @@ def dot_product_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
-#: (backend, shapes, dtype, causal) -> bool: did the flash kernel's full
-#: fwd+bwd lower AND compile?  Populated once per shape by `_flash_compiles`.
-_FLASH_COMPILE_CACHE: dict = {}
-
-
-def _flash_compiles(q, k, v, causal: bool) -> bool:
-    """Probe-compile the flash kernel (fwd+bwd) for these abstract shapes.
-
-    Under jit the kernel's failures surface at Mosaic lowering/compile
-    time, *outside* any try/except around the traced call — so 'auto'
-    must prove compilability ahead of time.  The probe runs once per
-    (backend, shape, dtype, causal) and is cached; q/k/v may be tracers
-    (only .shape/.dtype are read).
-    """
-    key = (
-        jax.default_backend(), q.shape, k.shape, v.shape,
-        jnp.dtype(q.dtype).name, causal,
-    )
-    hit = _FLASH_COMPILE_CACHE.get(key)
-    if hit is None:
-        from distributeddataparallel_tpu.ops import pallas_attention
-
-        def probe(q, k, v):
-            out, vjp = jax.vjp(
-                lambda q, k, v: pallas_attention.flash_attention(
-                    q, k, v, causal
-                ),
-                q, k, v,
-            )
-            return out, vjp(out)
-
-        avals = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)]
-        try:
-            jax.jit(probe).lower(*avals).compile()
-            hit = True
-        # ddplint: allow[broad-except] — compile probe: any failure means
-        # "no pallas here", fall back to the XLA path
-        except Exception:
-            import logging
-
-            logging.getLogger("ddp_tpu").warning(
-                "pallas flash attention failed to compile for q=%s kv=%s "
-                "on %s; using the O(S^2) XLA path (perf/memory hit)",
-                q.shape, k.shape, jax.default_backend(), exc_info=True,
-            )
-            hit = False
-        _FLASH_COMPILE_CACHE[key] = hit
-    return hit
-
-
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -182,12 +132,12 @@ def attention(
 ) -> jnp.ndarray:
     """Dispatch: 'xla' reference, 'pallas' flash kernel, or 'auto'.
 
-    'auto' uses the Pallas flash kernel on TPU when shapes are
-    block-aligned AND a one-time probe compile of the kernel (fwd+bwd)
-    succeeds for these shapes — compile failures therefore fall back to
-    XLA instead of aborting the jit (they are not catchable around the
-    traced call itself).  'pallas' forces the kernel and lets failures
-    propagate.
+    'auto' uses the Pallas flash kernel on TPU whenever the shapes are
+    ``supported()`` and the XLA reference otherwise; the choice is made
+    from backend and shapes alone, so a kernel that fails to compile
+    fails the jit — it never quietly becomes the O(S^2) path.  'pallas'
+    additionally rejects unsupported shapes; 'xla' is the explicit way
+    to ask for the reference.
 
     GQA: k/v may carry fewer heads than q (H % Hkv == 0).  The flash
     kernel consumes them natively (the shared kv head is indexed per
@@ -200,9 +150,8 @@ def attention(
         from distributeddataparallel_tpu.ops import pallas_attention
 
         if pallas_attention.supported(q, k, v):
-            if impl == "pallas" or _flash_compiles(q, k, v, causal):
-                return pallas_attention.flash_attention(q, k, v, causal=causal)
-        elif impl == "pallas":
+            return pallas_attention.flash_attention(q, k, v, causal=causal)
+        if impl == "pallas":
             raise ValueError(
                 f"pallas flash attention unsupported for shapes "
                 f"q={q.shape} k={k.shape} on {jax.default_backend()}"

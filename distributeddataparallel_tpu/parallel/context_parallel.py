@@ -175,25 +175,18 @@ def ring_attention(
     order) to slicing full attention over the gathered sequence.
 
     ``impl``: 'auto' uses the Pallas flash kernel per kv-hop
-    (``flash_ring_attention``) when the local shapes support it and the
-    kernel probe-compiles, 'pallas' forces it, 'xla' keeps the einsum
-    blocks below.  Only causal attention takes the kernel path (the
-    ring's wrap masking assumes it).
+    (``flash_ring_attention``) whenever the local shapes support it (a
+    kernel compile failure fails the jit), 'pallas' also rejects
+    unsupported shapes, 'xla' keeps the einsum blocks below.  Only
+    causal attention takes the kernel path (the ring's wrap masking
+    assumes it).
     """
     if impl in ("auto", "pallas") and causal:
-        from distributeddataparallel_tpu.ops.attention import _flash_compiles
         from distributeddataparallel_tpu.ops.pallas_attention import supported
 
         if supported(q, k, v) and k.shape[2] == q.shape[2]:
-            # Probe BOTH causal variants: hop 0 runs the causal kernels,
-            # every later hop the non-causal ones — a shape passing only
-            # the causal probe would still die at jit time in the ring.
-            if impl == "pallas" or (
-                _flash_compiles(q, k, v, True)
-                and _flash_compiles(q, k, v, False)
-            ):
-                return flash_ring_attention(q, k, v, axis_name)
-        elif impl == "pallas":
+            return flash_ring_attention(q, k, v, axis_name)
+        if impl == "pallas":
             raise ValueError(
                 f"pallas ring attention unsupported for shapes "
                 f"q={q.shape} kv={k.shape} on {jax.default_backend()}"

@@ -471,67 +471,6 @@ def cycles_by_scope(
     }
 
 
-_TPU_TOPOLOGY_PROBE: dict[str, bool] = {}
-
-
-def _probe_tpu_topology(topology: str, timeout_s: float = 20.0) -> None:
-    """Raise unless TPU AOT topology init is known to complete.
-
-    On a host with the TPU PJRT plugin installed but no TPU runtime,
-    ``get_topology_desc`` can block forever inside the plugin's C++
-    initialization (a retry loop the Python caller cannot interrupt)
-    instead of raising.  Probing in a throwaway subprocess under a
-    deadline converts that wedge into the prompt ``RuntimeError`` every
-    caller's degrade path already handles.  The verdict is cached per
-    topology string, so a process pays for the probe at most once.
-    """
-    if topology not in _TPU_TOPOLOGY_PROBE:
-        import os
-        import subprocess
-        import sys
-
-        # Scrub the child env: a supervised gang worker carries
-        # distributed-init vars (JAX_COORDINATOR_ADDRESS & co) and chaos
-        # wiring that the probe must not inherit — the throwaway child
-        # would block rendezvousing with a gang it isn't part of, and
-        # the 20s deadline would misread "waiting on a coordinator" as
-        # "plugin wedged".
-        child_env = {
-            k: v for k, v in os.environ.items()
-            if k not in (
-                "JAX_COORDINATOR_ADDRESS", "JAX_NUM_PROCESSES",
-                "JAX_PROCESS_ID", "CLOUD_TPU_TASK_ID", "TPU_WORKER_ID",
-            ) and not k.startswith("DDP_")
-        }
-        # Exit sentinel 3 = "plugin raised cleanly" (no TPU runtime /
-        # no plugin): an expected skip, unlike a crash or a wedge.
-        code = (
-            "import sys\n"
-            "try:\n"
-            "    from jax.experimental.topologies import "
-            "get_topology_desc\n"
-            f"    get_topology_desc(platform='tpu', "
-            f"topology_name={topology!r})\n"
-            "except Exception:\n"
-            "    sys.exit(3)\n"
-        )
-        try:
-            res = subprocess.run(
-                [sys.executable, "-c", code],
-                capture_output=True,
-                timeout=timeout_s,
-                env=child_env,
-            )
-            _TPU_TOPOLOGY_PROBE[topology] = res.returncode == 0
-        except subprocess.TimeoutExpired:
-            _TPU_TOPOLOGY_PROBE[topology] = False
-    if not _TPU_TOPOLOGY_PROBE[topology]:
-        raise RuntimeError(
-            f"TPU AOT topology {topology!r} unavailable: plugin init "
-            f"failed or wedged past {timeout_s:.0f}s in a probe subprocess"
-        )
-
-
 def tpu_topology_mesh(topology: str = "v5e:2x4", axis_names=("data",),
                       shape=None):
     """An n-chip TPU Mesh from an AOT topology description — no multi-chip
@@ -542,7 +481,6 @@ def tpu_topology_mesh(topology: str = "v5e:2x4", axis_names=("data",),
     from jax.experimental import topologies
     from jax.sharding import Mesh
 
-    _probe_tpu_topology(topology)
     topo = topologies.get_topology_desc(platform="tpu", topology_name=topology)
     devs = np.array(topo.devices)
     if shape is None:

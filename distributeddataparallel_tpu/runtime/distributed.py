@@ -42,6 +42,58 @@ class _ProcessGroupState:
 
 _STATE = _ProcessGroupState()
 
+#: ``--device`` values whose ``jax.Device.platform`` is spelled otherwise
+_PLATFORM_OF = {"cuda": "gpu"}
+
+
+def select_device(device: str = "auto", fake_devices: int = 0) -> None:
+    """Pick the JAX platform for this process (the ``--device`` flag of
+    every entry point).  Must run before a backend initializes.
+
+    A named device is an instruction, not a preference: it replaces
+    whatever ``JAX_PLATFORMS`` says, and ``device_summary(device)`` —
+    called once the backend is up — refuses any other platform.
+    ``auto`` leaves JAX's own choice (``JAX_PLATFORMS`` when set) alone.
+    """
+    if fake_devices:
+        if device not in ("auto", "cpu"):
+            raise SystemExit("--fake-devices requires --device cpu")
+        from distributeddataparallel_tpu.compat import configure_cpu_devices
+
+        configure_cpu_devices(fake_devices)
+    elif device != "auto":
+        jax.config.update("jax_platforms", device)
+
+
+def device_summary(expect: str = "auto") -> dict:
+    """``{"platform", "kind", "count"}`` of the devices JAX runs on —
+    initializing the backend if need be — or ``SystemExit`` when
+    ``expect`` names a platform and JAX found anything else."""
+    try:
+        devs = jax.devices()
+    except RuntimeError as exc:
+        if expect == "auto":
+            raise
+        raise SystemExit(
+            f"--device {expect}: JAX found no {expect!r} platform on this "
+            f"machine (JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} "
+            f"is not consulted): {exc}"
+        ) from exc
+    found = {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+    }
+    if expect != "auto" and found["platform"] != _PLATFORM_OF.get(
+        expect, expect
+    ):
+        raise SystemExit(
+            f"--device {expect}: JAX is running on platform "
+            f"{found['platform']!r} ({found['kind']} x{found['count']}), "
+            f"not {expect!r}"
+        )
+    return found
+
 
 def init_process_group(
     backend: str | None = None,
@@ -62,8 +114,8 @@ def init_process_group(
       run ``jax.distributed.initialize`` for control-plane rendezvous.
     - Otherwise run single-process: all devices are local, no rendezvous.
 
-    ``backend`` is advisory ("tpu", "cpu", "cuda"); device selection itself
-    is done via ``JAX_PLATFORMS`` before import, by the CLI layer.
+    ``backend`` is advisory ("tpu", "cpu", "cuda"); the CLI layer picks
+    the platform with ``select_device`` before this runs.
     """
     if _STATE.initialized:
         raise RuntimeError(
@@ -71,11 +123,33 @@ def init_process_group(
         )
 
     explicit = coordinator_address is not None or num_processes is not None
+    # A TPU VM exports CLOUD_TPU_TASK_ID / TPU_WORKER_ID on single hosts
+    # too; only a roster of several workers announces a pod, and only a
+    # pod may call the argument-less (metadata-driven) rendezvous — on a
+    # sealed single host it would wait for peers that do not exist.
+    pod = (
+        "CLOUD_TPU_TASK_ID" in os.environ
+        and len(os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")) > 1
+    )
     env_multiproc = (
         os.environ.get("JAX_COORDINATOR_ADDRESS")
         or os.environ.get("JAX_NUM_PROCESSES")
-        or os.environ.get("CLOUD_TPU_TASK_ID")
+        or pod
     )
+    announced = [
+        k for k in (
+            "CLOUD_TPU_TASK_ID", "TPU_WORKER_ID", "JAX_COORDINATOR_ADDRESS"
+        ) if k in os.environ
+    ]
+    if announced:
+        from distributeddataparallel_tpu.utils.logging import get_logger
+
+        get_logger().info(
+            "init_process_group: environment sets %s -> %s",
+            ", ".join(announced),
+            "rendezvous" if explicit or env_multiproc
+            else "single process, no rendezvous",
+        )
 
     if explicit or env_multiproc:
         # jax.distributed.initialize does NOT read the JAX_COORDINATOR_*

@@ -69,20 +69,12 @@ def _free_port() -> int:
 
 
 def _child(fn, process_id, nprocs, coordinator, env, args):
-    # Runs in a fresh interpreter (spawn start method): configure the JAX
-    # runtime before anything imports jax.
+    # Runs in a fresh interpreter (spawn start method).  Importing this
+    # module imported jax, so ``env`` is too late for what jax reads at
+    # import (JAX_PLATFORMS, JAX_COMPILATION_CACHE_DIR: those a child
+    # inherits from the parent's environment); it is in time for what
+    # the worker and backend initialization read.
     os.environ.update(env)
-    if os.environ.get("DDP_COMPILE_CACHE"):
-        # Inherit the parent's persistent compilation cache before the
-        # worker's first compile: this is what turns a supervised
-        # respawn's startup from a recompile into a cache hit, for ANY
-        # worker function — dpp's trainer reads the env itself, but test
-        # and bench workers get the cache here without extra plumbing.
-        from distributeddataparallel_tpu.training.warm_start import (
-            enable_compile_cache,
-        )
-
-        enable_compile_cache(os.environ["DDP_COMPILE_CACHE"])
     if nprocs > 1:
         # A single supervised worker must NOT get distributed-init vars:
         # it is a one-process job that happens to run in a child, and a

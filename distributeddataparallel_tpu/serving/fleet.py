@@ -525,12 +525,6 @@ class ServingFleet:
 # Multi-process fleet (ddp_serve --fleet P:D)
 # ---------------------------------------------------------------------------
 
-_WORKER_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-}
-
-
 def _send_line(sock: socket.socket, msg: dict) -> None:
     sock.sendall(json.dumps(msg, separators=(",", ":")).encode() + b"\n")
 
@@ -579,6 +573,13 @@ def fleet_worker(process_id: int, cfg_json: str) -> None:
 
     import jax
     import jax.numpy as jnp
+
+    from distributeddataparallel_tpu.compat import configure_cpu_devices
+
+    # Fleet workers are CPU processes: a chip belongs to one process, so
+    # P+D workers could not share it (ddp_serve refuses --fleet with
+    # --device tpu for the same reason).
+    configure_cpu_devices(1)
 
     from distributeddataparallel_tpu.models import TransformerLM
     from distributeddataparallel_tpu.models.transformer import (
@@ -946,7 +947,6 @@ class FleetService:
         })
         procs = spawn(
             fleet_worker, args=(cfg_json,), nprocs=nprocs, join=False,
-            env=dict(_WORKER_ENV),
         )
         try:
             return self._drive(

@@ -445,7 +445,8 @@ class NonFiniteBreaker:
 
 
 def note_warm_start(
-    counters, *, mode: str, first_step_s: float | None = None, events=None
+    counters, *, mode: str, first_step_s: float | None = None, events=None,
+    cache_hits: int | None = None, cache_misses: int | None = None,
 ) -> None:
     """Record how this incarnation obtained its train step.
 
@@ -454,7 +455,10 @@ def note_warm_start(
     path's warm-start behavior is visible in the normal run log and in
     the fault summary: a respawn that was supposed to hit the cache but
     logs ``cold`` is a warm-start regression, caught by reading logs
-    instead of by profiling.
+    instead of by profiling.  ``cache_hits`` / ``cache_misses`` count
+    JAX persistent-compilation-cache lookups made while the first step
+    was acquired: a warm start of a plain ``jit`` step is hits > 0 with
+    no miss.
     """
     from distributeddataparallel_tpu.utils.logging import log0
 
@@ -466,10 +470,13 @@ def note_warm_start(
         events.emit(
             "warm_start",
             mode=mode, first_step_s=first_step_s, attempt=attempt,
+            cache_hits=cache_hits, cache_misses=cache_misses,
         )
     log0(
-        "warm start: attempt %d acquired the train step via %s%s",
+        "warm start: attempt %d acquired the train step via %s%s%s",
         attempt, mode,
         f" (first step ready in {first_step_s:.2f}s)"
         if first_step_s is not None else "",
+        f", compile cache {cache_hits} hit(s) / {cache_misses} miss(es)"
+        if cache_hits is not None else "",
     )

@@ -7,9 +7,10 @@ every process start — including every supervised gang respawn
 the host every step to read metrics.  This module is the warm-start +
 dispatch subsystem that closes both gaps:
 
-- ``enable_compile_cache``: one switch for JAX's persistent compilation
-  cache, exported through the environment so spawned/respawned gang
-  members (fresh interpreters) inherit it before their first compile.
+- ``resolve_compile_cache``: the one place that decides where JAX's
+  persistent compilation cache lives (``JAX_COMPILATION_CACHE_DIR`` from
+  outside, else a fixed directory in the checkout), exported so
+  spawned/respawned gang members (fresh interpreters) inherit it.
 - ``ExecutableStore`` + ``warm_train_step``: ahead-of-time reuse of the
   *serialized executable itself* — the compiled train step is saved
   keyed by (topology, mesh, model config, step-factory flags, jax
@@ -67,36 +68,34 @@ class WarmStartMismatch(RuntimeError):
     """A stored executable's key does not match the live run (strict mode)."""
 
 
-def enable_compile_cache(
-    cache_dir: str, *, min_compile_time_s: float | None = None
-) -> str:
-    """Turn on JAX's persistent compilation cache rooted at ``cache_dir``.
+#: JAX's persistent compilation cache when the environment names none: a
+#: fixed directory in the checkout.  The path is part of every entry's
+#: key, so it derives from the package's own location — never from the
+#: working directory, a pid, the time or a temp name.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
-    Also exports ``JAX_COMPILATION_CACHE_DIR`` / ``DDP_COMPILE_CACHE`` so
-    child processes (supervised gang members, respawns, bench workers)
-    inherit the cache: they start in fresh interpreters, and the
-    environment is the only channel that survives the spawn.
 
-    ``min_compile_time_s=None`` keeps an inherited
-    ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` (or 0.0): a child
-    re-enabling the parent's cache must not silently raise the floor and
-    start skipping entries the parent intended to persist.
+def resolve_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Every entry point calls this once, before it compiles or spawns.
+    ``JAX_COMPILATION_CACHE_DIR`` set from outside wins and nothing is
+    set in code — JAX reads it itself.  Otherwise the cache goes to
+    ``DEFAULT_COMPILE_CACHE_DIR``, exported so spawned workers (fresh
+    interpreters) inherit the same directory.  Which compiles persist is
+    JAX's own ``jax_persistent_cache_min_compile_time_secs`` (1 s unless
+    the environment overrides it) everywhere.
     """
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    os.makedirs(cache_dir, exist_ok=True)
-    if min_compile_time_s is None:
-        min_compile_time_s = float(
-            os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", 0.0)
-        )
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs", float(min_compile_time_s)
-    )
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-    os.environ["DDP_COMPILE_CACHE"] = cache_dir
-    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = str(
-        float(min_compile_time_s)
-    )
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     return cache_dir
 
 

@@ -5,14 +5,19 @@ Usage:
     python scripts/ddp_serve.py --model tiny --rate 20 --duration 2 \
         --events-dir runs/serve
     python scripts/ddp_serve.py --smoke          # CI: tiny burst, asserts
-    python scripts/ddp_serve.py --model gpt2_124m --seq-len 256 \
-        --slots 8 --rate 4 --duration 5 --store .aot-cache
+    python scripts/ddp_serve.py --device tpu --model gpt2_124m \
+        --seq-len 1024 --slots 8 --blocks 512 --chunk 128 --rate 4 \
+        --duration 5 --prompt-len 64,512 --output-len 16,64
 
-Builds the model with randomly-initialized params (the traffic is
-synthetic token ids — serving-path performance and correctness do not
-depend on trained weights), wires the engine to an events dir +
-metrics registry, replays a seeded loadgen trace, and prints the
-serving summary as JSON.  The events dir afterwards holds a mergeable
+``--device tpu|cpu`` is required, not preferred (same helper as
+``dpp.py``); ``auto`` takes what JAX finds.  Builds the model with
+randomly-initialized params (the traffic is synthetic token ids —
+serving-path performance and correctness do not depend on trained
+weights), wires the engine to an events dir + metrics registry, replays
+a seeded loadgen trace, and prints the serving summary as one JSON line
+naming the device it ran on.  On the real clock the programs are
+compiled before the first arrival and the time reported as
+``compile_s``.  The events dir afterwards holds a mergeable
 timeline that ``ddp_trace.py`` exports to Perfetto (request spans,
 active-slot counter) and ``ddp_report.py`` renders with its Serving
 section.
@@ -33,21 +38,12 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _ensure_cpu() -> None:
-    """CPU-safe defaults when no accelerator is configured (same
-    contract as ddplint: must run before the first jax import)."""
-    if "jax" in sys.modules:
-        return
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
-
-
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--device", choices=("tpu", "cpu", "auto"),
+                    default="auto",
+                    help="backend selector, as in dpp.py: a named device "
+                         "is required, not preferred")
     ap.add_argument("--model", default="tiny",
                     choices=("tiny", "gpt2_124m"))
     ap.add_argument("--seq-len", type=int, default=None,
@@ -208,7 +204,7 @@ def _run_fleet(args) -> int:
     )
     out = svc.run(trace)
     out["fleet"] = f"{n_prefill}:{n_decode}"
-    print(json.dumps(out, indent=1, sort_keys=True, default=str))
+    print(json.dumps(out, sort_keys=True, default=str))
 
     if not args.smoke:
         return 0
@@ -341,13 +337,36 @@ def _run_fleet(args) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    _ensure_cpu()
+    if args.fleet and args.device == "tpu":
+        # Fleet workers are separate OS processes pinned to the CPU
+        # (serving.fleet): a chip belongs to one process, so P+D workers
+        # cannot share it, and one-chip-per-replica fleets are not built.
+        raise SystemExit(
+            "--fleet with --device tpu: fleet workers are CPU processes "
+            "(one chip cannot be shared by several processes); run one "
+            "engine with --device tpu, or the fleet with --device cpu|auto"
+        )
+    from distributeddataparallel_tpu.training.warm_start import (
+        resolve_compile_cache,
+    )
 
+    resolve_compile_cache()
     if args.fleet:
         return _run_fleet(args)
 
+    from distributeddataparallel_tpu.runtime.distributed import (
+        device_summary,
+        select_device,
+    )
+
+    select_device(args.device)
+    device = device_summary(args.device)
+
+    import time
+
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from distributeddataparallel_tpu.models import TransformerLM
     from distributeddataparallel_tpu.models.transformer import (
@@ -358,6 +377,9 @@ def main(argv=None) -> int:
         EventLog,
         events_path,
         merge_timeline,
+    )
+    from distributeddataparallel_tpu.observability.memory import (
+        device_memory_stats,
     )
     from distributeddataparallel_tpu.observability.registry import (
         MetricsRegistry,
@@ -392,7 +414,11 @@ def main(argv=None) -> int:
     if args.events_dir:
         os.makedirs(args.events_dir, exist_ok=True)
         events = EventLog(events_path(args.events_dir, 0), 0)
-        events.emit("run_start", argv=sys.argv[1:], role="serve")
+        events.emit(
+            "run_start", argv=sys.argv[1:], role="serve",
+            devices=device["count"], platform=device["platform"],
+            device_kind=device["kind"],
+        )
     registry = MetricsRegistry()
 
     clock = VirtualClock(args.virtual_dt) if args.virtual_dt else None
@@ -426,8 +452,27 @@ def main(argv=None) -> int:
         turns=args.turns,
         turn_gap_s=args.turn_gap,
     ))
+    compile_s = None
+    if clock is None:
+        # Real clock: compile the programs before the first arrival, so
+        # request latencies are the server's and not the compiler's
+        # (under --virtual-dt compile time never reaches the clock).
+        t0 = time.perf_counter()
+        engine.submit(np.arange(4, dtype=np.int32) % cfg.vocab_size, 4)
+        engine.run()
+        engine.completed.clear()
+        compile_s = round(time.perf_counter() - t0, 3)
     out = run_load(engine, trace, clock=clock)
     out["requests"] = len(trace)
+    out["device"] = device
+    out["model_dtype"] = jnp.dtype(cfg.dtype).name
+    out["compile_s"] = compile_s
+    out["compile_cache"] = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    mem = device_memory_stats()
+    if mem:
+        out["device_peak_bytes_each"] = [
+            m["peak_bytes_in_use"] for m in mem
+        ]
     out["kv_pool_bytes"] = kv_pool_bytes(
         cfg, args.blocks, args.block_size, quantized_kv=args.quantize_kv
     )
@@ -440,7 +485,7 @@ def main(argv=None) -> int:
         events.close()
         merge_timeline(args.events_dir)
 
-    print(json.dumps(out, indent=1, sort_keys=True, default=str))
+    print(json.dumps(out, sort_keys=True, default=str))
 
     if args.smoke:
         from distributeddataparallel_tpu.observability.trace_export import (

@@ -16,7 +16,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import distributeddataparallel_tpu as ddp
-from distributeddataparallel_tpu import compat
 from distributeddataparallel_tpu.analysis import (
     mesh_sim,
     schedule_lint,
@@ -61,7 +60,7 @@ def mesh(devices):
 
 
 def _lowered_text(fn, mesh, *args, in_specs, out_specs=P()):
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
@@ -150,7 +149,7 @@ def test_sf204_custom_vjp_hides_collective(mesh):
     def prog(x):
         return jnp.sum(sneaky(x))
 
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         prog, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
         check_vma=False,
     )

@@ -178,7 +178,8 @@ def test_corrupt_artifact_falls_back_loudly(devices, tmp_path):
 
 def _cache_probe_worker(cache_dir, out_path):
     """Spawn child: compile one jit function with the persistent cache
-    rooted at ``cache_dir`` and record the hit/miss event counts."""
+    the ENVIRONMENT placed at ``cache_dir`` (inherited at birth, read by
+    jax at import) and record the hit/miss event counts."""
     import json
 
     from distributeddataparallel_tpu.compat import configure_cpu_devices
@@ -190,10 +191,12 @@ def _cache_probe_worker(cache_dir, out_path):
 
     from distributeddataparallel_tpu.training.warm_start import (
         CompileCacheStats,
-        enable_compile_cache,
+        resolve_compile_cache,
     )
 
-    enable_compile_cache(cache_dir)
+    # The resolver leaves an environment-placed cache alone (that jax
+    # itself read it is what the second process's hit proves).
+    assert resolve_compile_cache() == cache_dir
     stats = CompileCacheStats()
 
     @jax.jit
@@ -206,13 +209,16 @@ def _cache_probe_worker(cache_dir, out_path):
         json.dump({"hits": stats.hits, "misses": stats.misses}, fh)
 
 
-def test_compile_cache_hit_across_processes(tmp_path):
-    """Two REAL processes, same cache dir: the first compiles (miss),
-    the second must hit — the event counters make this deterministic
-    instead of a timing assertion."""
+def test_compile_cache_hit_across_processes(tmp_path, monkeypatch):
+    """Two REAL processes, same JAX_COMPILATION_CACHE_DIR: the first
+    compiles (miss), the second must hit — the event counters make this
+    deterministic instead of a timing assertion."""
     import json
 
     cache = str(tmp_path / "cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+    # The probe function compiles in well under JAX's 1 s default floor.
+    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     ctx = mp.get_context("spawn")
     results = []
     for run in range(2):
