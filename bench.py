@@ -327,44 +327,6 @@ def bench_gpt2() -> dict:
             "step_ms_fenced_chunks": [round(t, 3) for t in dist],
             "ran_pallas": want_pallas,
         }
-        if want_pallas:
-            # MFU-gap decomposition (VERDICT r3 item 8): bucket the
-            # compiled step's own estimated_cycles by trace scope.
-            # Measured: the TIED head's d x V matmuls (fwd + transpose
-            # grad into the embedding) are ~24% of all scheduled cycles
-            # and the loss softmax ~9% — a third of the step on
-            # vocab-width work the 6N MFU numerator largely miscredits
-            # at 124M scale (V=50257 vs d=768; Llama-0.6B's smaller
-            # head share is exactly why its mfu_est reads ~53%).
-            # Experiments: untied head measured SLOWER (91.4 -> 94.6 ms
-            # — same head FLOPs, 38M more params to update); RoPE
-            # instead of learned positions gained ~1%.  The r3
-            # attribution to f32 LayerNorms is refuted: norms measure
-            # 0.07% of cycles.  Conclusion: ~44% mfu_est IS the 124M
-            # tied-head ceiling; the decomposition below re-records
-            # every round.
-            from distributeddataparallel_tpu.parallel.overlap import (
-                cycles_by_scope,
-            )
-
-            try:
-                txt = (
-                    step.lower(state, batch, jax.random.PRNGKey(1))
-                    .compile().as_text()
-                )
-                decomp = cycles_by_scope(txt, strict=True, buckets={
-                    "attention": (
-                        "q_proj|k_proj|v_proj|out_proj|attn|flash|attention"
-                    ),
-                    "mlp": "/mlp/",
-                    "norms": "ln_|norm",
-                    "embed_lookup": "token_embed|pos_embed|lm_head",
-                    "tied_head_matmuls": r"TransformerLM\)+/dot_general",
-                    "loss_softmax": r"cross_entropy|log_softmax|jvp\(\)/",
-                })
-            except Exception as e:  # noqa: BLE001 - diagnostics only
-                decomp = {"error": repr(e)}
-            results[impl]["cycle_decomposition"] = decomp
         del state, step
 
     winner = max(results, key=lambda k: results[k]["tokens_s_chip"])

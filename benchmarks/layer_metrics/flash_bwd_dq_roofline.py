@@ -1,0 +1,8 @@
+"""Kernels (``ops/pallas_attention.py``): as ``flash_attn_roofline``, for
+the ``flash_bwd_dq`` kernel alone."""
+
+from benchmarks import flash_cost
+
+
+def read(ctx):
+    return flash_cost.roofline(ctx, "dq")

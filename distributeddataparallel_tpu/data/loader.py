@@ -23,6 +23,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distributeddataparallel_tpu.observability.trace import get_tracer
 from distributeddataparallel_tpu.parallel.sampler import DistributedSampler
 
 Pytree = Any
@@ -353,8 +354,21 @@ class DataLoader:
                 batch["valid"] = np.concatenate(masks).astype(np.float32)
             yield batch
 
-    def __iter__(self) -> Iterator[Pytree]:
+    def _batches(self) -> Iterator[Pytree]:
+        """One ``loader.batch`` span round the production of each batch —
+        host gather plus, with ``device_feed``, the placement — on
+        whichever thread pulls this iterator."""
+        tracer = get_tracer()
         it = self._host_batches()
+        for _ in range(self.steps_per_epoch):
+            with tracer.span("loader.batch"):
+                batch = next(it)
+                if self.device_feed:
+                    batch = self._place_fn(batch)
+            yield batch
+
+    def __iter__(self) -> Iterator[Pytree]:
+        it = self._batches()
         if not self.device_feed:
             yield from it
             return
@@ -366,8 +380,8 @@ class DataLoader:
         queue: collections.deque = collections.deque()
         self._depth_fn = lambda: len(queue)
         try:
-            for host_batch in it:
-                queue.append(self._place_fn(host_batch))
+            for batch in it:
+                queue.append(batch)
                 if len(queue) > self.prefetch:
                     yield queue.popleft()
             while queue:
@@ -409,8 +423,8 @@ class DataLoader:
 
         def produce():
             try:
-                for host_batch in it:
-                    if not put(self._place_fn(host_batch)):
+                for batch in it:
+                    if not put(batch):
                         return
                 put(done)
             # ddplint: allow[broad-except] — producer thread: transports ANY

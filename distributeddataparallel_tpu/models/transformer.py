@@ -37,6 +37,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributeddataparallel_tpu.observability import scopes
 from distributeddataparallel_tpu.ops.attention import (
     apply_rope,
     attention,
@@ -820,14 +821,15 @@ class TransformerLM(nn.Module):
             embedding_init=nn.initializers.normal(0.02),
             param_dtype=jnp.float32,
         )
-        x = embed(tokens).astype(cfg.dtype)
-        if cfg.positional == "learned":
-            pos = positions if positions is not None else jnp.arange(S)
-            pos_embed = self.param(
-                "pos_embed", nn.initializers.normal(0.02),
-                (cfg.max_seq_len, cfg.d_model), jnp.float32,
-            )
-            x = x + pos_embed[pos].astype(cfg.dtype)
+        with jax.named_scope(scopes.EMBED):
+            x = embed(tokens).astype(cfg.dtype)
+            if cfg.positional == "learned":
+                pos = positions if positions is not None else jnp.arange(S)
+                pos_embed = self.param(
+                    "pos_embed", nn.initializers.normal(0.02),
+                    (cfg.max_seq_len, cfg.d_model), jnp.float32,
+                )
+                x = x + pos_embed[pos].astype(cfg.dtype)
         x = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)(x)
 
         rope = None
@@ -864,12 +866,16 @@ class TransformerLM(nn.Module):
         # the vocab-sized matmul at 1/4 MXU rate — measured ~25% of the
         # whole GPT-2 train step.  Under cfg.dtype=float32 (tests, CPU)
         # the casts are no-ops and this is exactly the f32 matmul.
-        if cfg.tie_embeddings:
-            w = embed.embedding.astype(cfg.dtype)  # (V, D)
-            logits = jax.lax.dot_general(
-                x.astype(cfg.dtype), w, (((x.ndim - 1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        else:
-            logits = LMHead(cfg.vocab_size, cfg.dtype, name="lm_head")(x)
+        with jax.named_scope(scopes.HEAD):
+            if cfg.tie_embeddings:
+                w = embed.embedding.astype(cfg.dtype)  # (V, D)
+                logits = jax.lax.dot_general(
+                    x.astype(cfg.dtype), w,
+                    (((x.ndim - 1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            else:
+                logits = LMHead(
+                    cfg.vocab_size, cfg.dtype, name="lm_head"
+                )(x)
         return logits

@@ -77,7 +77,7 @@ from .schema import (
     validate_record,
 )
 from .straggler import straggler_report
-from .trace import Tracer
+from .trace import Tracer, get_tracer, set_tracer
 from .trace_export import to_trace_events, validate_trace, write_trace
 from .tracecontext import (
     SpanContext,
@@ -119,6 +119,7 @@ __all__ = [
     "events_path",
     "from_fields",
     "from_traceparent",
+    "get_tracer",
     "goodput_from_timeline",
     "json_safe",
     "live_array_bytes",
@@ -139,6 +140,7 @@ __all__ = [
     "run_summary_from_timeline",
     "save_baseline",
     "scrape",
+    "set_tracer",
     "simple_cnn_fwd_flops",
     "straggler_report",
     "tier_rollups",

@@ -22,6 +22,7 @@ import glob
 import heapq
 import json
 import os
+import threading
 import time
 
 from .schema import SCHEMA_VERSION, json_safe
@@ -50,19 +51,23 @@ class EventLog:
         self.proc = proc
         self._seq = 0
         self._fh = open(path, "a", buffering=1)  # line-buffered
+        # one writer per FILE, but a process has threads: the loader's
+        # producer emits its spans beside the train loop's
+        self._lock = threading.Lock()
 
     def emit(self, kind: str, **fields) -> dict:
-        rec = {
-            "v": SCHEMA_VERSION,
-            "ts": time.time(),
-            "seq": self._seq,
-            "proc": self.proc,
-            "kind": kind,
-        }
-        self._seq += 1
-        for k, v in fields.items():
-            rec[k] = json_safe(v)
-        self._fh.write(json.dumps(rec) + "\n")
+        safe = {k: json_safe(v) for k, v in fields.items()}
+        with self._lock:
+            rec = {
+                "v": SCHEMA_VERSION,
+                "ts": time.time(),
+                "seq": self._seq,
+                "proc": self.proc,
+                "kind": kind,
+            }
+            self._seq += 1
+            rec.update(safe)
+            self._fh.write(json.dumps(rec) + "\n")
         return rec
 
     def flush(self) -> None:

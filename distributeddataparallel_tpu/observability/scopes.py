@@ -1,0 +1,38 @@
+"""Names the program writes into what it compiles: ``jax.named_scope``
+names on the parts of a train step that no Flax module names, and the
+names of the Pallas kernels.  They reach the HLO ``op_name`` metadata of
+every operation traced under them (``jit(step)/jvp(M)/head/dot_general``)
+and, through it, the device trace.
+
+What Flax names itself is relied on, not re-wrapped: ``layer_<i>`` (or
+``layers/block`` under ``scan_layers``), ``attn``, ``mlp`` and the norms.
+Forward and backward need no scope either: JAX writes ``jvp(`` and
+``transpose(jvp(`` into ``op_name``.
+
+Module-import rule: stdlib only (see schema.py).
+"""
+
+from __future__ import annotations
+
+#: token + position embedding (``models/transformer.py``)
+EMBED = "embed"
+#: the output projection, tied ``dot_general`` or ``LMHead``
+HEAD = "head"
+#: ``ops/losses.py``: the cross entropies
+LOSS = "loss"
+#: accuracy, and the step's ``pmean`` of loss and aux
+METRICS = "metrics"
+#: the gradient exchange over the data axis: bucket packing, the
+#: collective and unpacking (also the cp-axis and ZeRO exchanges)
+GRAD_SYNC = "grad_sync"
+GRAD_CLIP = "grad_clip"
+#: ``state.apply_gradients`` (and ZeRO's sharded update)
+OPTIMIZER = "optimizer"
+
+#: the three ``pallas_call``s of ``ops/pallas_attention.py``
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+
+STEP_SCOPES = (EMBED, HEAD, LOSS, METRICS, GRAD_SYNC, GRAD_CLIP, OPTIMIZER)
+KERNEL_NAMES = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)

@@ -7,8 +7,11 @@ of activation dtype (logits are upcast) for numerical parity.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import optax
+
+from distributeddataparallel_tpu.observability import scopes
 
 
 def per_example_cross_entropy(
@@ -30,15 +33,18 @@ def per_example_accuracy(
     return hit if hit.ndim == 1 else hit.mean(axis=tuple(range(1, hit.ndim)))
 
 
+@jax.named_scope(scopes.LOSS)
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     """Mean softmax CE with integer labels; logits (B, C), labels (B,)."""
     return per_example_cross_entropy(logits, labels).mean()
 
 
+@jax.named_scope(scopes.METRICS)
 def accuracy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     return per_example_accuracy(logits, labels).mean()
 
 
+@jax.named_scope(scopes.LOSS)
 def lm_cross_entropy(
     logits: jnp.ndarray,
     targets: jnp.ndarray,

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from distributeddataparallel_tpu.observability import scopes
 from distributeddataparallel_tpu.ops.attention import NEG_INF
 
 
@@ -209,6 +210,7 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool):
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name=scopes.FLASH_FWD,
     )(qf, kf, vf)
     out = out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     # lse stays in its (B*H, 8, Sq) sublane-broadcast layout: the backward
@@ -399,6 +401,7 @@ def _bwd(causal, interpret, res, do):
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name=scopes.FLASH_BWD_DQ,
     )(qf, kf, vf, dof, lse8, delta8)
 
     # dkv grid: one row per KV head; the inner index t walks every
@@ -435,6 +438,7 @@ def _bwd(causal, interpret, res, do):
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name=scopes.FLASH_BWD_DKV,
     )(qf, kf, vf, dof, lse8, delta8)
 
     dq = dq.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distributeddataparallel_tpu.observability import scopes
+
 Pytree = Any
 
 #: DDP's default bucket size: 25 MiB (SURVEY.md §2b, torch Reducer default).
@@ -81,11 +83,6 @@ def all_reduce_gradients(
     _check_compress(compress)
     if chain and bucket_bytes is None:
         bucket_bytes = OVERLAP_BUCKET_BYTES
-    if bucket_bytes is not None:
-        return bucket_gradients(
-            grads, axis_name, op=op, bucket_bytes=bucket_bytes, chain=chain,
-            compress=compress,
-        )
     red = lax.pmean if op == "mean" else lax.psum
 
     def _leaf(g):
@@ -93,7 +90,15 @@ def all_reduce_gradients(
             return red(g.astype(jnp.bfloat16), axis_name).astype(g.dtype)
         return red(g, axis_name)
 
-    return jax.tree.map(_leaf, grads)
+    # One scope for the whole exchange — packing, collective, unpacking —
+    # so the device trace can give it an owner (observability/scopes.py).
+    with jax.named_scope(scopes.GRAD_SYNC):
+        if bucket_bytes is not None:
+            return bucket_gradients(
+                grads, axis_name, op=op, bucket_bytes=bucket_bytes,
+                chain=chain, compress=compress,
+            )
+        return jax.tree.map(_leaf, grads)
 
 
 def bucket_gradients(

@@ -415,62 +415,6 @@ def schedule_report(
     }
 
 
-def cycles_by_scope(
-    hlo_text: str, buckets: dict[str, str], *, strict: bool = False
-) -> dict:
-    """Bucket the scheduled program's ``estimated_cycles`` by op scope.
-
-    ``buckets`` maps bucket name -> regex matched against each
-    instruction's ``op_name`` metadata (the jax trace scope, e.g.
-    ``.../Attention_0/q_proj/...``); first match wins, unmatched cycles
-    land in ``other``.  Walks EVERY computation (fusion cycles live on
-    the call sites in entry AND inside while/cond bodies), skipping
-    fusion-body internals by only counting lines that carry
-    ``estimated_cycles``.  A measured decomposition of where the
-    compiler thinks the time goes — the per-op half of MFU-gap
-    attribution (``observability.cost_model`` supplies the other half:
-    the analytic FLOP numerator the gap is measured against).
-    """
-    compiled = {k: re.compile(v, re.IGNORECASE) for k, v in buckets.items()}
-    out = {k: 0 for k in buckets}
-    out["other"] = 0
-    seen_calls: set[str] = set()
-    for line in hlo_text.splitlines():
-        cyc = re.search(r'"estimated_cycles":"(\d+)"', line)
-        if not cyc:
-            continue
-        callm = re.search(r"calls=(%[\w.\-]+)", line)
-        if callm:
-            # one count per called computation (call sites repeat in
-            # schedules that unroll)
-            if callm.group(1) in seen_calls:
-                continue
-            seen_calls.add(callm.group(1))
-        name_m = re.search(r'op_name="([^"]*)"', line)
-        scope = name_m.group(1) if name_m else ""
-        n = int(cyc.group(1))
-        for k, rx in compiled.items():
-            if rx.search(scope):
-                out[k] += n
-                break
-        else:
-            out["other"] += n
-    total = sum(out.values())
-    if strict and total == 0:
-        raise ScheduleEvidenceError(
-            "cycles_by_scope: zero estimated_cycles parsed from a live "
-            "compile — cost-model metadata key renamed?"
-        )
-    return {
-        "total_cycles": total,
-        "by_scope": out,
-        "frac": {
-            k: round(v / total, 4) if total else 0.0
-            for k, v in out.items()
-        },
-    }
-
-
 def tpu_topology_mesh(topology: str = "v5e:2x4", axis_names=("data",),
                       shape=None):
     """An n-chip TPU Mesh from an AOT topology description — no multi-chip

@@ -71,6 +71,8 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from distributeddataparallel_tpu.observability import scopes
+
 Pytree = Any
 
 #: Default bucket granularity for the zero2/zero3 flat layout — matches
@@ -751,12 +753,13 @@ def zero_update(
     idx = lax.axis_index(axis_name)
     padded, chunk = flat_size(state.params, n)
 
-    flat_g = flatten_f32(grads, padded)
     # reduce_scatter: each replica receives the SUM of its 1/N chunk,
     # divided for DDP mean semantics (ref dpp.py grad averaging).
-    g_shard = lax.psum_scatter(
-        flat_g, axis_name, scatter_dimension=0, tiled=True
-    ) / n
+    with jax.named_scope(scopes.GRAD_SYNC):
+        flat_g = flatten_f32(grads, padded)
+        g_shard = lax.psum_scatter(
+            flat_g, axis_name, scatter_dimension=0, tiled=True
+        ) / n
     if clip_norm is not None:
         from distributeddataparallel_tpu.parallel.data_parallel import (
             clip_scale,
@@ -765,45 +768,62 @@ def zero_update(
             sumsq_f32,
         )
 
-        if model_axes:
-            if local_specs is None:
-                raise ValueError(
-                    "clip under model_axes needs local_specs (the "
-                    "per-leaf PartitionSpec tree of the local grads)"
-                )
-            # Per-leaf duplicate count: product of the model-axis sizes
-            # the leaf is NOT sharded over (its copies across those
-            # positions are identical).  Static at trace time.
-            sizes = [l.size for l in jax.tree.leaves(grads)]
-            dups = [
-                int(np.prod([
-                    lax.axis_size(ax)
-                    for ax in model_axes
-                    if ax not in spec_axes(sp)
-                ] or [1]))
-                for sp in jax.tree.leaves(
-                    local_specs,
-                    is_leaf=lambda x: isinstance(x, P),
-                )
-            ]
-            s = flat_chunk_sumsq(g_shard, idx * chunk, sizes, dups)
-            s = lax.psum(s, axis_name)
-            for ax in model_axes:
-                s = lax.psum(s, ax)
-            gnorm = jnp.sqrt(s)
-        else:
-            gnorm = jnp.sqrt(lax.psum(sumsq_f32(g_shard), axis_name))
-        g_shard = g_shard * clip_scale(gnorm, clip_norm)
+        with jax.named_scope(scopes.GRAD_CLIP):
+            if model_axes:
+                if local_specs is None:
+                    raise ValueError(
+                        "clip under model_axes needs local_specs (the "
+                        "per-leaf PartitionSpec tree of the local grads)"
+                    )
+                # Per-leaf duplicate count: product of the model-axis sizes
+                # the leaf is NOT sharded over (its copies across those
+                # positions are identical).  Static at trace time.
+                sizes = [l.size for l in jax.tree.leaves(grads)]
+                dups = [
+                    int(np.prod([
+                        lax.axis_size(ax)
+                        for ax in model_axes
+                        if ax not in spec_axes(sp)
+                    ] or [1]))
+                    for sp in jax.tree.leaves(
+                        local_specs,
+                        is_leaf=lambda x: isinstance(x, P),
+                    )
+                ]
+                s = flat_chunk_sumsq(g_shard, idx * chunk, sizes, dups)
+                s = lax.psum(s, axis_name)
+                for ax in model_axes:
+                    s = lax.psum(s, ax)
+                gnorm = jnp.sqrt(s)
+            else:
+                gnorm = jnp.sqrt(lax.psum(sumsq_f32(g_shard), axis_name))
+            g_shard = g_shard * clip_scale(gnorm, clip_norm)
 
-    flat_p = flatten_f32(state.params, padded)
-    p_shard = lax.dynamic_slice(flat_p, (idx * chunk,), (chunk,))
+    with jax.named_scope(scopes.OPTIMIZER):
+        flat_p = flatten_f32(state.params, padded)
+        p_shard = lax.dynamic_slice(flat_p, (idx * chunk,), (chunk,))
 
-    updates, new_opt_state = state.tx.update(g_shard, state.opt_state, p_shard)
-    new_p_shard = optax.apply_updates(p_shard, updates)
+        updates, new_opt_state = state.tx.update(
+            g_shard, state.opt_state, p_shard
+        )
+        new_p_shard = optax.apply_updates(p_shard, updates)
 
-    new_flat = lax.all_gather(new_p_shard, axis_name, axis=0, tiled=True)
-    new_params = unflatten(new_flat, state.params)
+        new_flat = lax.all_gather(new_p_shard, axis_name, axis=0, tiled=True)
+        new_params = unflatten(new_flat, state.params)
     return new_params, new_opt_state
+
+
+def _clip_shard(g_shard, axis_name: str, clip_norm: float):
+    """Clip a flat gradient shard to the global norm: the shards partition
+    the gradient vector, so norm² is one psum of the local norm²s."""
+    from distributeddataparallel_tpu.parallel.data_parallel import (
+        clip_scale,
+        sumsq_f32,
+    )
+
+    with jax.named_scope(scopes.GRAD_CLIP):
+        gnorm = jnp.sqrt(lax.psum(sumsq_f32(g_shard), axis_name))
+        return g_shard * clip_scale(gnorm, clip_norm)
 
 
 def zero2_update(
@@ -820,22 +840,20 @@ def zero2_update(
     (``zero_state(level=2)``).  Clipping is exact: the bucketed shards
     partition the gradient vector (padding is zeros), so the global
     norm² is one psum of local chunk norm²s."""
-    g_shard = scatter_grads_bucketed(grads, plan, axis_name, num_shards)
+    with jax.named_scope(scopes.GRAD_SYNC):
+        g_shard = scatter_grads_bucketed(grads, plan, axis_name, num_shards)
     if clip_norm is not None:
-        from distributeddataparallel_tpu.parallel.data_parallel import (
-            clip_scale,
-            sumsq_f32,
+        g_shard = _clip_shard(g_shard, axis_name, clip_norm)
+
+    with jax.named_scope(scopes.OPTIMIZER):
+        p_shard = shard_params_bucketed(state.params, plan, axis_name)
+        updates, new_opt_state = state.tx.update(
+            g_shard, state.opt_state, p_shard
         )
-
-        gnorm = jnp.sqrt(lax.psum(sumsq_f32(g_shard), axis_name))
-        g_shard = g_shard * clip_scale(gnorm, clip_norm)
-
-    p_shard = shard_params_bucketed(state.params, plan, axis_name)
-    updates, new_opt_state = state.tx.update(g_shard, state.opt_state, p_shard)
-    new_p_shard = optax.apply_updates(p_shard, updates)
-    new_params = gather_params_bucketed(
-        new_p_shard, state.params, plan, axis_name
-    )
+        new_p_shard = optax.apply_updates(p_shard, updates)
+        new_params = gather_params_bucketed(
+            new_p_shard, state.params, plan, axis_name
+        )
     return new_params, new_opt_state
 
 
@@ -853,19 +871,17 @@ def zero3_update(
     shard, done: the new flat shard IS the next state's params (the
     re-gather happens at the top of the next step).  Returns
     (new_flat, new_opt_state)."""
-    g_shard = g_shard / num_shards
+    with jax.named_scope(scopes.GRAD_SYNC):
+        g_shard = g_shard / num_shards
     if clip_norm is not None:
-        from distributeddataparallel_tpu.parallel.data_parallel import (
-            clip_scale,
-            sumsq_f32,
+        g_shard = _clip_shard(g_shard, axis_name, clip_norm)
+
+    with jax.named_scope(scopes.OPTIMIZER):
+        p_shard = state.params.flat
+        updates, new_opt_state = state.tx.update(
+            g_shard, state.opt_state, p_shard
         )
-
-        gnorm = jnp.sqrt(lax.psum(sumsq_f32(g_shard), axis_name))
-        g_shard = g_shard * clip_scale(gnorm, clip_norm)
-
-    p_shard = state.params.flat
-    updates, new_opt_state = state.tx.update(g_shard, state.opt_state, p_shard)
-    new_flat = optax.apply_updates(p_shard, updates)
+        new_flat = optax.apply_updates(p_shard, updates)
     return new_flat, new_opt_state
 
 

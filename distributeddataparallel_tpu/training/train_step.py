@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distributeddataparallel_tpu.observability import scopes
 from distributeddataparallel_tpu.parallel.data_parallel import (
     OVERLAP_BUCKET_BYTES,
     all_reduce_gradients,
@@ -534,9 +535,11 @@ def make_train_step(
             # Complete the seq-sharded gradient: each position's loss saw
             # only its sequence chunk; the replicated params' gradient is
             # the mean over chunks.  Loss/aux likewise become global.
-            grads = jax.tree.map(lambda g: lax.pmean(g, cp_axis), grads)
-            loss = lax.pmean(loss, cp_axis)
-            aux = jax.tree.map(lambda a: lax.pmean(a, cp_axis), aux)
+            with jax.named_scope(scopes.GRAD_SYNC):
+                grads = jax.tree.map(lambda g: lax.pmean(g, cp_axis), grads)
+            with jax.named_scope(scopes.METRICS):
+                loss = lax.pmean(loss, cp_axis)
+                aux = jax.tree.map(lambda a: lax.pmean(a, cp_axis), aux)
 
         if nonfinite_guard:
             # Decide BEFORE any gradient leaves this position: a NaN must
@@ -644,9 +647,10 @@ def make_train_step(
                         powersgd_sync,
                     )
 
-                    grads, new_comm = powersgd_sync(
-                        grads, state.comm_state, axis_name
-                    )
+                    with jax.named_scope(scopes.GRAD_SYNC):
+                        grads, new_comm = powersgd_sync(
+                            grads, state.comm_state, axis_name
+                        )
                     state = state.replace(comm_state=new_comm)
                 elif presynced is None:
                     grads = all_reduce_gradients(
@@ -689,27 +693,30 @@ def make_train_step(
                     sumsq_f32,
                 )
 
-                if tp_axis is not None or ep_axis is not None:
-                    # Megatron/expert shards: per-leaf-spec-aware global
-                    # norm — sharded leaves psum over their model axes,
-                    # replicated leaves (complete per position) count
-                    # once.  The result is identical on every position,
-                    # so the scale is uniform.
-                    from distributeddataparallel_tpu.parallel.expert_parallel import (
-                        model_axes_param_specs,
-                    )
+                with jax.named_scope(scopes.GRAD_CLIP):
+                    if tp_axis is not None or ep_axis is not None:
+                        # Megatron/expert shards: per-leaf-spec-aware
+                        # global norm — sharded leaves psum over their
+                        # model axes, replicated leaves (complete per
+                        # position) count once.  The result is identical
+                        # on every position, so the scale is uniform.
+                        from distributeddataparallel_tpu.parallel.expert_parallel import (
+                            model_axes_param_specs,
+                        )
 
-                    sumsq = model_axes_sumsq(
-                        grads,
-                        model_axes_param_specs(grads, tp_axis, ep_axis),
-                    )
-                else:
-                    # Grads are complete per position here (post sync /
-                    # cp pmean), so the local norm IS the global norm.
-                    sumsq = sumsq_f32(grads)
-                scale = clip_scale(jnp.sqrt(sumsq), grad_clip)
-                grads = jax.tree.map(lambda g: g * scale, grads)
-            new_state = state.apply_gradients(grads)
+                        sumsq = model_axes_sumsq(
+                            grads,
+                            model_axes_param_specs(grads, tp_axis, ep_axis),
+                        )
+                    else:
+                        # Grads are complete per position here (post sync
+                        # / cp pmean), so the local norm IS the global
+                        # norm.
+                        sumsq = sumsq_f32(grads)
+                    scale = clip_scale(jnp.sqrt(sumsq), grad_clip)
+                    grads = jax.tree.map(lambda g: g * scale, grads)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_state = state.apply_gradients(grads)
         if with_model_state:
             sync_axes = (axis_name,) + (
                 (cp_axis,) if cp_axis is not None else ()
@@ -790,10 +797,11 @@ def make_train_step(
                 lambda n, o: jnp.where(keep, n, o), new_state, orig_state
             )
             new_state = new_state.replace(step=orig_state.step + 1)
-        metrics = {"loss": lax.pmean(loss, axis_name)}
-        metrics.update(
-            {k: lax.pmean(v, axis_name) for k, v in aux.items()}
-        )
+        with jax.named_scope(scopes.METRICS):
+            metrics = {"loss": lax.pmean(loss, axis_name)}
+            metrics.update(
+                {k: lax.pmean(v, axis_name) for k, v in aux.items()}
+            )
         if nonfinite_guard:
             # Already mesh-uniform (pmin above): no further reduction.
             metrics["nonfinite_grad"] = 1.0 - fin

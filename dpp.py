@@ -1282,7 +1282,7 @@ def train(args) -> float:
     # and an XLA-profiler orchestrator for windowed / on-anomaly capture.
     # Everything stays host-side — emitting an event or exporting a
     # snapshot never reads a device value, so none of it adds a sync.
-    events = tracer = registry = prof = None
+    events = registry = prof = None
     if args.events_dir or args.profile_steps:
         from distributeddataparallel_tpu.observability import (
             EventLog,
@@ -1290,7 +1290,6 @@ def train(args) -> float:
             MetricsRegistry,
             ProfilerOrchestrator,
             TextExporter,
-            Tracer,
             events_path,
             parse_profile_steps,
         )
@@ -1315,7 +1314,6 @@ def train(args) -> float:
                 registry.add_exporter(
                     TextExporter(os.path.join(args.events_dir, "metrics.txt"))
                 )
-            tracer = Tracer(events, registry)
         # Trace destination: --profile-dir when given, else a subdir of
         # the events dir.  The orchestrator is armed whenever it has
         # somewhere to write — --profile-steps drives the window, and
@@ -1332,12 +1330,15 @@ def train(args) -> float:
                 events=events,
             )
 
-    def _span(name, **attrs):
-        if tracer is not None:
-            return tracer.span(name, **attrs)
-        import contextlib
+    # One tracer per run.  It always exists: a profiler capture
+    # (--profile-steps, --profile-dir) reads its spans as ``ddp:<name>``
+    # with or without an event log.  It is installed as the process's,
+    # for the loader's ``loader.batch``, only inside the ``try`` whose
+    # ``finally`` takes it out again.
+    from distributeddataparallel_tpu.observability import Tracer, set_tracer
 
-        return contextlib.nullcontext()
+    tracer = Tracer(events, registry)
+    _span = tracer.span
 
     # Autotune BEFORE anything batch-shaped exists: apply replays a
     # persisted winner (zero trials), search measures on the live mesh
@@ -2264,7 +2265,8 @@ def train(args) -> float:
         those points always observe fully-synced state and the nan
         guard's decision point is never crossed unobserved."""
         for h, w in dispatch.drain():
-            settle(h, w)
+            with _span("settle"):
+                settle(h, w)
 
     # Step watchdog: a wedged collective or infeed stall should produce a
     # diagnostic and a best-effort checkpoint, not a silent hang.  Armed
@@ -2367,6 +2369,7 @@ def train(args) -> float:
     # uninterrupted run would have used, instead of replaying epoch-0 keys.
     base_rng = jax.random.PRNGKey(args.seed + 1)
     try:
+        set_tracer(tracer)
         for epoch in range(start_epoch, args.epochs):    # ref dpp.py:44
             epoch_rng = jax.random.fold_in(base_rng, epoch)
             # Legacy whole-epoch trace only when the windowed capture
@@ -2534,7 +2537,8 @@ def train(args) -> float:
                             else metrics["loss"]
                         )
                         for h, w in dispatch.push(guard, (epoch, batch_idx)):
-                            settle(h, w)
+                            with _span("settle"):
+                                settle(h, w)
                     if integrity is not None:
                         on_cadence = integrity.due(integrity_step)
                         integrity_step += 1
@@ -2999,6 +3003,9 @@ def train(args) -> float:
         ddp.destroy_process_group()
         raise
     finally:
+        # The run's tracer leaves with the run: its event log closes below,
+        # and a later loader in this process must not span into it.
+        set_tracer(None)
         if watchdog is not None:
             watchdog.stop()
         if prof is not None:

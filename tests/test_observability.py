@@ -192,6 +192,17 @@ def test_hot_path_never_syncs(tmp_path, monkeypatch, devices):
                 out = f(jnp.ones((256,)) * i)  # dispatched, NOT read
             ev.emit("nan_skip", step=i)
             reg.export(step=i)
+        # a tracer with nowhere to write, whose spans are still the
+        # profiler's annotations (jax is loaded here), with a trace being
+        # taken
+        bare = Tracer()
+        jax.profiler.start_trace(str(tmp_path / "xprof"))
+        try:
+            for i in range(5):
+                with bare.span("step", step=i), bare.span("settle"):
+                    out = f(jnp.ones((256,)) * i)
+        finally:
+            jax.profiler.stop_trace()
     assert calls["n"] == 0, "observability hot path forced a device sync"
     real(out)  # drain before leaving the test
     assert validate_file(path) == []

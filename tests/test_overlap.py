@@ -342,20 +342,6 @@ def test_compiler_stamp():
     assert stamp["jax"]  # at minimum the jax version is always present
 
 
-def test_cycles_by_scope_strict():
-    from distributeddataparallel_tpu.parallel.overlap import (
-        ScheduleEvidenceError,
-        cycles_by_scope,
-    )
-
-    with pytest.raises(ScheduleEvidenceError):
-        cycles_by_scope("ENTRY %m () -> f32[] {}", {"a": "x"}, strict=True)
-    # non-strict keeps the old degrade-to-zero behavior for diagnostics
-    assert cycles_by_scope(
-        "ENTRY %m () -> f32[] {}", {"a": "x"}
-    )["total_cycles"] == 0
-
-
 def test_cpu_fabric_note(devices):
     note = cpu_fabric_note()
     assert note["physical_cores"] >= 1

@@ -1,0 +1,400 @@
+"""What the compiled step, the profiler's trace and the program's spans
+are named by (PR 25): the ``jax.named_scope`` names of
+``observability/scopes.py`` reach the HLO ``op_name`` of the train step,
+the three Pallas kernels have three names, ``Tracer`` spans enter the
+profiler's trace as ``ddp:<name>`` and nest per thread, the loader spans
+where the work happens, and ``dpp.py --profile-steps`` captures them."""
+
+import collections
+import os
+import re
+import sys
+import threading
+import types
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import distributeddataparallel_tpu as ddp  # noqa: E402
+from benchmarks import scope_reduce  # noqa: E402
+from distributeddataparallel_tpu.data import SyntheticClassification  # noqa: E402
+from distributeddataparallel_tpu.data.loader import DataLoader, shard_batch  # noqa: E402
+from distributeddataparallel_tpu.models.transformer import (  # noqa: E402
+    TransformerLM,
+    gpt2_124m,
+)
+from distributeddataparallel_tpu.observability import (  # noqa: E402
+    Tracer,
+    get_tracer,
+    scopes,
+    set_tracer,
+)
+from distributeddataparallel_tpu.ops import accuracy, lm_cross_entropy  # noqa: E402
+
+# ------------------------------------------------- scopes in the compiled step
+
+#: instructions that do the step's work; the rest (bitcasts, constants,
+#: parameters, tuples) takes no device time
+_WORK = re.compile(
+    r" (?:fusion|dot|convolution|reduce|reduce-window|all-reduce|"
+    r"all-gather|reduce-scatter|custom-call|scatter|gather)\("
+)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _compiled_step_text(n_devices: int, scan_layers: bool) -> str:
+    cfg = gpt2_124m(
+        vocab_size=128, d_model=32, num_layers=2, num_heads=2, d_ff=64,
+        max_seq_len=16, scan_layers=scan_layers, attn_impl="xla",
+    )
+    model = TransformerLM(cfg)
+    mesh = ddp.make_mesh(("data",), devices=jax.devices()[:n_devices])
+
+    def loss_fn(params, batch, rng):
+        logits = model.apply({"params": params}, batch["tokens"][:, :-1])
+        targets = batch["tokens"][:, 1:]
+        return lm_cross_entropy(logits, targets), {
+            "accuracy": accuracy(logits, targets)
+        }
+
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    state = ddp.broadcast_params(
+        ddp.TrainState.create(
+            apply_fn=model.apply, params=params, tx=optax.adamw(1e-3)
+        ),
+        mesh,
+    )
+    step = ddp.make_train_step(loss_fn, mesh=mesh, grad_clip=1.0)
+    batch = shard_batch(
+        {"tokens": jnp.zeros((2 * n_devices, 17), jnp.int32)}, mesh
+    )
+    return step.lower(state, batch, jax.random.PRNGKey(0)).compile().as_text()
+
+
+@pytest.mark.parametrize("scan_layers", [False, True],
+                         ids=["unrolled", "scan_layers"])
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_compiled_step_carries_every_scope(devices, n_devices, scan_layers):
+    text = _compiled_step_text(n_devices, scan_layers)
+    found = collections.Counter()
+    work = collections.Counter()
+    for line in text.splitlines():
+        scope = _OP_NAME.search(line)
+        scope = scope.group(1) if scope else ""
+        bucket = scope_reduce.bucket_of(scope)
+        found[bucket] += 1
+        if _WORK.search(line) and " = " in line:
+            work[bucket] += 1
+    expected = {"embed", "head", "loss", "metrics", "grad_clip", "optimizer",
+                "attn", "mlp", "norm", "block"}
+    if n_devices > 1:
+        expected.add("grad_sync")
+    assert expected <= set(found), sorted(expected - set(found))
+    # on one device the exchange is a collective over one replica, which
+    # the TPU compiler drops: grad_sync is asked for across devices only
+    if n_devices > 1:
+        exchanged = [ln for ln in text.splitlines() if " all-reduce(" in ln]
+        assert exchanged and all(
+            scope_reduce.bucket_of(_OP_NAME.search(ln).group(1))
+            in ("grad_sync", "metrics", "grad_clip")
+            for ln in exchanged
+        )
+    # forward and backward are told apart by what JAX itself writes
+    assert "transpose(jvp(" in text and "/jvp(" in text
+    total = sum(work.values())
+    assert total > 20
+    assert work[scope_reduce.OTHER] <= 0.10 * total, work
+
+
+def test_every_scope_constant_falls_in_exactly_one_bucket():
+    for name in scopes.STEP_SCOPES + scopes.KERNEL_NAMES:
+        for path in (f"jit(step)/jvp(M)/{name}/add",
+                     f"jit(step)/shard_map/transpose(jvp({name}))/mul"):
+            hits = [b for b, rx in scope_reduce.BUCKETS
+                    if re.search(rx, path)]
+            assert len(hits) == 1, (name, path, hits)
+    table = dict(scope_reduce.BUCKETS)
+    assert set(scope_reduce.UPDATE_BUCKETS) == {
+        scopes.GRAD_SYNC, scopes.GRAD_CLIP, scopes.OPTIMIZER
+    } and set(scope_reduce.UPDATE_BUCKETS) <= set(table)
+    # what Flax names itself, relied on and not re-wrapped
+    for path, bucket in [
+        ("jit(s)/jvp(M)/layer_1/attn/q_proj/dot_general", "attn"),
+        ("jit(s)/transpose(jvp(M))/layer_1/mlp/up_proj/reduce_sum", "mlp"),
+        ("jit(s)/jvp(M)/layer_0/attn_norm/rsqrt", "norm"),
+        ("jit(s)/jvp(M)/final_norm/add", "norm"),
+        ("jit(s)/jvp(M)/layer_0/add", "block"),
+        ("jit(s)/jvp(M)/while/body/closed_call/layers/block/add", "block"),
+        ("jit(s)/jvp(M)/while/body/dynamic_slice", "block"),
+        ("jit(s)/jvp(M)/layer_3/attn/flash_bwd_dkv/pallas_call",
+         "attn_kernel.dkv"),
+        ("jit(s)/shard_map", "other"), ("", "other"),
+    ]:
+        assert scope_reduce.bucket_of(path) == bucket, path
+    assert scope_reduce.phase_of("a/transpose(jvp(M))/mlp/x", "mlp") == "bwd"
+    assert scope_reduce.phase_of("a/jvp(M)/mlp/x", "mlp") == "fwd"
+    assert scope_reduce.phase_of("a/optimizer/x", "optimizer") == "update"
+
+
+def test_three_pallas_calls_have_three_names():
+    from distributeddataparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, True).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+    kernels = re.findall(r"\bname=(\w+)", text)
+    assert sorted(set(kernels) & set(scopes.KERNEL_NAMES)) == sorted(
+        scopes.KERNEL_NAMES
+    ), kernels
+    assert text.count("pallas_call[") == 3
+
+
+# ---------------------------------------------------------------- the tracer
+
+class _Recorder:
+    """Stands in for an ``EventLog``: keeps what a tracer emits."""
+
+    def __init__(self):
+        self.records = []
+
+    def emit(self, kind, **fields):
+        self.records.append(dict(fields, kind=kind))
+
+    def names(self):
+        return collections.Counter(r["name"] for r in self.records)
+
+    def parents(self):
+        return {r["name"]: r["parent"] for r in self.records}
+
+
+def test_tracer_keeps_parent_and_depth_and_stores_nothing_itself():
+    rec = _Recorder()
+    tr = Tracer(rec)
+    for i in range(50):
+        with tr.span("step", step=i):
+            with tr.span("settle"):
+                assert tr.depth == 2
+    assert tr.depth == 0
+    assert rec.names() == {"step": 50, "settle": 50}
+    last, before = rec.records[-1], rec.records[-2]
+    assert (last["name"], last["parent"], last["depth"]) == ("step", None, 0)
+    assert (before["name"], before["parent"], before["depth"]) == (
+        "settle", "step", 1)
+    assert last["step"] == 49 and last["dur_s"] >= before["dur_s"] >= 0
+    # with a registry, one histogram per span name
+    from distributeddataparallel_tpu.observability import MetricsRegistry
+
+    reg = MetricsRegistry()
+    tr2 = Tracer(None, reg)
+    with tr2.span("loader.batch"):
+        pass
+    assert reg.snapshot()["span_loader_batch_s"]["count"] == 1
+    # with nowhere to write, a span leaves nothing behind in the tracer:
+    # no ring, no counters, nothing that grows over a long run
+    bare = Tracer()
+    before = dict(vars(bare))
+    for _ in range(50):
+        with bare.span("step"):
+            pass
+    assert vars(bare) == before and set(before) == {
+        "events", "registry", "_local"}
+
+
+def test_tracer_nests_per_thread():
+    rec = _Recorder()
+    tr = Tracer(rec)
+    inside = threading.Event()
+    release = threading.Event()
+
+    def producer():
+        with tr.span("loader.batch"):
+            inside.set()
+            release.wait(5)
+
+    t = threading.Thread(target=producer)
+    with tr.span("epoch"):
+        t.start()
+        assert inside.wait(5)
+        # the other thread's open span is not this thread's parent
+        with tr.span("step"):
+            assert tr.depth == 2
+        release.set()
+        t.join()
+    assert rec.parents() == {
+        "loader.batch": None, "step": "epoch", "epoch": None}
+
+
+def test_tracer_enters_a_trace_annotation_when_jax_is_loaded(monkeypatch):
+    entered = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **attrs):
+            self.name, self.attrs = name, attrs
+
+        def __enter__(self):
+            entered.append(("in", self.name, self.attrs))
+
+        def __exit__(self, *exc):
+            entered.append(("out", self.name))
+            return False
+
+    fake = types.ModuleType("jax")
+    fake.profiler = types.SimpleNamespace(TraceAnnotation=FakeAnnotation)
+    monkeypatch.setitem(sys.modules, "jax", fake)
+    tr = Tracer()
+    with tr.span("step", step=3):
+        with tr.span("settle"):
+            pass
+    assert entered == [
+        ("in", "ddp:step", {"step": 3}), ("in", "ddp:settle", {}),
+        ("out", "ddp:settle"), ("out", "ddp:step"),
+    ]
+    # without jax in the process a span is two clock reads, no import
+    monkeypatch.delitem(sys.modules, "jax")
+    entered.clear()
+    with tr.span("step"):
+        pass
+    assert entered == [] and "jax" not in sys.modules
+
+
+def test_spans_from_many_threads_lose_nothing(tmp_path):
+    """The loader's producer emits its spans beside the train loop's: the
+    event log's ``seq`` stays a total order and no line is torn, with
+    more threads than cores and a short switch interval."""
+    from distributeddataparallel_tpu.observability import (
+        EventLog,
+        read_events,
+        validate_file,
+    )
+
+    path = str(tmp_path / "events-p0.jsonl")
+    n_threads, per_thread = 2 * (os.cpu_count() or 4), 200
+    previous = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with EventLog(path, 0) as ev:
+            logged = Tracer(ev)
+
+            def work():
+                for i in range(per_thread):
+                    with logged.span("loader.batch", i=i):
+                        pass
+
+            threads = [threading.Thread(target=work) for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(previous)
+    records = read_events(path)
+    assert len(records) == n_threads * per_thread
+    assert sorted(r["seq"] for r in records) == list(range(len(records)))
+    assert all(r["depth"] == 0 and r["parent"] is None for r in records)
+    assert validate_file(path) == []
+
+
+# ---------------------------------------------------------------- the loader
+
+@pytest.mark.parametrize("workers", [0, 1], ids=["inline", "threaded"])
+def test_loader_emits_one_batch_span_a_batch(devices, workers):
+    mesh = ddp.make_mesh(("data",))
+    ds = SyntheticClassification(num_examples=256, shape=(4, 4, 1), seed=0)
+    loader = DataLoader(ds, per_replica_batch=4, mesh=mesh, workers=workers)
+    previous = get_tracer()
+    rec = _Recorder()
+    set_tracer(Tracer(rec))
+    try:
+        batches = list(loader)
+    finally:
+        set_tracer(previous)
+    assert len(batches) == len(loader) == 8
+    # the one span of the loader, once a batch, on whichever thread makes
+    # the batch (the producer's own stack: no parent)
+    assert rec.names() == {"loader.batch": 8}
+    assert rec.parents() == {"loader.batch": None}
+
+
+# -------------------------------------------------------------------- dpp.py
+
+def test_profile_steps_alone_has_a_live_tracer_and_captures_its_spans(
+    devices, tmp_path, monkeypatch
+):
+    import dpp
+    from benchmarks import trace_reduce
+    from distributeddataparallel_tpu import observability
+
+    installed = []
+    monkeypatch.setattr(
+        observability, "set_tracer",
+        lambda tracer: installed.append(set_tracer(tracer)) or installed[-1],
+    )
+    previous = get_tracer()
+    args = dpp.parse_args([
+        "--device", "cpu", "--dataset", "synthetic", "--model", "mlp",
+        "--num-examples", "256", "--batch-size", "4", "--epochs", "1",
+        "--log-every", "1000", "--profile-steps", "2:5",
+        "--profile-dir", str(tmp_path / "xprof"),
+    ])
+    try:
+        dpp.train(args)
+        # the run's tracer left with the run
+        assert get_tracer() is installed[-1] is not installed[0]
+    finally:
+        set_tracer(previous)
+    # installed once, inside the run, and with no event log behind it
+    assert len(installed) == 2 and installed[0] is not previous
+    assert installed[0].events is None and installed[0].registry is None
+    trace = trace_reduce.load_xplane(
+        trace_reduce.find_xplane(str(tmp_path / "xprof"))
+    )
+    host = collections.Counter(
+        name for plane in trace["planes"] for line in plane["lines"]
+        for name, _, _ in line["events"]
+    )
+    assert host["ddp:step"] == 3, host["ddp:step"]  # steps 2, 3, 4
+    assert host["ddp:loader.batch"] >= 1 and host["ddp:settle"] >= 1
+    assert any(name.startswith("PjitFunction(") for name in host)
+
+
+def test_a_run_that_fails_in_set_up_leaves_no_tracer_installed(
+    devices, monkeypatch
+):
+    import dpp
+    from distributeddataparallel_tpu import observability
+    from distributeddataparallel_tpu.data import loader as loader_mod
+
+    installed = []
+    monkeypatch.setattr(
+        observability, "set_tracer",
+        lambda tracer: installed.append(set_tracer(tracer)) or installed[-1],
+    )
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("no loader today")
+
+    monkeypatch.setattr(loader_mod.DataLoader, "__init__", broken)
+    previous = get_tracer()
+    args = dpp.parse_args([
+        "--device", "cpu", "--dataset", "synthetic", "--model", "mlp",
+        "--num-examples", "64", "--batch-size", "4", "--epochs", "1",
+    ])
+    try:
+        with pytest.raises(RuntimeError, match="no loader today"):
+            dpp.train(args)
+        assert installed == [] and get_tracer() is previous
+    finally:
+        set_tracer(previous)
+        ddp.destroy_process_group()  # set-up had opened it
