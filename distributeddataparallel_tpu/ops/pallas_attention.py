@@ -5,14 +5,25 @@ The reference reaches its attention-free compute through cuDNN kernels
 the LM configs (BASELINE 4-5), written against the Pallas TPU guide
 (/opt/skills/guides/pallas_guide.md):
 
-- Grid (batch*heads, q_blocks, kv_blocks), kv innermost; q/k/v tiles are
-  DMA'd HBM→VMEM by BlockSpec, matmuls hit the MXU with
-  ``preferred_element_type=float32``.
+- Forward: grid (batch*heads, q_blocks, kv_fetches).  K/V of a
+  (batch, head) row are fetched as ONE block where they fit
+  (``_fwd_plan``: all of Skv at the training shapes, so kv_fetches = 1
+  and no grid step is dead) and walked in score tiles by in-kernel loops
+  whose trip counts stop at the diagonal (``_kv_span``): the tiles below
+  it run without a mask, the tiles it crosses with one, the tiles above
+  it are never entered.  Where K/V need several fetches, a step above
+  the diagonal names the block already in VMEM, so it issues no DMA.
+  ``fwd_tile_counts`` says how often each path engages, from shapes.
+- Matmuls hit the MXU with ``preferred_element_type=float32``; q/k/v
+  blocks are DMA'd HBM→VMEM by BlockSpec.
 - Online softmax: VMEM scratch carries the running max ``m``, normalizer
-  ``l``, and f32 accumulator across kv blocks, so the (S, S) score matrix
-  is never materialized — O(S) memory instead of O(S²).
-- Causal blocks strictly above the diagonal are skipped with ``pl.when``
-  (predicated off — no MXU work, no DMA dependency stalls).
+  ``l``, and f32 accumulator across kv tiles, so the (S, S) score matrix
+  is never materialized — O(S) memory instead of O(S²).  In the forward
+  the row statistics stay (rows, 128) lane-replicated from the reduction
+  to the store (a 1-D row vector costs a relayout a tile).
+- Backward kernels: grid (batch*heads, q_blocks, kv_blocks); causal
+  blocks strictly above the diagonal are skipped with ``pl.when``
+  (predicated off — no MXU work; their K/V blocks are still fetched).
 - Backward: ``custom_vjp`` saving (q, k, v, out, lse); gradients use the
   standard flash-attention identities with the saved log-sum-exp,
   recomputing probability tiles BLOCKWISE in two Pallas kernels (the
@@ -28,6 +39,8 @@ CPU tests run the same kernel under ``interpret=True``.
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +48,8 @@ from jax.experimental import pallas as pl
 
 from distributeddataparallel_tpu.observability import scopes
 from distributeddataparallel_tpu.ops.attention import NEG_INF
+
+_LANES = 128
 
 
 def _pick_block(s: int, preferred: tuple[int, ...] = (512, 256, 128)) -> int | None:
@@ -81,6 +96,83 @@ def _block_live(i, j, *, causal: bool, block_q: int, block_k: int, q_offset: int
     return (not causal) or (j * block_k <= q_last)
 
 
+def _block_unmasked(i, j, *, causal: bool, block_q: int, block_k: int, q_offset: int):
+    """The (q block i, kv block j) tile lies wholly at or below the
+    diagonal — its last key is visible to its first query — so it needs
+    no mask.  Non-causal tiles never do."""
+    q_first = q_offset + i * block_q
+    return (not causal) or (j * block_k + block_k - 1 <= q_first)
+
+
+def _kv_span(i, *, causal: bool, block_q: int, block_k: int, q_offset: int, n_k: int):
+    """``(full, live)`` for q block ``i``: kv tiles ``[0, full)`` are
+    ``_block_unmasked``, ``[full, live)`` are live and crossed by the
+    diagonal, ``[live, n_k)`` are dead — the two predicates counted in
+    closed form (both are monotone in j), so a loop can stop where a grid
+    would test.  ``i`` may be a Python int or a traced scalar."""
+    if not causal:
+        return n_k, n_k
+    q_first = q_offset + i * block_q
+    return (q_first + 1) // block_k, (q_first + block_q - 1) // block_k + 1
+
+
+class FwdPlan(NamedTuple):
+    """The forward kernel's tiles, from shapes alone (``_fwd_plan``)."""
+
+    block_q: int   # q rows a grid step owns
+    block_k: int   # kv rows of one score tile, the inner loop's stride
+    block_kv: int  # kv rows fetched a grid step (a multiple of block_k)
+
+
+class TileCounts(NamedTuple):
+    """Per (batch*head) row: score tiles run without a mask, with one,
+    never entered; and the grid steps that row's q blocks launch."""
+
+    unmasked: int
+    masked: int
+    skipped: int
+    steps: int
+
+
+#: K or V rows fetched a grid step stay under this (each is double
+#: buffered, so K and V together hold four times it in VMEM)
+_KV_FETCH_BYTES = 1 << 20
+
+
+def _fwd_plan(Sq: int, Skv: int, D: int, itemsize: int) -> FwdPlan:
+    """The forward's tile plan, chosen from what the operands show.
+
+    Score tiles are ``_pick_block`` squares as in the backward kernels.
+    K/V are fetched as one block for the whole q block where that fits
+    ``_KV_FETCH_BYTES`` (all of Skv at the training shapes: no dead grid
+    steps), else in the largest multiple of the tile that divides Skv.
+    """
+    block_q = _pick_block(Sq)
+    block_k = _pick_block(Skv)
+    n_k = Skv // block_k
+    fit = max(1, _KV_FETCH_BYTES // (block_k * D * itemsize))
+    per_fetch = max(r for r in range(1, n_k + 1) if n_k % r == 0 and r <= fit)
+    return FwdPlan(block_q, block_k, per_fetch * block_k)
+
+
+def fwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
+                    plan: FwdPlan) -> TileCounts:
+    """How often each path of the forward kernel engages — static, like
+    the mechanism: ``_kv_span`` summed over the q blocks."""
+    n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
+    spans = [
+        _kv_span(i, causal=causal, block_q=plan.block_q,
+                 block_k=plan.block_k, q_offset=q_offset, n_k=n_k)
+        for i in range(n_q)
+    ]
+    unmasked = sum(full for full, _ in spans)
+    live = sum(live for _, live in spans)
+    return TileCounts(
+        unmasked, live - unmasked, n_q * n_k - live,
+        n_q * (Skv // plan.block_kv),
+    )
+
+
 def _causal_mask_scores(s, i, j, *, block_q: int, block_k: int, q_offset: int):
     """Mask the (BQ, BK) score tile above the diagonal with NEG_INF —
     the single in-kernel statement of the position convention (one copy,
@@ -94,57 +186,110 @@ def _causal_mask_scores(s, i, j, *, block_q: int, block_k: int, q_offset: int):
     return jnp.where(k_pos <= q_pos, s, NEG_INF)
 
 
+def _lanes(x, n: int):
+    """A lane-replicated (rows, 128) statistic at width ``n``: a lane slice
+    down, a lane repeat up — never through a 1-D row vector."""
+    if n <= _LANES:
+        return x if n == _LANES else x[:, :n]
+    if n % _LANES == 0:
+        return jnp.concatenate([x] * (n // _LANES), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _flash_kernel(
-    q_ref, k_ref, v_ref,  # (1, BQ, D), (1, BK, D), (1, BK, D)
+    q_ref, k_ref, v_ref,  # (1, BQ, D), (1, BKV, D), (1, BKV, D)
     o_ref,                # (1, BQ, D)
     lse_ref,              # (1, 8, BQ) — lse broadcast over 8 sublanes to
                           # satisfy the TPU (8, 128) block-tiling minimum
     m_ref, l_ref, acc_ref,  # VMEM scratch: (BQ, 128), (BQ, 128), (BQ, D)
-    *, causal: bool, block_q: int, block_k: int, scale: float, q_offset: int,
+    *, causal: bool, block_k: int, scale: float, q_offset: int,
 ):
-    i = pl.program_id(1)  # q block index
-    j = pl.program_id(2)  # kv block index
-    nj = pl.num_programs(2)
+    """One grid step owns one q block of one (batch*head) row and one
+    fetched K/V block of ``BKV`` rows, and walks that block in score tiles
+    of ``block_k`` rows: first the tiles below the diagonal (no mask),
+    then the tiles the diagonal crosses; tiles above it are never entered.
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
+    The row statistics m, l, the correction and lse are (BQ, 128)
+    lane-replicated from the reduction (``keepdims``) to the store.  The
+    scratch has no initial state: kv tile 0, which every q block sees,
+    writes it (``opening``) where the others update it.
+    """
+    _, block_q, D = q_ref.shape
+    per_fetch = k_ref.shape[1] // block_k
+    i = pl.program_id(1)   # q block index
+    jf = pl.program_id(2)  # fetched K/V block index
+    nf = pl.num_programs(2)
     geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
+    full, live = _kv_span(i, causal=causal, n_k=per_fetch * nf, **geom)
+    first = jf * per_fetch  # the kv tile this fetch starts at
 
-    @pl.when(_block_live(i, j, causal=causal, **geom))
-    def _body():
-        q = q_ref[0]  # (BQ, D)
-        k = k_ref[0]  # (BK, D)
+    q = q_ref[0]  # (BQ, D)
+    # scale moves onto q where that is bit-exact (a power of two, 2^-3 at
+    # D = 64): one (BQ, D) multiply a step, not one (BQ, BK) a tile
+    fold = math.frexp(scale)[0] == 0.5
+    if fold:
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+    def tile(j, *, masked: bool, opening: bool = False):
+        rows = pl.ds(pl.multiple_of((j - first) * block_k, block_k), block_k)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k_ref[0, rows, :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # (BQ, BK)
-        if causal:
+        )  # (BQ, BK)
+        if not fold:
+            s = s * scale
+        if masked:
             s = _causal_mask_scores(s, i, j, **geom)
-
-        m_prev = m_ref[:, 0]                      # (BQ,)
-        m_cur = jnp.max(s, axis=1)                # (BQ,)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])           # (BQ, BK)
-        correction = jnp.exp(m_prev - m_new)      # (BQ,)
-        l_new = correction * l_ref[:, 0] + jnp.sum(p, axis=1)
-        acc_ref[:] = acc_ref[:] * correction[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        m_cur = jnp.max(s, axis=1, keepdims=True)           # (BQ, 1)
+        if opening:
+            m_new = jnp.broadcast_to(m_cur, m_ref.shape)    # (BQ, 128)
+        else:
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - _lanes(m_new, block_k))             # (BQ, BK)
+        l_cur = jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, rows, :],
+            (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        )  # (BQ, D)
+        if opening:
+            l_ref[...] = jnp.broadcast_to(l_cur, l_ref.shape)
+            acc_ref[...] = pv
+        else:
+            correction = jnp.exp(m_prev - m_new)            # (BQ, 128)
+            l_ref[...] = correction * l_ref[...] + l_cur
+            acc_ref[...] = acc_ref[...] * _lanes(correction, D) + pv
+        m_ref[...] = m_new
 
-    @pl.when(j == nj - 1)
+    def run(lo, hi, *, masked: bool):
+        """kv tiles [lo, hi) that lie in this step's fetched block."""
+        lo = jnp.maximum(lo, first)
+        hi = jnp.minimum(hi, first + per_fetch)
+        jax.lax.fori_loop(lo, hi, lambda j, _: tile(j, masked=masked), None)
+
+    @pl.when(jf == 0)
+    def _open():
+        if causal:
+            jax.lax.cond(
+                full > 0,
+                lambda: tile(0, masked=False, opening=True),
+                lambda: tile(0, masked=True, opening=True),
+            )
+        else:
+            tile(0, masked=False, opening=True)
+
+    run(1, full, masked=False)
+    if causal:
+        run(jnp.maximum(full, 1), live, masked=True)
+
+    @pl.when(jf == nf - 1)
     def _finish():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe[:, None]).astype(o_ref.dtype)
-        lse = m_ref[:, 0] + jnp.log(l_safe)  # (BQ,)
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+        o_ref[0] = (acc_ref[...] / _lanes(l_safe, D)).astype(o_ref.dtype)
+        lse = m_ref[...] + jnp.log(l_safe)  # (BQ, 128)
+        lse_ref[0] = lse.T[:8]              # lanes are equal: (8, BQ)
 
 
 def _gqa_kv_row(b, *, H: int, Hkv: int):
@@ -161,9 +306,7 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool):
     Hkv = k.shape[2]
     if H % Hkv:
         raise ValueError(f"num_heads {H} not a multiple of kv heads {Hkv}")
-    block_q = _pick_block(Sq)
-    block_k = _pick_block(Skv)
-    if block_q is None or block_k is None:
+    if _pick_block(Sq) is None or _pick_block(Skv) is None:
         raise ValueError(f"seq lens ({Sq}, {Skv}) not divisible by 128")
     if causal and Sq > Skv:
         raise ValueError(
@@ -171,52 +314,91 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool):
             f"the END of the kv sequence); got Sq={Sq} > Skv={Skv}, which "
             f"leaves rows with no visible keys"
         )
-    scale = 1.0 / (D ** 0.5)
-
     # (B, S, H, D) -> (B*H, S, D): one grid row per (batch, head); kv
     # stays at its own (smaller) head count.
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Skv, D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Skv, D)
-    kv_row = functools.partial(_gqa_kv_row, H=H, Hkv=Hkv)
-
-    grid = (B * H, Sq // block_q, Skv // block_k)
-    kernel = functools.partial(
-        _flash_kernel,
-        causal=causal, block_q=block_q, block_k=block_k, scale=scale,
-        q_offset=Skv - Sq,
+    out, lse = _fwd_launch(
+        qf, kf, vf, H=H, Hkv=Hkv, causal=causal, interpret=interpret
     )
-    from jax.experimental.pallas import tpu as pltpu
-
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (kv_row(b), j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (kv_row(b), j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, 8, Sq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        interpret=interpret,
-        name=scopes.FLASH_FWD,
-    )(qf, kf, vf)
     out = out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     # lse stays in its (B*H, 8, Sq) sublane-broadcast layout: the backward
     # kernels consume exactly this shape, so saving it unsliced avoids a
     # slice here and a re-broadcast (extra HBM copy) per backward pass.
     return out, lse
+
+
+@functools.partial(
+    jax.jit, static_argnames=("H", "Hkv", "causal", "interpret")
+)
+def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool):
+    """The forward's one ``pallas_call``, on flat (rows, S, D) operands.
+
+    Jitted on its own so that a model's layers share one trace and one
+    lowering of the kernel (every layer calls it with the same shapes;
+    without this each call traces and lowers the kernel again, which is
+    seconds of a 24-layer step's set-up).  Only the kernel is inside: the
+    transposes round it stay in the caller's scope.
+    """
+    rows, Sq, D = qf.shape
+    Skv = kf.shape[1]
+    scale = 1.0 / (D ** 0.5)
+    q_offset = Skv - Sq
+    plan = _fwd_plan(Sq, Skv, D, qf.dtype.itemsize)
+    counts = fwd_tile_counts(Sq, Skv, causal, q_offset, plan)
+    block_q, block_k, block_kv = plan
+    per_fetch = block_kv // block_k
+    kv_row = functools.partial(_gqa_kv_row, H=H, Hkv=Hkv)
+
+    def kv_index(b, i, jf):
+        if causal and Skv > block_kv:
+            # a step above the diagonal names the block already in VMEM:
+            # no DMA is issued for K/V it will not read
+            _, live = _kv_span(
+                i, causal=True, block_q=block_q, block_k=block_k,
+                q_offset=q_offset, n_k=Skv // block_k,
+            )
+            jf = jnp.minimum(jf, (live - 1) // per_fetch)
+        return (kv_row(b), jf, 0)
+
+    kernel = functools.partial(
+        _flash_kernel,
+        causal=causal, block_k=block_k, scale=scale, q_offset=q_offset,
+    )
+    from jax.experimental.pallas import tpu as pltpu
+
+    tiles = rows * (counts.unmasked + counts.masked)
+    return pl.pallas_call(
+        kernel,
+        grid=(rows, Sq // block_q, Skv // block_kv),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, i, jf: (b, i, 0)),
+            pl.BlockSpec((1, block_kv, D), kv_index),
+            pl.BlockSpec((1, block_kv, D), kv_index),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, i, jf: (b, i, 0)),
+            pl.BlockSpec((1, 8, block_q), lambda b, i, jf: (b, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, Sq, D), qf.dtype),
+            jax.ShapeDtypeStruct((rows, 8, Sq), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+        ],
+        cost_estimate=pl.CostEstimate(
+            flops=tiles * 4 * block_q * block_k * D,
+            transcendentals=tiles * block_q * block_k,
+            bytes_accessed=(2 * qf.size + kf.size + vf.size) * qf.dtype.itemsize
+            + rows * 8 * Sq * 4,
+        ),
+        interpret=interpret,
+        name=scopes.FLASH_FWD,
+    )(qf, kf, vf)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -176,8 +176,10 @@ def test_flash_decode_shape_grads_match_reference():
 def test_flash_backward_memory_is_linear_in_seq():
     """Long-context guarantee: backward peak temp memory must scale O(S),
     not O(S²) — the blockwise kernels never materialize the (S, S)
-    probability matrix (an O(S²) backward at S=2048 needs >100 MB here;
-    the blockwise one a few MB)."""
+    probability matrix (an O(S²) backward at S=4096 needs >500 MB here;
+    the blockwise one a few MB).  Read from S=1024 up: at S=512 the
+    forward's grid is a single q block, whose loop XLA:CPU unrolls, and
+    the constant that leaves behind would sit in the first increment."""
 
     def temp_bytes(S):
         def loss(q, k, v):
@@ -192,11 +194,11 @@ def test_flash_backward_memory_is_linear_in_seq():
             pytest.skip("backend exposes no memory analysis")
         return analysis.temp_size_in_bytes
 
-    m512, m1024, m2048 = temp_bytes(512), temp_bytes(1024), temp_bytes(2048)
+    m1024, m2048, m4096 = temp_bytes(1024), temp_bytes(2048), temp_bytes(4096)
     # Linear growth: each doubling adds ~2x the previous increment.
     # Quadratic growth would multiply increments by ~4 and blow past this.
-    assert m2048 - m1024 < 3 * (m1024 - m512) + (1 << 20), (m512, m1024, m2048)
-    assert m2048 < 8 * m512, (m512, m2048)
+    assert m4096 - m2048 < 3 * (m2048 - m1024) + (1 << 20), (m1024, m2048, m4096)
+    assert m4096 < 8 * m1024, (m1024, m4096)
 
 
 def test_flash_rejects_causal_sq_gt_skv():
@@ -242,3 +244,223 @@ def test_flash_gqa_grads_match_repeated_reference():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-4, err_msg=f"d{name}"
         )
+
+
+# --- the forward's tile plan (PR 26) -------------------------------------
+
+
+def _ref_out_lse(q, k, v, causal):
+    """``dot_product_attention`` at "highest", and the log-sum-exp of the
+    same scaled, masked scores as (B, H, Sq)."""
+    group = q.shape[2] // k.shape[2]
+    k, v = repeat_kv(k, group), repeat_kv(v, group)
+    Sq, Skv, D = q.shape[1], k.shape[1], q.shape[3]
+    with jax.default_matmul_precision("highest"):
+        out = dot_product_attention(q, k, v, causal=causal)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+    if causal:
+        s = s + causal_mask_bias(Sq, Skv, q_offset=Skv - Sq)
+    return out, jax.nn.logsumexp(s, axis=-1)
+
+
+#: name -> (B, Sq, Skv, H, Hkv, D): the shapes where the plan changes
+#: character
+_PLAN_SHAPES = {
+    "one_tile": (1, 128, 128, 2, 2, 16),
+    "3x3_of_128": (1, 384, 384, 2, 2, 16),
+    "cell_d64": (1, 1024, 1024, 2, 2, 64),
+    "d128": (1, 1024, 1024, 1, 1, 128),
+    "decode_block_multiple": (1, 512, 1024, 2, 2, 16),   # q_offset 512
+    "decode_inside_a_block": (1, 384, 1024, 2, 2, 16),   # q_offset 640
+    "decode_one_q_tile": (1, 128, 512, 2, 2, 16),        # q_offset 384
+    "gqa_4_to_1": (2, 256, 256, 4, 1, 16),
+}
+
+
+def _plan_inputs(name):
+    B, Sq, Skv, H, Hkv, D = _PLAN_SHAPES[name]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(26), 3)
+    return (
+        jax.random.normal(kq, (B, Sq, H, D), jnp.float32),
+        jax.random.normal(kk, (B, Skv, Hkv, D), jnp.float32),
+        jax.random.normal(kv, (B, Skv, Hkv, D), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("name", list(_PLAN_SHAPES))
+def test_flash_forward_out_and_lse_match_reference(name, causal):
+    q, k, v = _plan_inputs(name)
+    B, Sq, H, _ = q.shape
+    out, lse = pallas_attention._flash_fwd_impl(
+        q, k, v, causal=causal, interpret=True
+    )
+    ref_out, ref_lse = _ref_out_lse(q, k, v, causal)
+    assert lse.shape == (B * H, 8, Sq) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), atol=2e-5)
+    # every one of the 8 sublanes carries the row's lse
+    np.testing.assert_allclose(
+        np.asarray(lse.reshape(B, H, 8, Sq)),
+        np.broadcast_to(np.asarray(ref_lse)[:, :, None], (B, H, 8, Sq)),
+        atol=2e-5,
+    )
+
+
+@pytest.mark.parametrize("name", ["3x3_of_128", "decode_inside_a_block"])
+def test_flash_grads_match_reference_where_plan_changes(name):
+    """The backward kernels read the forward's lse."""
+    q, k, v = _plan_inputs(name)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(pallas_attention.flash_attention(q, k, v, True, True) ** 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(dot_product_attention(q, k, v, causal=True) ** 2)
+
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for n, a, b in zip("qkv", gf, gr):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-4, err_msg=f"d{n}"
+        )
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_forward_kv_fetched_in_several_blocks(causal, monkeypatch):
+    """K/V longer than one fetch: the kv grid axis is back, the inner
+    loops clip to the fetched block, and a step above the diagonal names
+    the block already there.  Steered here by shrinking the fetch budget
+    to one 128-row tile of D=16 float32."""
+    monkeypatch.setattr(pallas_attention, "_KV_FETCH_BYTES", 128 * 16 * 4)
+    q, k, v = _plan_inputs("3x3_of_128")
+    plan = pallas_attention._fwd_plan(384, 384, 16, 4)
+    assert plan == (128, 128, 128)
+    assert pallas_attention.fwd_tile_counts(384, 384, causal, 0, plan).steps == 9
+    # the jitted launch keeps its traces by shape: drop the one-fetch
+    # trace of this shape before, and this test's trace after
+    pallas_attention._fwd_launch.clear_cache()
+    try:
+        jaxpr = str(jax.make_jaxpr(
+            lambda q, k, v: pallas_attention._flash_fwd_impl(
+                q, k, v, causal=causal, interpret=True
+            )
+        )(q, k, v))
+        assert "grid=(2, 3, 3)" in jaxpr, "the kv axis is not in the grid"
+        out, lse = pallas_attention._flash_fwd_impl(
+            q, k, v, causal=causal, interpret=True
+        )
+    finally:
+        pallas_attention._fwd_launch.clear_cache()
+    ref_out, ref_lse = _ref_out_lse(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(lse[:, 0].reshape(ref_lse.shape)), np.asarray(ref_lse),
+        atol=2e-5,
+    )
+
+
+@pytest.mark.parametrize(
+    "Sq,Skv,D,itemsize,causal,plan,counts",
+    [
+        # the benchmark cell: 1 / 2 / 1 of 4 tiles, 2 steps a row (the
+        # parent launched 4: 512 a call of 128 rows, now 256)
+        (1024, 1024, 64, 2, True, (512, 512, 1024), (1, 2, 1, 2)),
+        (1024, 1024, 64, 2, False, (512, 512, 1024), (4, 0, 0, 2)),
+        # decode: 128 queries at the end of 1024 keys (q_offset 896)
+        (128, 1024, 64, 2, True, (128, 512, 1024), (1, 1, 0, 1)),
+        (384, 384, 64, 2, True, (128, 128, 384), (3, 3, 3, 3)),
+        # K/V past the fetch budget (1 MiB each): two fetches a q block
+        (2048, 8192, 128, 2, True, (512, 512, 4096), (54, 4, 6, 8)),
+    ],
+)
+def test_fwd_tile_plan_counts(Sq, Skv, D, itemsize, causal, plan, counts):
+    got = pallas_attention._fwd_plan(Sq, Skv, D, itemsize)
+    assert got == plan
+    assert pallas_attention.fwd_tile_counts(
+        Sq, Skv, causal, Skv - Sq, got
+    ) == counts
+
+
+def test_fwd_tile_counts_cover_the_grid_and_agree_with_predicates():
+    """unmasked + masked + skipped is every (q block, kv block) pair, and
+    the closed-form span is the two predicates counted tile by tile."""
+    pa = pallas_attention
+    for Sq, Skv in [(128, 128), (384, 384), (1024, 1024), (128, 1024),
+                    (384, 1024), (256, 768), (512, 2048), (1024, 1536)]:
+        for causal in (True, False):
+            plan = pa._fwd_plan(Sq, Skv, 64, 2)
+            geom = dict(causal=causal, block_q=plan.block_q,
+                        block_k=plan.block_k, q_offset=Skv - Sq)
+            n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
+            live = [[bool(pa._block_live(i, j, **geom)) for j in range(n_k)]
+                    for i in range(n_q)]
+            free = [[bool(pa._block_unmasked(i, j, **geom)) for j in range(n_k)]
+                    for i in range(n_q)]
+            for i in range(n_q):
+                full, end = pa._kv_span(i, n_k=n_k, **geom)
+                assert free[i] == [j < full for j in range(n_k)]
+                assert live[i] == [j < end for j in range(n_k)]
+                assert end >= 1  # kv tile 0 opens every q block
+            c = pa.fwd_tile_counts(Sq, Skv, causal, Skv - Sq, plan)
+            assert c.unmasked == sum(map(sum, free))
+            assert c.unmasked + c.masked == sum(map(sum, live))
+            assert c.unmasked + c.masked + c.skipped == n_q * n_k
+
+
+# --- the forward kernel through the chip's own compiler (no chip) --------
+# Interpret mode cannot see what Mosaic refuses (a misaligned slice, too
+# much VMEM, a layout it cannot make); an AOT compile for a described v5e
+# can, at the real widths, in about a second a shape.
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    try:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+        return SingleDeviceSharding(topo.devices[0])
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e!r}")
+
+
+@pytest.mark.parametrize(
+    "B,Sq,Skv,H,Hkv,D,dtype,causal",
+    [
+        (8, 1024, 1024, 16, 16, 64, jnp.bfloat16, True),    # the cell
+        (2, 1024, 1024, 12, 12, 64, jnp.float32, True),     # chip_smoke
+        (2, 1024, 1024, 8, 8, 64, jnp.bfloat16, False),     # a ring hop
+        (2, 384, 384, 4, 4, 64, jnp.bfloat16, True),        # 128-tiles
+        (2, 128, 1024, 8, 2, 128, jnp.bfloat16, True),      # GQA decode
+        (1, 2048, 8192, 4, 4, 128, jnp.bfloat16, True),     # two fetches
+        (1, 1024, 1024, 4, 4, 200, jnp.bfloat16, True),     # D off the lanes
+        (1, 1024, 1024, 4, 4, 256, jnp.bfloat16, True),     # D of two vregs
+    ],
+)
+def test_flash_forward_compiles_for_v5e(
+    v5e_chip, B, Sq, Skv, H, Hkv, D, dtype, causal
+):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    q = jax.ShapeDtypeStruct((B, Sq, H, D), dtype, sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((B, Skv, Hkv, D), dtype, sharding=v5e_chip)
+    # an entry written without a chip cannot be read back: keep this
+    # compile out of the persistent cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(
+            lambda q, k, v: pallas_attention._flash_fwd_impl(
+                q, k, v, causal=causal, interpret=False
+            )
+        ).lower(q, kv, kv).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_fwd" in text

@@ -286,6 +286,43 @@ def test_flash_ring_matches_xla_ring(n_ring, devices):
         )
 
 
+def test_flash_ring_forward_matches_single_device(devices):
+    """The ring forward merges its hops by the lse the flash forward
+    returns ((B*H, 8, S), every sublane the row's value): out and the
+    merged lse on a 4-device mesh == one device over the whole sequence."""
+    from jax.sharding import Mesh
+
+    from distributeddataparallel_tpu.ops.pallas_attention import _flash_fwd_impl
+    from distributeddataparallel_tpu.parallel.context_parallel import (
+        _flash_ring_fwd_impl,
+    )
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("seq",))
+    B, S, H, D = 1, 512, 2, 32
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(26), 3)
+    q = jax.random.normal(kq, (B, S, H, D), jnp.float32)
+    k = jax.random.normal(kk, (B, S, H, D), jnp.float32)
+    v = jax.random.normal(kv, (B, S, H, D), jnp.float32)
+
+    ring = jax.shard_map(
+        lambda q, k, v: _flash_ring_fwd_impl(q, k, v, "seq", True),
+        mesh=mesh,
+        in_specs=(P(None, "seq"),) * 3,
+        out_specs=(P(None, "seq"), P(None, None, "seq")),
+        check_vma=False,
+    )
+    out, lse = jax.jit(ring)(q, k, v)
+    one_out, one_lse8 = _flash_fwd_impl(q, k, v, causal=True, interpret=True)
+    with jax.default_matmul_precision("highest"):
+        ref = dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(one_out), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(one_lse8[:, 0, :].reshape(B, H, S)),
+        atol=2e-5,
+    )
+
+
 def test_ring_impl_dispatch(devices):
     """impl='pallas' off-TPU/odd shapes raises; impl='xla' never touches
     the kernel; 'auto' silently stays on the XLA path on CPU."""
