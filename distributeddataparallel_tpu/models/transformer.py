@@ -51,6 +51,9 @@ from distributeddataparallel_tpu.parallel.tensor_parallel import (
 )
 
 
+LAYER_KINDS = frozenset({"attention", "mamba"})
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int
@@ -63,7 +66,7 @@ class TransformerConfig:
     head_dim: int | None = None      # None -> d_model // num_heads
     norm: str = "layernorm"          # "layernorm" | "rmsnorm"
     activation: str = "gelu"         # "gelu" | "swiglu"
-    positional: str = "learned"      # "learned" | "rope"
+    positional: str = "learned"      # "learned" | "rope" | "none"
     rope_theta: float = 10000.0
     tie_embeddings: bool = True
     dtype: Any = jnp.float32         # activation/matmul dtype
@@ -133,6 +136,42 @@ class TransformerConfig:
     # int8 stack stays HBM-resident (set by models.generate for
     # quantized decode; see _ScanBlock).
     quant_serving: bool = False
+    # Hybrid stacks: the kind of each layer's mixer, "attention" or
+    # "mamba" (a Mamba-2 mixer, ``Mamba2Mixer``), in order; None is
+    # "attention" throughout.  Layers of two kinds are not a scan's one
+    # body, so ``scan_layers`` (and with it PP and FSDP) is refused.
+    layer_types: tuple[str, ...] | None = None
+    # The Mamba-2 mixer's sizes: H heads of P channels (d_inner = H * P),
+    # an N-wide state, G groups that share B and C, a causal depthwise
+    # convolution of ``ssm_conv`` taps, and the scan's chunk (ops.ssd).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # Granite's four scalars (1 / None leave the step as it was):
+    # embeddings times ``embedding_multiplier``; each residual branch
+    # times ``residual_multiplier``; attention scores times
+    # ``attention_multiplier`` in place of 1/sqrt(head_dim); logits
+    # divided by ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    logits_scaling: float = 1.0
+
+    def __post_init__(self):
+        kinds = self.layer_types
+        if kinds is None:
+            return
+        object.__setattr__(self, "layer_types", tuple(kinds))  # a JSON list
+        if len(kinds) != self.num_layers or set(kinds) - LAYER_KINDS:
+            raise ValueError(
+                f"layer_types must name {self.num_layers} layers, each one "
+                f"of {sorted(LAYER_KINDS)}; got {kinds!r}"
+            )
+        if self.scan_layers and set(kinds) != {"attention"}:
+            raise ValueError("scan_layers runs attention layers only")
 
     @property
     def kv_heads(self) -> int:
@@ -164,6 +203,26 @@ def llama3_8b(**overrides) -> TransformerConfig:
         activation="swiglu", positional="rope", rope_theta=500000.0,
         tie_embeddings=False, dtype=jnp.bfloat16, remat=True,
         scan_layers=True, use_bias=False,
+    )
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
+def granite_4_0_h_micro(**overrides) -> TransformerConfig:
+    """Granite 4.0-H Micro (``granitemoehybrid``, no experts): 40 layers
+    in periods of ten — nine Mamba-2 mixers and, sixth, one GQA attention
+    layer (32 heads on 8, D 64) — d 2048, a gated SiLU MLP of 8192, no
+    positions, 100352 ids tied, and the four multipliers."""
+    period = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    base = dict(
+        vocab_size=100352, num_layers=40, num_heads=32, num_kv_heads=8,
+        head_dim=64, d_model=2048, d_ff=8192, max_seq_len=131072,
+        norm="rmsnorm", activation="swiglu", positional="none",
+        tie_embeddings=True, use_bias=False, layer_types=period * 4,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+        ssm_conv=4, ssm_chunk=256, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=1.0 / 64,
+        logits_scaling=8.0,
     )
     base.update(overrides)
     return TransformerConfig(**base)
@@ -330,7 +389,8 @@ class Attention(nn.Module):
                     0.0, NEG_INF,
                 ).astype(jnp.float32)  # (B, 1, S, max_seq_len)
                 out = dot_product_attention(
-                    q, kf, vf, causal=False, bias=bias
+                    q, kf, vf, causal=False, bias=bias,
+                    scale=cfg.attention_multiplier,
                 )
             else:
                 pos = positions.reshape(-1)  # (S,) global token positions
@@ -349,8 +409,14 @@ class Attention(nn.Module):
                     S, cfg.max_seq_len, q_offset=pos[0]
                 )
                 out = dot_product_attention(
-                    q, kf, vf, causal=False, bias=bias[None, None]
+                    q, kf, vf, causal=False, bias=bias[None, None],
+                    scale=cfg.attention_multiplier,
                 )
+        elif cfg.cp_axis is not None and cfg.attention_multiplier is not None:
+            raise ValueError(
+                "attention_multiplier is not carried through the "
+                "context-parallel attentions"
+            )
         elif cfg.cp_axis is not None and cfg.cp_impl == "ulysses":
             from distributeddataparallel_tpu.parallel.context_parallel import (
                 ulysses_attention,
@@ -379,7 +445,10 @@ class Attention(nn.Module):
         else:
             # GQA kv stays at its own head count: the flash kernel indexes
             # the shared head natively; the XLA path expands internally.
-            out = attention(q, k, v, causal=True, impl=cfg.attn_impl)
+            out = attention(
+                q, k, v, causal=True, impl=cfg.attn_impl,
+                scale=cfg.attention_multiplier,
+            )
         return _RowParallelOut(
             features=cfg.d_model,
             kernel_shape=(H, D, cfg.d_model),
@@ -642,26 +711,137 @@ class MoEMLP(nn.Module):
         return out.reshape(B, S, d)
 
 
+class GatedRMSNorm(RMSNorm):
+    """Mamba-2's output norm: ``RMSNorm(y * silu(z))`` over the whole
+    inner width (one group); the gate too in f32."""
+
+    def __call__(self, y, z):
+        gated = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+        return super().__call__(gated).astype(y.dtype)
+
+
+def a_log_init(key, shape, dtype=jnp.float32):
+    """``A = -exp(A_log)`` drawn uniform in [-16, -1] (the public Mamba-2
+    initialisation)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def dt_bias_init(key, shape, dtype=jnp.float32):
+    """``softplus(dt_bias)`` drawn log-uniform in [1e-3, 0.1]: a decay
+    near 1, so a state remembers hundreds of steps."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, dtype, jnp.log(1e-3), jnp.log(0.1)
+    ))
+    return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
+
+
+def conv_init(taps: int):
+    """The depthwise convolution's taps and bias, uniform in
+    +-1/sqrt(taps) (a depthwise Conv1d's default, which the public
+    Mamba-2 code keeps): ``x``, ``B`` and ``C`` come out of order 1.
+    With normal(0, 0.02) taps they are ~0.03 and the scan adds nothing
+    to a ``y`` that ``D * x`` carries (PERF.md section 2, PR 28)."""
+    bound = taps ** -0.5
+
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 mixer (Dao & Gu 2024) as ``GraniteMoeHybrid`` lays it out:
+    one input projection to the gate ``z``, the convolved ``x | B | C``
+    and ``dt``; a causal depthwise convolution and SiLU; the state-space
+    scan (``ops.ssd``); the gated norm; the output projection.  Its five
+    parts carry ``scopes.MIXER_SCOPES``.  Data-parallel training only:
+    no tensor or context parallelism, and no decoding state yet."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from distributeddataparallel_tpu.ops import ssd
+
+        cfg = self.cfg
+        if cfg.decode or cfg.tp_axis is not None or cfg.cp_axis is not None:
+            raise ValueError(
+                "mamba layers run data-parallel training only "
+                "(no decode, tp_axis or cp_axis)"
+            )
+        B_, S, _ = u.shape
+        H, P = cfg.ssm_heads, cfg.ssm_head_dim
+        G, N, K = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv
+        inner, bc = H * P, G * N
+        dense = lambda feats, name: nn.Dense(  # noqa: E731
+            feats, dtype=cfg.dtype, name=name, use_bias=False,
+            kernel_init=nn.initializers.normal(0.02),
+        )
+        with jax.named_scope(scopes.SSM_IN_PROJ):
+            z, xbc, dt = jnp.split(
+                dense(2 * inner + 2 * bc + H, "in_proj")(u),
+                [inner, 2 * inner + 2 * bc], axis=-1,
+            )
+        with jax.named_scope(scopes.SSM_CONV):
+            taps = self.param(
+                "conv_kernel", conv_init(K), (K, inner + 2 * bc), jnp.float32,
+            )
+            bias = self.param(
+                "conv_bias", conv_init(K), (inner + 2 * bc,), jnp.float32,
+            )
+            # tap k reads the step K - 1 - k back: K - 1 zeros to the left
+            padded = jnp.pad(
+                xbc.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0))
+            )
+            xbc = nn.silu(bias + sum(
+                taps[k] * padded[:, k:k + S] for k in range(K)
+            )).astype(cfg.dtype)
+            x, Bm, Cm = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        with jax.named_scope(scopes.SSD):
+            dt_bias = self.param("dt_bias", dt_bias_init, (H,), jnp.float32)
+            a_log = self.param("A_log", a_log_init, (H,), jnp.float32)
+            skip = self.param("D", nn.initializers.ones, (H,), jnp.float32)
+            y = ssd.ssd_chunked(
+                x.reshape(B_, S, H, P),
+                jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                -jnp.exp(a_log),
+                Bm.reshape(B_, S, G, N), Cm.reshape(B_, S, G, N),
+                skip, chunk=cfg.ssm_chunk,
+            ).reshape(B_, S, inner)
+        with jax.named_scope(scopes.SSM_GATE_NORM):
+            y = GatedRMSNorm(name="norm")(y, z)
+        with jax.named_scope(scopes.SSM_OUT_PROJ):
+            return dense(cfg.d_model, "out_proj")(y)
+
+
 class DecoderBlock(nn.Module):
     cfg: TransformerConfig
+    kind: str = "attention"  # this layer's mixer, of cfg.layer_types
 
     @nn.compact
     def __call__(self, x, positions=None, rope=None, deterministic=True):
         cfg = self.cfg
         drop = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)
-        y = _make_norm(cfg, "attn_norm")(x)
-        x = x + drop(
-            Attention(cfg, name="attn")(
+
+        def add(x, branch):
+            if cfg.residual_multiplier != 1.0:
+                branch = branch * cfg.residual_multiplier
+            return x + drop(branch)
+
+        if self.kind == "mamba":
+            y = _make_norm(cfg, "mamba_norm")(x)
+            x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
+        else:
+            y = _make_norm(cfg, "attn_norm")(x)
+            x = add(x, Attention(cfg, name="attn")(
                 y, positions=positions, rope=rope, deterministic=deterministic
-            )
-        )
+            ))
         y = _make_norm(cfg, "mlp_norm")(x)
         mlp = (
             MoEMLP(cfg, name="mlp") if cfg.moe_experts > 0
             else MLP(cfg, name="mlp")
         )
-        x = x + drop(mlp(y))
-        return x
+        return add(x, mlp(y))
 
 
 class _ScanBlock(nn.Module):
@@ -823,6 +1003,8 @@ class TransformerLM(nn.Module):
         )
         with jax.named_scope(scopes.EMBED):
             x = embed(tokens).astype(cfg.dtype)
+            if cfg.embedding_multiplier != 1.0:
+                x = x * cfg.embedding_multiplier
             if cfg.positional == "learned":
                 pos = positions if positions is not None else jnp.arange(S)
                 pos_embed = self.param(
@@ -854,8 +1036,9 @@ class TransformerLM(nn.Module):
                 if cfg.remat
                 else DecoderBlock
             )
-            for i in range(cfg.num_layers):
-                x = block_cls(cfg, name=f"layer_{i}")(
+            kinds = cfg.layer_types or ("attention",) * cfg.num_layers
+            for i, kind in enumerate(kinds):
+                x = block_cls(cfg, kind, name=f"layer_{i}")(
                     x, positions, rope, deterministic
                 )
 
@@ -878,4 +1061,6 @@ class TransformerLM(nn.Module):
                 logits = LMHead(
                     cfg.vocab_size, cfg.dtype, name="lm_head"
                 )(x)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
         return logits

@@ -103,6 +103,24 @@ def transformer_fwd_flops(cfg, *, batch: int, seq_len: int) -> int:
     return cfg.num_layers * (qkv + scores_values + out_proj + mlp) + logits
 
 
+def ssd_cost(batch: int, seq: int, heads: int, head_dim: int, state: int,
+             groups: int, chunk: int) -> dict:
+    """FLOPs and least HBM bytes of one layer's state-space scan
+    (``ops.ssd``) in one train step, from shapes alone.  The chunked
+    form's four products, forward (``C B^T`` 2 L N a group, the masked
+    matrix on ``x`` 2 L H P, ``B^T x`` and ``C h`` 2 N H P each, a token),
+    times 3 for forward and backward; bytes: ``x`` and ``y`` (bf16),
+    ``B`` and ``C`` (bf16), ``dt`` (f32) and their gradients, once each."""
+    chunk = min(chunk, seq)
+    tokens = batch * chunk * -(-seq // chunk)
+    inner = heads * head_dim
+    per_token = 2 * chunk * (groups * state + inner) + 4 * state * inner
+    bytes_ = batch * seq * 2 * (
+        2 * 2 * inner + 2 * 2 * groups * state + 4 * heads
+    )
+    return {"flops": 3 * tokens * per_token, "bytes": bytes_}
+
+
 def simple_cnn_fwd_flops(
     *,
     batch: int,

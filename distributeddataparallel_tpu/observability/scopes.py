@@ -34,5 +34,15 @@ FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
 
+#: the five parts of ``models.transformer.Mamba2Mixer`` (a Flax module
+#: named ``mamba``: ``layer_<i>/mamba/<part>/...``); a tuple of their own,
+#: read by the benchmark's ``mixer_scopes`` and not by its bucket table
+SSM_IN_PROJ = "ssm_in_proj"
+SSM_CONV = "ssm_conv"
+SSD = "ssd"
+SSM_GATE_NORM = "ssm_gate_norm"
+SSM_OUT_PROJ = "ssm_out_proj"
+
 STEP_SCOPES = (EMBED, HEAD, LOSS, METRICS, GRAD_SYNC, GRAD_CLIP, OPTIMIZER)
 KERNEL_NAMES = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
+MIXER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSD, SSM_GATE_NORM, SSM_OUT_PROJ)

@@ -102,15 +102,18 @@ def dot_product_attention(
     *,
     causal: bool = True,
     bias: jnp.ndarray | None = None,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """XLA reference attention. q: (B,Sq,H,D); k/v: (B,Skv,H,D) -> (B,Sq,H,D).
 
     Softmax in float32; matmuls in the input dtype (bf16 on TPU hits the
     MXU; the f32 softmax runs on the VPU and fuses with the scale/mask).
+    ``scale`` multiplies the scores; None is 1/sqrt(D).
     """
     *_, Sq, H, D = q.shape
     Skv = k.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
         # Sq != Skv (decode / chunked queries): queries are the LAST Sq
@@ -129,8 +132,10 @@ def attention(
     *,
     causal: bool = True,
     impl: str = "auto",
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Dispatch: 'xla' reference, 'pallas' flash kernel, or 'auto'.
+    ``scale`` multiplies the scores; None is 1/sqrt(head_dim).
 
     'auto' uses the Pallas flash kernel on TPU whenever the shapes are
     ``supported()`` and the XLA reference otherwise; the choice is made
@@ -150,7 +155,9 @@ def attention(
         from distributeddataparallel_tpu.ops import pallas_attention
 
         if pallas_attention.supported(q, k, v):
-            return pallas_attention.flash_attention(q, k, v, causal=causal)
+            return pallas_attention.flash_attention(
+                q, k, v, causal, False, scale
+            )
         if impl == "pallas":
             raise ValueError(
                 f"pallas flash attention unsupported for shapes "
@@ -164,4 +171,4 @@ def attention(
             )
         k = repeat_kv(k, H // Hkv)
         v = repeat_kv(v, H // Hkv)
-    return dot_product_attention(q, k, v, causal=causal)
+    return dot_product_attention(q, k, v, causal=causal, scale=scale)

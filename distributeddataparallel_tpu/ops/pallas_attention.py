@@ -300,7 +300,8 @@ def _gqa_kv_row(b, *, H: int, Hkv: int):
     return (b // H) * Hkv + (b % H) // group
 
 
-def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool):
+def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool,
+                    scale: float | None = None):
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     Hkv = k.shape[2]
@@ -320,7 +321,8 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool):
     kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Skv, D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Skv, D)
     out, lse = _fwd_launch(
-        qf, kf, vf, H=H, Hkv=Hkv, causal=causal, interpret=interpret
+        qf, kf, vf, H=H, Hkv=Hkv, causal=causal, interpret=interpret,
+        scale=scale,
     )
     out = out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     # lse stays in its (B*H, 8, Sq) sublane-broadcast layout: the backward
@@ -330,9 +332,10 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("H", "Hkv", "causal", "interpret")
+    jax.jit, static_argnames=("H", "Hkv", "causal", "interpret", "scale")
 )
-def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool):
+def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
+                scale: float | None = None):
     """The forward's one ``pallas_call``, on flat (rows, S, D) operands.
 
     Jitted on its own so that a model's layers share one trace and one
@@ -343,7 +346,8 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool):
     """
     rows, Sq, D = qf.shape
     Skv = kf.shape[1]
-    scale = 1.0 / (D ** 0.5)
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     q_offset = Skv - Sq
     plan = _fwd_plan(Sq, Skv, D, qf.dtype.itemsize)
     counts = fwd_tile_counts(Sq, Skv, causal, q_offset, plan)
@@ -401,15 +405,21 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool):
     )(qf, kf, vf)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention(q, k, v, causal: bool = True, interpret: bool = False):
-    """Flash attention: q,k,v (B,S,H,D) -> (B,S,H,D), causal by default."""
-    out, _ = _flash_fwd_impl(q, k, v, causal=causal, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
+                    scale: float | None = None):
+    """Flash attention: q,k,v (B,S,H,D) -> (B,S,H,D), causal by default;
+    ``scale`` multiplies the scores (None: 1/sqrt(D))."""
+    out, _ = _flash_fwd_impl(
+        q, k, v, causal=causal, interpret=interpret, scale=scale
+    )
     return out
 
 
-def _fwd(q, k, v, causal, interpret):
-    out, lse = _flash_fwd_impl(q, k, v, causal=causal, interpret=interpret)
+def _fwd(q, k, v, causal, interpret, scale):
+    out, lse = _flash_fwd_impl(
+        q, k, v, causal=causal, interpret=interpret, scale=scale
+    )
     return out, (q, k, v, out, lse)
 
 
@@ -523,7 +533,7 @@ def _bwd_dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(causal, interpret, res, do):
+def _bwd(causal, interpret, scale, res, do):
     """Blockwise flash backward: two Pallas kernels, O(S) peak memory.
 
     Probability tiles are recomputed per (q block, kv block) pair from the
@@ -539,7 +549,8 @@ def _bwd(causal, interpret, res, do):
     group = H // Hkv
     block_q = _pick_block(Sq)
     block_k = _pick_block(Skv)
-    scale = 1.0 / (D ** 0.5)
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     q_offset = Skv - Sq
 
     # (B, S, H, D) -> (B*H, S, D) flat layout, matching the forward; kv

@@ -142,6 +142,68 @@ def test_every_scope_constant_falls_in_exactly_one_bucket():
     assert scope_reduce.phase_of("a/optimizer/x", "optimizer") == "update"
 
 
+def test_mixer_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
+    """The Mamba-2 mixer's five scopes (PR 28) are a tuple of their own,
+    read by ``benchmarks/mixer_scopes.py``'s table and not by
+    ``scope_reduce.BUCKETS``; a compiled hybrid step carries each of them,
+    forward and backward."""
+    from benchmarks import mixer_scopes
+    from distributeddataparallel_tpu.models.transformer import (
+        granite_4_0_h_micro,
+    )
+
+    assert not set(scopes.MIXER_SCOPES) & set(scopes.STEP_SCOPES)
+    assert [name for name, _ in mixer_scopes.PARTS] == list(scopes.MIXER_SCOPES)
+    for name in scopes.MIXER_SCOPES:
+        for path in (f"jit(step)/jvp(M)/layer_0/mamba/{name}/add",
+                     f"jit(s)/transpose(jvp(M))/layer_3/mamba/{name}/mul"):
+            hits = [part for part, rx in mixer_scopes.PARTS
+                    if re.search(rx, path)]
+            assert hits == [name], (path, hits)
+            assert mixer_scopes.part_of(path) == name
+    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/mamba/reshape") == (
+        mixer_scopes.REST)
+    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/mamba_norm/mul") is None
+    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/attn/q_proj/dot") is None
+
+    cfg = granite_4_0_h_micro(
+        vocab_size=128, num_layers=2, layer_types=("mamba", "attention"),
+        d_model=32, num_heads=2, num_kv_heads=1, head_dim=16, d_ff=64,
+        max_seq_len=16, ssm_heads=4, ssm_head_dim=16, ssm_state=16,
+        ssm_chunk=8, attn_impl="xla", remat=True,
+    )
+    model = TransformerLM(cfg)
+    mesh = ddp.make_mesh(("data",), devices=jax.devices()[:1])
+
+    def loss_fn(params, batch, rng):
+        logits = model.apply({"params": params}, batch["tokens"][:, :-1])
+        return lm_cross_entropy(logits, batch["tokens"][:, 1:]), {}
+
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    state = ddp.broadcast_params(
+        ddp.TrainState.create(
+            apply_fn=model.apply, params=params, tx=optax.adamw(1e-3)
+        ),
+        mesh,
+    )
+    batch = shard_batch({"tokens": jnp.zeros((2, 17), jnp.int32)}, mesh)
+    text = ddp.make_train_step(loss_fn, mesh=mesh).lower(
+        state, batch, jax.random.PRNGKey(0)
+    ).compile().as_text()
+    found = collections.Counter()
+    for scope in _OP_NAME.findall(text):
+        part = mixer_scopes.part_of(scope)
+        if part is not None:
+            found[part, scope_reduce.phase_of(scope, "")] += 1
+    for name in scopes.MIXER_SCOPES:
+        assert found[name, "fwd"] and found[name, "bwd"], (name, found)
+    # the accepted table still has a row for all of it
+    assert scope_reduce.bucket_of(
+        "jit(s)/jvp(M)/layer_0/mamba/ssd/dot_general") == "block"
+
+
 def test_three_pallas_calls_have_three_names():
     from distributeddataparallel_tpu.ops.pallas_attention import (
         flash_attention,
