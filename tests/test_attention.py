@@ -1,6 +1,8 @@
 """Attention op tests: RoPE properties, causal masking, GQA expansion, and
 the Pallas flash kernel vs the XLA reference (interpret mode on CPU)."""
 
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -464,3 +466,49 @@ def test_flash_forward_compiles_for_v5e(
         compilation_cache.reset_cache()
     assert text.count("tpu_custom_call") == 1
     assert "flash_fwd" in text
+
+
+@pytest.mark.parametrize(
+    "b,s,h,p,g,n,chunk,dtype",
+    [
+        (2, 4096, 64, 64, 1, 128, 256, jnp.bfloat16),   # the granite cell
+        (1, 512, 16, 64, 2, 128, 256, jnp.float32),     # two groups, f32
+        (1, 256, 8, 128, 1, 256, 128, jnp.bfloat16),    # one sub-tile a chunk
+    ],
+)
+def test_ssd_kernels_compile_for_v5e(v5e_chip, b, s, h, p, g, n, chunk, dtype):
+    """The state-space scan's forward and backward kernels (``ops/ssd.py``,
+    PR 30), kept in this file because only the process that described the
+    topology may compile for it.  Mosaic refused one version of the
+    backward that every interpret-mode test had passed (a lane slice of
+    a row it held replicated)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from distributeddataparallel_tpu.ops import ssd
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+
+    f32 = jnp.float32
+    args = (sds((b, s, h, p), dtype), sds((b, s, h), f32), sds((h,), f32),
+            sds((b, s, g, n), dtype), sds((b, s, g, n), dtype), sds((h,), f32))
+    assert ssd._plan(chunk, h // g, p).heads in (8, 16)
+
+    def loss(*a):
+        # _interpret=False and a CPU backend: what `supported` would allow
+        # on the chip is asked for by hand
+        with mock.patch.object(ssd, "supported", lambda *_: True):
+            return ssd.ssd_chunked(*a, chunk=chunk).astype(f32).sum()
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(
+            jax.value_and_grad(loss, argnums=range(6))
+        ).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssd_fwd" in text and "ssd_bwd" in text

@@ -87,6 +87,124 @@ def test_a_dropped_chunk_carry_shows_only_past_the_first_chunk():
     assert float(jnp.abs(got[:, 8:] - want[:, 8:]).max()) > 1e-2
 
 
+# ------------------------------------------------- the scan's two kernels
+
+def through_sin(fn, args, argnums):
+    return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32))),
+                    argnums=argnums)(*args)
+
+
+@pytest.mark.parametrize("inputs,chunk,skip,dtype", [
+    (dict(g=1), 8, True, jnp.float32),             # one group of four heads
+    (dict(g=2), 8, True, jnp.float32),             # two groups, padded tail
+    (dict(g=2), 4, True, jnp.float32),             # whole chunks
+    (dict(g=2), 256, True, jnp.float32),           # one chunk of S = 20
+    (dict(g=1), 8, False, jnp.float32),            # D=None
+    (dict(b=1, s=300, h=2, g=1), 256, True, jnp.float32),  # 128-sub-tiles, padded
+    (dict(g=2), 8, True, jnp.bfloat16),
+    (dict(b=1, s=300, h=2, g=1), 256, False, jnp.bfloat16),
+], ids=["g1-r4", "g2-padded", "g2-whole", "g2-one-chunk", "no-skip",
+        "sub-tiles", "bf16", "bf16-sub-tiles-no-skip"])
+def test_scan_kernels_are_the_recurrence_and_the_plain_form(
+    inputs, chunk, skip, dtype
+):
+    """``ssd_fwd`` and ``ssd_bwd`` through the interpreter: ``y`` and every
+    gradient.  f32 against the recurrence at the chunked form's own
+    tolerances (``test_chunked_scan_is_the_recurrence``; the sub-tiled case
+    sums 256 steps where those sum 20, five times the rounding).  bf16
+    against the plain form under the same casts: both round the tile and
+    ``dy`` to eight bits (2 ** -9 a rounding), in different places of the
+    backward, so the worst entry agrees to 2 % of the largest and the whole
+    to 1 % in norm."""
+    args = scan_inputs(**inputs)
+    if not skip:
+        args = args[:5]
+    argnums = range(len(args))
+    cast = lambda a: tuple(  # noqa: E731
+        v.astype(dtype) if i in (0, 3, 4) else v for i, v in enumerate(a))
+    kernels = lambda *a: ssd.ssd_chunked(  # noqa: E731
+        *cast(a), chunk=chunk, _interpret=True)
+    plain = lambda *a: ssd.ssd_chunked(*cast(a), chunk=chunk)  # noqa: E731
+    got, g_got = kernels(*args), through_sin(kernels, args, argnums)
+    assert got.dtype == dtype and got.shape == args[0].shape
+    if dtype == jnp.float32:
+        wide = 5 if args[0].shape[1] > 20 else 1
+        recurrence = granite_hybrid.ssm_scan if skip else (
+            lambda *a: granite_hybrid.ssm_scan(*a, jnp.zeros(a[1].shape[-1])))
+        for fn in (recurrence, plain):
+            want = fn(*args)
+            np.testing.assert_allclose(
+                got, want, atol=wide * 1e-5 * float(jnp.abs(want).max()))
+            for a, b in zip(g_got, through_sin(fn, args, argnums)):
+                np.testing.assert_allclose(
+                    a, b, atol=wide * 2e-5 * float(jnp.abs(b).max()))
+        return
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    want = plain(*args)
+    pairs = [(got, want)] + list(zip(g_got, through_sin(plain, args, argnums)))
+    for a, b in pairs:
+        a, b = f32(a), f32(b)
+        np.testing.assert_allclose(a, b, atol=2e-2 * np.abs(b).max())
+        assert np.linalg.norm(a - b) <= 1e-2 * np.linalg.norm(b)
+
+
+def on_the_kernel_path():
+    """``ssd.ssd_chunked`` with the kernels forced through the interpreter,
+    patched in under its own name so that the benchmark's fault hooks, which
+    patch that name and ``_carry_states``, find it."""
+    import functools
+
+    return mock.patch.object(
+        ssd, "ssd_chunked",
+        functools.partial(ssd.ssd_chunked, _interpret=True),
+    )
+
+
+def test_the_benchmarks_faults_bite_on_the_kernel_path():
+    args = scan_inputs()
+    with on_the_kernel_path():
+        want = ssd.ssd_chunked(*args, chunk=8)
+        with readings_hybrid.FAULTS["fault_no_chunk_carry"]():
+            got = ssd.ssd_chunked(*args, chunk=8)
+        np.testing.assert_array_equal(got[:, :8], want[:, :8])
+        assert float(jnp.abs(got[:, 8:] - want[:, 8:]).max()) > 1e-2
+        with readings_hybrid.FAULTS["fault_no_skip"]():
+            got = ssd.ssd_chunked(*args, chunk=8)
+        np.testing.assert_array_equal(
+            got, ssd.ssd_chunked(*args[:5], chunk=8))
+        np.testing.assert_allclose(
+            want - got, args[5][:, None] * args[0], atol=1e-5)
+    # and both are the plain form's answers
+    np.testing.assert_allclose(
+        want, ssd.ssd_chunked(*args, chunk=8),
+        atol=1e-5 * float(jnp.abs(want).max()))
+
+
+def test_the_kernels_take_the_cells_shapes_and_count_their_tiles():
+    """``supported`` reads backend, shapes and dtype, nothing else."""
+    def shapes(s=4096, h=64, p=64, g=1, n=128, dtype=jnp.bfloat16):
+        return (jax.ShapeDtypeStruct((2, s, h, p), dtype),
+                jax.ShapeDtypeStruct((2, s, g, n), dtype))
+
+    assert not ssd.supported(*shapes(), 256)        # the CPU the tests run on
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert ssd.supported(*shapes(), 256)        # the cell
+        assert ssd.supported(*shapes(dtype=jnp.float32), 256)
+        assert ssd.supported(*shapes(s=8192), 128)
+        assert not ssd.supported(*shapes(), 20)     # no 128-square sub-tile
+        assert not ssd.supported(*shapes(s=20), 256)
+        assert not ssd.supported(*shapes(n=16), 256)   # the tests' state
+        assert not ssd.supported(*shapes(p=8), 256)
+        assert not ssd.supported(*shapes(h=8, g=2), 256)  # 4 heads a block
+        assert not ssd.supported(*shapes(dtype=jnp.float16), 256)
+    # L 256 = 2 x 128: three of a chunk tile's four sub-tiles are live
+    tiles = 2 * 16 * 64
+    assert ssd.tile_counts(2, 4096, 64, 256) == (3 * tiles, tiles)
+    assert ssd.tile_counts(2, 4096, 64, 128) == (2 * tiles, 0)
+    assert ssd.tile_counts(2, 20, 4, 8) == (2 * 3 * 4, 0)
+    assert ssd._plan(256, 64, 64) == (128, 16)
+
+
 # ----------------------------------------- the model against the reference
 
 def tiny_model(**overrides):

@@ -142,29 +142,12 @@ def test_every_scope_constant_falls_in_exactly_one_bucket():
     assert scope_reduce.phase_of("a/optimizer/x", "optimizer") == "update"
 
 
-def test_mixer_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
-    """The Mamba-2 mixer's five scopes (PR 28) are a tuple of their own,
-    read by ``benchmarks/mixer_scopes.py``'s table and not by
-    ``scope_reduce.BUCKETS``; a compiled hybrid step carries each of them,
-    forward and backward."""
-    from benchmarks import mixer_scopes
+def _tiny_hybrid_step_text():
+    """The compiled train step of a two-layer hybrid (``mamba``,
+    ``attention``) under remat, as HLO text."""
     from distributeddataparallel_tpu.models.transformer import (
         granite_4_0_h_micro,
     )
-
-    assert not set(scopes.MIXER_SCOPES) & set(scopes.STEP_SCOPES)
-    assert [name for name, _ in mixer_scopes.PARTS] == list(scopes.MIXER_SCOPES)
-    for name in scopes.MIXER_SCOPES:
-        for path in (f"jit(step)/jvp(M)/layer_0/mamba/{name}/add",
-                     f"jit(s)/transpose(jvp(M))/layer_3/mamba/{name}/mul"):
-            hits = [part for part, rx in mixer_scopes.PARTS
-                    if re.search(rx, path)]
-            assert hits == [name], (path, hits)
-            assert mixer_scopes.part_of(path) == name
-    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/mamba/reshape") == (
-        mixer_scopes.REST)
-    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/mamba_norm/mul") is None
-    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/attn/q_proj/dot") is None
 
     cfg = granite_4_0_h_micro(
         vocab_size=128, num_layers=2, layer_types=("mamba", "attention"),
@@ -189,11 +172,34 @@ def test_mixer_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
         mesh,
     )
     batch = shard_batch({"tokens": jnp.zeros((2, 17), jnp.int32)}, mesh)
-    text = ddp.make_train_step(loss_fn, mesh=mesh).lower(
+    return ddp.make_train_step(loss_fn, mesh=mesh).lower(
         state, batch, jax.random.PRNGKey(0)
     ).compile().as_text()
+
+
+def test_mixer_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
+    """The Mamba-2 mixer's five scopes (PR 28) are a tuple of their own,
+    read by ``benchmarks/mixer_scopes.py``'s table and not by
+    ``scope_reduce.BUCKETS``; a compiled hybrid step carries each of them,
+    forward and backward."""
+    from benchmarks import mixer_scopes
+
+    assert not set(scopes.MIXER_SCOPES) & set(scopes.STEP_SCOPES)
+    assert [name for name, _ in mixer_scopes.PARTS] == list(scopes.MIXER_SCOPES)
+    for name in scopes.MIXER_SCOPES:
+        for path in (f"jit(step)/jvp(M)/layer_0/mamba/{name}/add",
+                     f"jit(s)/transpose(jvp(M))/layer_3/mamba/{name}/mul"):
+            hits = [part for part, rx in mixer_scopes.PARTS
+                    if re.search(rx, path)]
+            assert hits == [name], (path, hits)
+            assert mixer_scopes.part_of(path) == name
+    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/mamba/reshape") == (
+        mixer_scopes.REST)
+    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/mamba_norm/mul") is None
+    assert mixer_scopes.part_of("jit(s)/jvp(M)/layer_0/attn/q_proj/dot") is None
+
     found = collections.Counter()
-    for scope in _OP_NAME.findall(text):
+    for scope in _OP_NAME.findall(_tiny_hybrid_step_text()):
         part = mixer_scopes.part_of(scope)
         if part is not None:
             found[part, scope_reduce.phase_of(scope, "")] += 1
@@ -202,6 +208,35 @@ def test_mixer_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
     # the accepted table still has a row for all of it
     assert scope_reduce.bucket_of(
         "jit(s)/jvp(M)/layer_0/mamba/ssd/dot_general") == "block"
+
+
+def test_scan_kernels_carry_the_mixers_ssd_scope_in_both_phases(devices):
+    """``ssd_fwd`` and ``ssd_bwd`` (PR 30) are launched from jitted
+    functions of their own and from a ``custom_vjp``: their operations
+    must still carry ``.../mamba/ssd/...``, which is where
+    ``train_ssd_scan_ms`` and ``ssd_scan_roofline`` look for them — the
+    forward in the forward pass (and again under remat), the backward
+    under ``transpose(``.  The kernels are forced through the interpreter;
+    on the chip each is one custom call under the same name."""
+    import functools
+    from unittest import mock
+
+    from benchmarks import mixer_scopes
+    from distributeddataparallel_tpu.ops import ssd
+
+    assert set(scopes.SSD_KERNEL_NAMES) == {"ssd_fwd", "ssd_bwd"}
+    with mock.patch.object(
+        ssd, "ssd_chunked", functools.partial(ssd.ssd_chunked, _interpret=True)
+    ):
+        text = _tiny_hybrid_step_text()
+    found = collections.Counter()
+    for scope in _OP_NAME.findall(text):
+        for name in scopes.SSD_KERNEL_NAMES:
+            if f"/{name}/" in scope:
+                assert mixer_scopes.part_of(scope) == "ssd", scope
+                found[name, scope_reduce.phase_of(scope, "")] += 1
+    assert found["ssd_fwd", "fwd"] and found["ssd_fwd", "bwd"], found
+    assert found["ssd_bwd", "bwd"] and not found["ssd_bwd", "fwd"], found
 
 
 def test_three_pallas_calls_have_three_names():
