@@ -247,8 +247,8 @@ def grad_sync_schedule_ir(
 ) -> ScheduleIR:
     """Bucketed gradient sync as a 1-stage schedule: tick ``i`` reduces
     bucket ``i`` (``microbatch`` doubles as the bucket index).  Gives
-    the overlap engine's bucket order the same lintable shape the
-    pipeline tables have."""
+    the bucketed exchange's order the same lintable shape the pipeline
+    tables have."""
     units = tuple(
         ScheduleUnit(tick=i, stage=0, chunk=0, microbatch=i, phase="S")
         for i in range(n_buckets)

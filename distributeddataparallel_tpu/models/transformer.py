@@ -121,8 +121,8 @@ class TransformerConfig:
     # axis the scanned blocks' param gradients are pmean'd over, per scan
     # iteration, via an identity-with-all-reduce-VJP on the param reads
     # (``parallel.data_parallel.sync_grad_in_backward``).  Scanned models
-    # otherwise hold every layer grad inside the backward while-loop
-    # where no post-loop all-reduce can overlap them (OVERLAP.md).
+    # otherwise hold every layer grad inside the backward while-loop,
+    # and a reduction after the loop has no backward left to run beside.
     # Requires ``scan_layers``; the train step must skip these leaves in
     # its own sync (``make_train_step(presynced=scanned_param_paths)``).
     # Backward passes must then run inside shard_map with the axis bound.
@@ -850,9 +850,8 @@ class _ScanBlock(nn.Module):
     Under ``cfg.grad_sync_axis`` the block's params are read through
     ``sync_grad_in_backward``: forward identity, backward pmean over the
     data axis — so each scan iteration's param-slice gradient is reduced
-    inside the backward while-loop body where the async scheduler can
-    hide it under the trip's remaining backward compute (the only
-    overlap available to a scanned model; see parallel/overlap.py).
+    inside the backward while-loop body, with the trip's remaining
+    backward compute beside it (after the loop there is none left).
 
     Under ``cfg.quant_serving`` (int8 weight-only decode, ops.quant) the
     per-layer param SLICE is dequantized here, inside the scan body —

@@ -5,9 +5,9 @@ trained.  ``AlertEngine`` closes that gap without touching the hot path:
 the trainer feeds it ONE ``observe()`` call per throughput window — the
 same boundary where StepTimer already drained and the MFU meter and
 memory sampler already run — so alerting adds zero per-step work and
-zero extra host syncs by construction (pinned in bench.py's counted
-loop).  Every signal it sees is a host float the boundary already
-computed; the engine never reads a device value.
+zero extra host syncs by construction.  Every signal it sees is a host
+float the boundary already computed; the engine never reads a device
+value.
 
 Rules are declarative: each is a small stateful object with thresholds
 as constructor parameters, evaluated against the boundary's signal dict.

@@ -12,14 +12,6 @@ from distributeddataparallel_tpu.parallel.context_parallel import (  # noqa: F40
     ring_attention,
     ulysses_attention,
 )
-from distributeddataparallel_tpu.parallel.overlap import (  # noqa: F401
-    OVERLAP_COMPILER_OPTIONS,
-    cpu_fabric_note,
-    grad_sync_schedule_evidence,
-    grad_sync_schedule_pair,
-    overlap_compiler_options,
-    schedule_report,
-)
 from distributeddataparallel_tpu.parallel.powersgd import (  # noqa: F401
     powersgd_state,
     powersgd_state_specs,

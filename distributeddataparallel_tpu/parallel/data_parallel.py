@@ -8,14 +8,16 @@ divide by world size — falls out declaratively in SPMD JAX:
 - *param broadcast*  → ``broadcast_params``: replicate across the mesh
   (and across hosts from process 0, the exact analog of DDP's rank-0
   broadcast).
-- *grad hooks + all-reduce* → ``all_reduce_gradients``: ``lax.pmean`` over
-  the ``data`` mesh axis inside the jit'd step; XLA's latency-hiding
-  scheduler overlaps the collective with remaining backward compute (the
-  performance property SURVEY.md §3.4 calls out as THE thing to reproduce).
+- *grad hooks + all-reduce* → ``all_reduce_gradients``: ``lax.pmean`` per
+  leaf over the ``data`` mesh axis inside the jit'd step; XLA's combiner
+  merges the leaves into a few large all-reduces and schedules them (how
+  much of the exchange is hidden behind the backward is read on the chip:
+  ``train_exposed_collective_frac``, PERF.md).
 - *bucketing* → ``bucket_gradients``: optional explicit 25 MiB-style
   coalescing of gradient leaves into a few large all-reduces.  Stock XLA
   usually makes this unnecessary; it exists for parity with BASELINE
-  config 4 ("bucketed psum all-reduce") and as a measured fallback.
+  config 4 ("bucketed psum all-reduce"), and ZeRO-2/3 size their
+  scatter/gather stream with the same ``bucket_bytes``.
 - *no_sync / grad accumulation* → handled in ``training.train_step`` by
   accumulating microbatch grads locally and reducing once per boundary.
 
@@ -39,13 +41,6 @@ Pytree = Any
 #: DDP's default bucket size: 25 MiB (SURVEY.md §2b, torch Reducer default).
 DEFAULT_BUCKET_BYTES = 25 * 1024 * 1024
 
-#: Overlap (chain) mode bucket size: unlike DDP's 25 MiB (NCCL latency
-#: amortization), the TPU async-collective scheduler overlaps best when
-#: large leaves ride solo as native-dtype all-reduces; only sub-MiB
-#: leaves (biases, norms) are worth coalescing.  Measured in
-#: parallel/overlap.py — 25 MiB concat buckets get zero async windows.
-OVERLAP_BUCKET_BYTES = 1 * 1024 * 1024
-
 
 def _check_compress(compress: str | None) -> None:
     if compress not in (None, "bf16"):
@@ -58,17 +53,14 @@ def all_reduce_gradients(
     *,
     op: str = "mean",
     bucket_bytes: int | None = None,
-    chain: bool = False,
     compress: str | None = None,
 ) -> Pytree:
     """All-reduce a gradient pytree across the data axis (inside shard_map).
 
     ``op='mean'`` reproduces DDP's divide-by-world-size so every replica
     holds averaged gradients and stays in lockstep under a local optimizer
-    step (ref dpp.py:52-53 semantics).  ``chain=True`` (needs
-    ``bucket_bytes``) orders the buckets with barriers so the compiler
-    keeps them separate and can overlap them with backward — see
-    ``bucket_gradients`` and ``parallel.overlap``.
+    step (ref dpp.py:52-53 semantics).  ``bucket_bytes=None`` reduces
+    leaf by leaf; a size coalesces the leaves first (``bucket_gradients``).
 
     ``compress='bf16'`` is the comm-hook analog of torch DDP's
     ``bf16_compress_hook`` (the stack behind ref dpp.py:52's
@@ -81,8 +73,6 @@ def all_reduce_gradients(
     if op not in ("mean", "sum"):
         raise ValueError(f"op must be 'mean' or 'sum', got {op!r}")
     _check_compress(compress)
-    if chain and bucket_bytes is None:
-        bucket_bytes = OVERLAP_BUCKET_BYTES
     red = lax.pmean if op == "mean" else lax.psum
 
     def _leaf(g):
@@ -96,7 +86,7 @@ def all_reduce_gradients(
         if bucket_bytes is not None:
             return bucket_gradients(
                 grads, axis_name, op=op, bucket_bytes=bucket_bytes,
-                chain=chain, compress=compress,
+                compress=compress,
             )
         return jax.tree.map(_leaf, grads)
 
@@ -107,29 +97,17 @@ def bucket_gradients(
     *,
     op: str = "mean",
     bucket_bytes: int = DEFAULT_BUCKET_BYTES,
-    chain: bool = False,
     compress: str | None = None,
 ) -> Pytree:
     """Coalesced all-reduce: flatten grad leaves into ~bucket_bytes groups,
-    reduce each group as one flat vector, scatter back.
+    reduce each group as one flat f32 vector, scatter back (each leaf
+    returns in its own dtype).
 
     The explicit analog of DDP's Reducer bucketing (25 MiB default).  Like
     DDP, buckets are formed in *reverse* leaf order so the bucket containing
     the last-computed (earliest-layer) grads is reduced last — giving the
     XLA scheduler the same freedom to overlap early buckets with remaining
     backward work.
-
-    ``chain=True`` additionally threads an ``optimization_barrier`` from
-    each bucket's reduced output into the next bucket's input.  That
-    pins the reduction order (reverse, like DDP's Reducer stream) and —
-    the real point — makes the buckets *data-dependent* on each other so
-    XLA's all-reduce combiner cannot legally merge them back into one
-    giant all-reduce that waits for the entire backward.  Separate
-    buckets are what lets the TPU backend's async-collective-fusion +
-    latency-hiding scheduler start bucket k's all-reduce while the
-    remaining backward is still computing (see ``parallel.overlap`` for
-    the scheduled-HLO evidence).  Numerics are identical to the unchained
-    path; the barrier moves no data.
     """
     from distributeddataparallel_tpu import native
 
@@ -142,22 +120,12 @@ def bucket_gradients(
     )
 
     reduced: list[Any] = [None] * len(leaves)
-    prev = None
     # Static mean divisor: lax.psum(1, axis) would materialize a scalar
-    # all-reduce per bucket on the TPU backend, serializing the tail of
-    # the overlapped schedule; the axis size is known at trace time.
+    # all-reduce per bucket on the TPU backend; the axis size is known at
+    # trace time.
     inv_n = 1.0 / lax.axis_size(axis_name)
     for bucket in buckets:
-        # chain (overlap) mode reduces in the native gradient dtype (DDP
-        # semantics, half the wire bytes for bf16) when the bucket is
-        # dtype-uniform; the legacy coalescing path keeps its original
-        # f32 accumulation so --bucket-mb numerics are unchanged.
-        dtypes = {leaves[i].dtype for i in bucket}
-        bdt = (
-            dtypes.pop()
-            if chain and len(dtypes) == 1
-            else jnp.float32
-        )
+        bdt = jnp.float32
         if compress == "bf16" and all(
             leaves[i].dtype == jnp.float32 for i in bucket
         ):
@@ -170,19 +138,13 @@ def bucket_gradients(
             bdt = jnp.bfloat16
         if len(bucket) == 1:
             # Single-leaf bucket: skip the concat/flatten round-trip —
-            # keeps the leaf's layout intact for the async scheduler.
+            # the leaf keeps its layout.
             flat = leaves[bucket[0]].astype(bdt)
         else:
             flat = jnp.concatenate(
                 [leaves[i].reshape(-1).astype(bdt) for i in bucket]
             )
-        if chain and prev is not None:
-            # Bucket k may not start reducing until bucket k-1 finished:
-            # the combiner would have to create a cycle to merge them.
-            flat, prev = lax.optimization_barrier((flat, prev))
         flat = lax.psum(flat, axis_name)
-        if chain:
-            prev = flat
         if op == "mean":
             flat = flat * jnp.asarray(inv_n, bdt)
         if len(bucket) == 1:
@@ -201,6 +163,40 @@ def bucket_gradients(
     return jax.tree.unflatten(treedef, reduced)
 
 
+def comm_schedule_ir(
+    params,
+    *,
+    bucket_bytes: int | None = None,
+    axis: str = "data",
+    prim: str = "psum",
+):
+    """The bucketed grad-sync order as schedule IR (``ScheduleIR``,
+    kind="grad-sync"): one tick per bucket, buckets planned from the
+    param tree by the SAME planner the traced step uses
+    (``native.plan_buckets``), so the SL302 traced-count check catches
+    the step and the plan diverging (e.g. a refactor dropping the
+    coalescing).
+
+    ``bucket_bytes=None`` means leaf-sized buckets (one psum per leaf).
+    Attached by ``make_train_step`` as ``step.comm_schedule(params)`` —
+    a builder, not a constant, because the partition depends on the
+    param tree the step is eventually called with.
+    """
+    from distributeddataparallel_tpu import native
+    from distributeddataparallel_tpu.analysis.schedule_lint import (
+        grad_sync_schedule_ir,
+    )
+
+    leaves = jax.tree.leaves(params)
+    if bucket_bytes is None:
+        n_buckets = len(leaves)
+    else:
+        n_buckets = len(native.plan_buckets(
+            [l.size * l.dtype.itemsize for l in leaves], bucket_bytes
+        ))
+    return grad_sync_schedule_ir(n_buckets, axis=axis, prim=prim)
+
+
 def sync_grad_in_backward(
     x: Pytree,
     axis_name: str,
@@ -216,12 +212,11 @@ def sync_grad_in_backward(
     this, ``models.transformer grad_sync_axis``), the gradient of that
     slice is reduced INSIDE the backward scan iteration — which is the
     only place a scanned model's layer grads exist before the loop
-    stacks them.  Measured on the scanned-Llama v5e:2x4 schedule: the
-    post-loop reduction of the stacked grads cannot overlap anything
-    (2.3% of compute in windows); the in-body reduction runs one async
-    window per scan trip while that trip's remaining backward computes
-    (OVERLAP.md).  The train step must then SKIP these leaves in its own
-    sync (``make_train_step(presynced=...)``) — re-reducing an averaged
+    stacks them: a reduction after the loop has no backward left to run
+    beside, one inside the body has the rest of that trip's.  No chip
+    run has timed it (no cell scans its layers; ROADMAP D4, D18).  The
+    train step must then SKIP these leaves in its own sync
+    (``make_train_step(presynced=...)``) — re-reducing an averaged
     gradient is numerically a no-op but pays the full wire bytes twice.
 
     Forward-only applies (eval, decode) never touch the axis, so the

@@ -251,7 +251,7 @@ def ep_memory_evidence(
         gpt2_124m,
     )
     from distributeddataparallel_tpu.ops import lm_cross_entropy
-    from distributeddataparallel_tpu.parallel.overlap import (
+    from distributeddataparallel_tpu.runtime.distributed import (
         compiler_stamp,
         tpu_topology_mesh,
     )

@@ -517,9 +517,17 @@ def _pp_1f1b_loss_and_grads(
     the pipe, so B stays on the critical path) and a weight-grad unit
     **W** (``jax.vjp`` w.r.t. the layer params only — nothing
     downstream consumes it, so it is off the critical path).  XLA CSE
-    merges the two vjps' duplicated forward recompute, and each
-    primitive's transpose is evaluated identically in both renderings,
-    so dx/dW are bit-identical to the joint vjp's.
+    merges the two vjps' duplicated forward recompute.  What zb gives
+    against 1f1b: the same loss to the bit on the same params (the
+    forward slot is the same code), the same microbatches summed in the
+    same order, and every gradient leaf equal to f32 rounding of its
+    accumulated sum — NOT bit equality, and not by construction: each
+    primitive's transpose is the same, but XLA compiles a slot anew in
+    every scan body it appears in and is free to tile a bias gradient's
+    row reduction differently there (on the CPU mesh ``o_proj/bias`` and
+    ``up_proj/bias`` differ from 1f1b's by 1-2 ulp after one step, and
+    ``up_proj/bias`` still does when zb takes dx and dW from one joint
+    vjp; ``tests/test_pp_zb.py`` holds every leaf to 4 ulp of its scale).
 
     In this SPMD masked-scan rendering a masked slot still burns wall
     clock, so the win comes from SEGMENTATION, not from moving W: the
@@ -726,7 +734,8 @@ def _pp_1f1b_loss_and_grads(
             # stage up is waiting on.  W unit: weight grad only, same
             # tick (deferral depth 0 — see the docstring).  Each vjp
             # transposes the same primitives the joint vjp would, so
-            # dx/dlayers are bit-identical and CSE shares the recompute.
+            # dx/dlayers equal its to f32 rounding and CSE shares the
+            # recompute.
             (dx,) = b_vjp(gy)
             _, w_vjp = jax.vjp(lambda lp: stage_fn(lp, xb), chunk_p)
             (dlayers,) = w_vjp(gy)
@@ -960,7 +969,8 @@ def make_pp_train_step(
     ``pp_bubble_fraction`` for the bubble accounting.
 
     ``schedule="zb"`` — zero-bubble ZB-H1-style W/B split (see
-    ``_pp_1f1b_loss_and_grads``): bit-identical losses/grads to 1f1b,
+    ``_pp_1f1b_loss_and_grads``): 1f1b's losses, and its grads to f32
+    rounding of each leaf's accumulated sum,
     smaller bubble (``1 - Mv/(j_last+n)`` vs ``1 - Mv/T``), same
     activation memory.  v1 rejects ``cfg.cp_axis`` and the MoE aux
     loss.  The 1f1b and zb steps return measured per-stage

@@ -7,9 +7,8 @@ al., NeurIPS 2019): instead of all-reducing the full gradient matrix
 iteration and feed the approximation error back into the next step's
 gradient.  Wire bytes per matrix drop from ``n*m`` to ``(n+m)*r`` —
 for this repo's GPT-2 124M tied embedding that is 154 MB -> 1.6 MB at
-rank 4, i.e. the exposed all-reduce tail (OVERLAP.md §4/§6) essentially
-vanishes; what stays dense is the 1-D leaves (biases/norms, ~0.1% of
-the payload).
+rank 4; what stays dense is the 1-D leaves (biases/norms, ~0.1% of the
+payload).
 
 Per step and per 2-D-reshapeable leaf (others stay dense all-reduce):
 

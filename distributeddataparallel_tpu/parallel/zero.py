@@ -36,9 +36,8 @@ gradient-ready order ``native.plan_buckets`` emits), each bucket padded
 to a multiple of the axis size, and a device's shard is the
 concatenation of its per-bucket sub-chunks.  Bucketing is what lets the
 reduce-scatter start before the last grad exists and the all-gather
-interleave with tail-of-step compute (the ``parallel/overlap`` latency
-story), instead of one monolithic vector serializing the wire behind
-the slowest leaf.
+interleave with tail-of-step compute, instead of one monolithic vector
+serializing the wire behind the slowest leaf.
 
   level 2: grads leave backward via per-bucket ``psum_scatter`` into the
       1/N shard — the full *reduced* f32 gradient vector is never
@@ -75,9 +74,7 @@ from distributeddataparallel_tpu.observability import scopes
 
 Pytree = Any
 
-#: Default bucket granularity for the zero2/zero3 flat layout — matches
-#: the overlap machinery's bucket size so the scatter/gather stream has
-#: the same latency-hiding shape as the bucketed-overlap dp path.
+#: Default bucket granularity for the zero2/zero3 flat layout.
 ZERO_BUCKET_BYTES = 1 << 20
 
 

@@ -302,6 +302,40 @@ def topology_fingerprint(mesh: Mesh | None = None) -> dict:
     return fp
 
 
+def tpu_topology_mesh(topology: str = "v5e:2x4", axis_names=("data",),
+                      shape=None):
+    """An n-chip TPU Mesh from an AOT topology description — no multi-chip
+    hardware required (``jax.experimental.topologies``).  Programs built
+    on this mesh can be ``.lower().compile()``d (not run) to inspect what
+    the real TPU compiler does at scale."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name=topology)
+    devs = np.array(topo.devices)
+    if shape is None:
+        shape = (devs.size,) if len(axis_names) == 1 else None
+    return Mesh(devs.reshape(shape), axis_names)
+
+
+def compiler_stamp() -> dict:
+    """Version stamp for AOT-evidence artifacts: which compiler produced
+    the program an artifact describes.  Evidence without a stamp can't be
+    audited across toolchain bumps."""
+    stamp = {"jax": jax.__version__}
+    try:
+        import jaxlib
+
+        stamp["jaxlib"] = jaxlib.__version__
+    except ImportError:  # pragma: no cover - jaxlib always ships with jax
+        pass
+    try:
+        stamp["backend_platform_version"] = jax.extend.backend.get_backend(
+        ).platform_version
+    except (RuntimeError, AttributeError):
+        pass  # AOT-only processes may have no addressable backend
+    return stamp
+
+
 def barrier(name: str = "ddp_tpu_barrier") -> None:
     """Block until all processes reach this point.
 

@@ -11,10 +11,9 @@ but as a single jit'd SPMD program over the mesh:
 - the batch arrives sharded along the ``data`` axis (one shard per mesh
   position — the role DDP gave to a whole process);
 - ``jax.value_and_grad`` replaces the autograd engine + hooks;
-- ``lax.pmean`` over the data axis replaces the Reducer's bucketed
-  all-reduce, with XLA's latency-hiding scheduler providing the
-  comm/compute overlap (SURVEY.md §3.4); set ``bucket_bytes`` to force
-  explicit DDP-style bucket coalescing instead;
+- ``lax.pmean`` per leaf over the data axis replaces the Reducer's
+  bucketed all-reduce (XLA's combiner merges and schedules them); set
+  ``bucket_bytes`` to force explicit DDP-style bucket coalescing instead;
 - the optax update replaces ``optimizer.step()`` — replicas stay in
   lockstep because they apply identical averaged grads to identical params;
 - gradient accumulation (``accum_steps > 1``) reproduces DDP's
@@ -37,8 +36,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributeddataparallel_tpu.observability import scopes
 from distributeddataparallel_tpu.parallel.data_parallel import (
-    OVERLAP_BUCKET_BYTES,
     all_reduce_gradients,
+    comm_schedule_ir,
 )
 from distributeddataparallel_tpu.training.state import TrainState
 
@@ -54,7 +53,6 @@ def make_train_step(
     axis_name: str = "data",
     accum_steps: int = 1,
     bucket_bytes: int | None = None,
-    overlap: bool = False,
     donate: bool = True,
     with_model_state: bool = False,
     zero: bool | int = False,
@@ -92,24 +90,17 @@ def make_train_step(
       win, the other replicas' updates are discarded).  Choose this for
       bit-level parity with the reference's training behavior.
 
-    ``overlap=True`` is the demonstrated analog of DDP's bucketed
-    all-reduce hidden under backward (ref dpp.py:52, SURVEY §3.4):
-    gradients reduce as unchained reverse-order buckets (sub-MiB leaves
-    coalesced, weight-sized leaves solo in native dtype) and the step
-    compiles with the TPU async-collective/latency-hiding options plus a
-    disabled all-reduce combiner, which schedules real backward compute
-    inside each collective's start/done window — see
-    ``parallel/overlap.py`` and OVERLAP.md for the scheduled-HLO
-    evidence measured on the real GPT-2 step.  Composes with
-    ``accum_steps`` (reduction still fires once per boundary) and
-    ``grad_clip``; on non-TPU backends the buckets still run (semantics
-    identical) without the TPU options.
+    ``bucket_bytes`` sizes the plain-DP gradient exchange: ``None``
+    (default) reduces leaf by leaf, a size coalesces the leaves into
+    reverse-order f32 buckets first (``parallel.data_parallel``).
+    Composes with ``accum_steps`` (the reduction still fires once per
+    boundary) and ``grad_clip``.
 
     ``grad_compress="bf16"`` is the bf16 comm hook (torch DDP's
     ``bf16_compress_hook`` analog): gradient buckets cross the wire in
     bfloat16 and decompress back after the average — half the f32 wire
     bytes, same exponent range so no loss scaling.  Composes with
-    ``overlap``/``bucket_bytes``/``accum_steps``/``grad_clip`` (the clip
+    ``bucket_bytes``/``accum_steps``/``grad_clip`` (the clip
     norm sees the decompressed averaged grads, matching torch's
     hook-then-clip order).  For scanned models syncing in-body, set
     ``TransformerConfig.grad_sync_compress`` for the presynced leaves.
@@ -133,14 +124,13 @@ def make_train_step(
     arXiv 2004.13336).  ``True``/``1``: ZeRO-1 — grads reduce_scatter as
     one flat vector, the update runs on each replica's 1/N shard,
     updated params all_gather back; ``state`` must come from
-    ``zero_state``; mutually exclusive with ``bucket_bytes``/``overlap``.
+    ``zero_state``; mutually exclusive with ``bucket_bytes``.
     ``2``: the BUCKETED layout — grads leave backward via per-bucket
     reduce-scatter (the full reduced f32 gradient vector never
     materializes), update on the shard, per-bucket all-gather back;
     ``bucket_bytes`` now sets the bucket granularity (must match
-    ``zero_state(level=2, bucket_bytes=...)``) and ``overlap`` composes
-    (the TPU latency-hiding options schedule the bucket gathers under
-    tail-of-step compute).  ``3``: additionally params STAY sharded
+    ``zero_state(level=2, bucket_bytes=...)``).  ``3``: additionally
+    params STAY sharded
     between steps (``Zero3Params``) and re-gather bucketwise inside the
     differentiated function at the top of each step, so AD's transpose
     of the gather reduce-scatters the grads; the state never holds a
@@ -152,8 +142,8 @@ def make_train_step(
     ``lambda path: path[0] == "layers"``) marks leaves whose gradients
     the MODEL already reduced over the data axis —
     ``TransformerConfig.grad_sync_axis`` reduces the scanned blocks'
-    grads inside the backward scan body, the only place they can overlap
-    with backward compute.  The step then syncs only the remaining
+    grads inside the backward scan body, the only place they exist
+    before the loop stacks them.  The step then syncs only the remaining
     leaves; re-reducing an averaged gradient would be a numeric no-op
     but pays the full wire bytes twice.
 
@@ -234,13 +224,12 @@ def make_train_step(
     zero_level = int(zero)
     if zero_level not in (0, 1, 2, 3):
         raise ValueError(f"zero={zero!r} (want False/True or a level 0-3)")
-    if zero_level == 1 and (bucket_bytes is not None or overlap):
-        # Level 1's single monolithic flat has no buckets to size or
-        # overlap; levels 2/3 accept both (bucket granularity + the TPU
-        # latency-hiding compile options).
+    if zero_level == 1 and bucket_bytes is not None:
+        # Level 1's single monolithic flat has no buckets to size;
+        # levels 2/3 take it as their bucket granularity.
         raise ValueError("zero=1 does its own reduction; drop "
-                         "bucket_bytes/overlap (or use zero=2/3, whose "
-                         "bucketed stream composes with both)")
+                         "bucket_bytes (or use zero=2/3, whose bucketed "
+                         "stream it sizes)")
     if zero_level >= 2 and (tp_axis is not None or ep_axis is not None):
         raise ValueError(
             "zero=2/3 shard over the data axis only; compose tp/ep with "
@@ -252,9 +241,9 @@ def make_train_step(
         # twice.  grad_sync=False skips the step's sync entirely, so a
         # skip-list is meaningless there.
         raise ValueError("presynced requires grad_sync=True and zero=False")
-    if not grad_sync and (zero or bucket_bytes is not None or overlap):
+    if not grad_sync and (zero or bucket_bytes is not None):
         raise ValueError("grad_sync=False skips the reduction entirely; "
-                         "it does not compose with zero/bucket_bytes/overlap")
+                         "it does not compose with zero/bucket_bytes")
     if grad_compress not in (None, "bf16", "powersgd"):
         raise ValueError(
             f"grad_compress must be None, 'bf16' or 'powersgd'; got "
@@ -331,7 +320,7 @@ def make_train_step(
     # Compilation-affecting factory flags, attached to the returned step
     # as ``aot_signature`` — the warm-start store (training.warm_start)
     # folds this into the executable's invalidation key, so a flag change
-    # (say, overlap on → off) can never silently reuse a stale binary.
+    # (say, donation on → off) can never silently reuse a stale binary.
     # ``presynced`` is a predicate whose identity is process-local; the
     # key can only honestly record its presence.
     aot_signature = {
@@ -339,7 +328,6 @@ def make_train_step(
         "axis_name": axis_name,
         "accum_steps": accum_steps,
         "bucket_bytes": bucket_bytes,
-        "overlap": overlap,
         "donate": donate,
         "with_model_state": with_model_state,
         "zero": zero_level,
@@ -417,7 +405,7 @@ def make_train_step(
     # The unbucketed leaf-wise layout is exactly countable: one psum per
     # param leaf, no more (a second sync is the classic 2x-wire bug).
     _exact = (
-        grad_sync and not zero and bucket_bytes is None and not overlap
+        grad_sync and not zero and bucket_bytes is None
         and grad_compress is None and not with_model_state
         and not nonfinite_guard and grad_clip is None
     )
@@ -427,9 +415,7 @@ def make_train_step(
         grad_reduce=_reduce,
         donate=donate,
         # coalesced buckets and ZeRO master flats legitimately reduce f32
-        allow_f32_reduce=bool(
-            bucket_bytes or overlap or zero or grad_compress
-        ),
+        allow_f32_reduce=bool(bucket_bytes or zero or grad_compress),
         per_leaf_axes=(axis_name,) if _exact else (),
     )
 
@@ -625,20 +611,6 @@ def make_train_step(
         else:
             if grad_sync:
                 # THE DDP moment: average grads across the data axis.
-                # overlap=True: UNCHAINED reverse-order buckets (1 MiB —
-                # leaves above it ride solo in native dtype, sub-MiB
-                # leaves coalesce) + the compiler options' disabled
-                # all-reduce combiner, so every weight-sized bucket stays
-                # a separate collective the TPU async scheduler can hide
-                # under the remaining backward.  Barrier-chaining the
-                # buckets (rounds 1-4) measured WORSE on the real model
-                # step — 12.3% vs 19.1% scheduled overlap at 2.7x the
-                # compile time — because the chain serializes the
-                # collectives themselves (parallel/overlap.py, OVERLAP.md).
-                bb = (
-                    bucket_bytes if bucket_bytes is not None
-                    else (OVERLAP_BUCKET_BYTES if overlap else None)
-                )
                 if grad_compress == "powersgd":
                     # Low-rank comm hook: factors all-reduce instead of
                     # the gradient matrices; hook state (warm Q + error
@@ -654,8 +626,8 @@ def make_train_step(
                     state = state.replace(comm_state=new_comm)
                 elif presynced is None:
                     grads = all_reduce_gradients(
-                        grads, axis_name, op="mean", bucket_bytes=bb,
-                        chain=False, compress=grad_compress,
+                        grads, axis_name, op="mean",
+                        bucket_bytes=bucket_bytes, compress=grad_compress,
                     )
                 else:
                     # Model-synced leaves (grad_sync_axis: reduced inside
@@ -676,8 +648,8 @@ def make_train_step(
                         if not presynced(k)
                     ]
                     rest = iter(all_reduce_gradients(
-                        rest, axis_name, op="mean", bucket_bytes=bb,
-                        chain=False, compress=grad_compress,
+                        rest, axis_name, op="mean",
+                        bucket_bytes=bucket_bytes, compress=grad_compress,
                     ))
                     grads = jax.tree.unflatten(
                         treedef,
@@ -823,25 +795,15 @@ def make_train_step(
     # would silently become a no-op (sum semantics = world_size× the DDP
     # learning rate).  This framework keeps the DDP-style *explicit* sync
     # point — grads stay per-replica until all_reduce_gradients — which is
-    # also what makes the bucketed/overlap variants possible.
+    # also what makes the bucketed variant possible.
     batch_spec = (
         P(axis_name, cp_axis) if cp_axis is not None else P(axis_name)
     )
     jit_kwargs = {"donate_argnums": (0,)} if donate else {}
-    if overlap:
-        # TPU async-collective + latency-hiding-scheduler options; None
-        # (a no-op) on backends whose compiler rejects TPU option names.
-        from distributeddataparallel_tpu.parallel.overlap import (
-            overlap_compiler_options,
-        )
-
-        opts = overlap_compiler_options()
-        if opts:
-            jit_kwargs["compiler_options"] = opts
 
     def _attach_comm_schedule(fn):
-        # Schedule-as-data for the SL3xx linter: bucketed/overlap grad
-        # sync exposes its bucket order as a builder (the partition
+        # Schedule-as-data for the SL3xx linter: bucketed grad sync
+        # exposes its bucket order as a builder (the partition
         # depends on the param tree, so it can't be a constant like the
         # pipeline tick tables).  Compressed sync reduces factors, not
         # buckets — no IR.
@@ -876,18 +838,10 @@ def make_train_step(
             fn.comm_schedule = _zero_cs
         elif (
             grad_sync and not zero_level and grad_compress is None
-            and (bucket_bytes is not None or overlap)
+            and bucket_bytes is not None
         ):
-            from distributeddataparallel_tpu.parallel.overlap import (
-                comm_schedule_ir,
-            )
-
-            _bb = (
-                bucket_bytes if bucket_bytes is not None
-                else OVERLAP_BUCKET_BYTES
-            )
             fn.comm_schedule = lambda params: comm_schedule_ir(
-                params, bucket_bytes=_bb, axis=axis_name
+                params, bucket_bytes=bucket_bytes, axis=axis_name
             )
         return fn
 

@@ -228,7 +228,7 @@ def executable_key(
     Anything that changes the compiled program must be in here:
     topology (platform, device kind, counts), mesh axes/shape, the model
     configuration, the step factory's compilation-affecting flags
-    (donation, overlap, accumulation, ...), and the jax/jaxlib/libtpu
+    (donation, bucketing, accumulation, ...), and the jax/jaxlib/libtpu
     versions.  Keys compare as plain JSON values — a mismatch on load is
     reported field-by-field.
     """

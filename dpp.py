@@ -183,8 +183,7 @@ def parse_args(argv=None):
                         "copy never materializes). --zero 3: params stay "
                         "sharded between steps too (1/N stored), gathered "
                         "per bucket inside the step. Levels 2/3 are "
-                        "data-axis only and compose with --bucket-mb and "
-                        "--overlap")
+                        "data-axis only and compose with --bucket-mb")
     p.add_argument("--moment-dtype", choices=["f32", "bf16", "int8"],
                    default=None,
                    help="optimizer-moment storage under --zero: bf16 or "
@@ -199,18 +198,12 @@ def parse_args(argv=None):
     p.add_argument("--bucket-mb", type=float, default=None,
                    help="explicit DDP-style gradient bucket size in MiB "
                         "(default: let XLA schedule the all-reduce)")
-    p.add_argument("--overlap", action="store_true",
-                   help="demonstrated comm/compute overlap (ref dpp.py:52): "
-                        "chained reverse-order gradient buckets + TPU "
-                        "async-collective/latency-hiding compiler options, "
-                        "so each bucket's all-reduce hides under the "
-                        "remaining backward (see OVERLAP.md)")
     p.add_argument("--grad-compress", choices=["bf16", "powersgd"],
                    default=None,
                    help="comm-hook gradient compression (torch DDP "
                         "ddp_comm_hooks analog). bf16: gradients cross "
                         "the wire in bfloat16, half the f32 bytes; "
-                        "composes with --overlap/--bucket-mb/"
+                        "composes with --bucket-mb/"
                         "--accum-steps/--grad-clip (clip sees "
                         "decompressed grads). powersgd: rank-r low-rank "
                         "factors with per-replica error feedback "
@@ -774,22 +767,6 @@ def validate_args(args) -> None:
             raise SystemExit("--tune-steps must be >= 1")
     if args.remat != "auto" and not is_lm(args):
         raise SystemExit("--remat applies to LM models (--model gpt2|llama)")
-    if args.overlap:
-        # ZeRO-1/FSDP/PP own their reductions (reduce_scatter /
-        # per-layer gathers / stage collectives) — the chained-bucket
-        # overlap path is the plain-DP all-reduce's.  ZeRO-2/3 already
-        # reduce per bucket, so --overlap there only adds the
-        # latency-hiding compiler options.
-        bad = [
-            f for f, on in (
-                ("--zero", args.zero == 1), ("--fsdp", args.fsdp),
-                ("--pp", args.pp > 1),
-            ) if on
-        ]
-        if bad:
-            raise SystemExit(
-                f"--overlap applies to the DP all-reduce; drop {', '.join(bad)}"
-            )
     if args.grad_compress and (args.zero or args.fsdp or args.pp > 1):
         # Those layouts own their reductions (reduce_scatter / per-layer
         # gathers / stage collectives); the comm hook is the plain-DP
@@ -809,11 +786,6 @@ def validate_args(args) -> None:
             raise SystemExit(
                 "--grad-compress powersgd supports DP/CP layouts; drop "
                 "--tp/--ep"
-            )
-        if args.overlap:
-            raise SystemExit(
-                "--grad-compress powersgd replaces the bucketed "
-                "all-reduce --overlap schedules; pick one mechanism"
             )
         if args.powersgd_rank < 1:
             raise SystemExit("--powersgd-rank must be >= 1")
@@ -962,15 +934,6 @@ def build_model(args, num_classes: int = 10, vocab_size: int | None = None):
                     )
                 overrides["num_kv_heads"] = kv
         cfg = family(**overrides)
-        if args.overlap and cfg.scan_layers:
-            # Scanned stacks hold every layer grad inside the backward
-            # while-loop; overlap needs the reduction to fire in there
-            # (sync_grad_in_backward) — the step then skips the "layers"
-            # subtree (presynced, wired at make_train_step below).
-            import dataclasses as _dc
-
-            cfg = _dc.replace(cfg, grad_sync_axis="data",
-                              grad_sync_compress=args.grad_compress)
         return tfm.TransformerLM(cfg)
     raise NotImplementedError(f"--model {args.model}")
 
@@ -1633,7 +1596,6 @@ def train(args) -> float:
             return ddp.make_train_step(
                 loss_fn, mesh=for_mesh, accum_steps=args.accum_steps,
                 bucket_bytes=int(args.bucket_mb * 1024 * 1024) if args.bucket_mb else None,
-                overlap=args.overlap,
                 with_model_state=has_ms, zero=args.zero,
                 buffer_sync=args.buffer_sync,
                 cp_axis="seq" if cp else None,
@@ -1641,12 +1603,6 @@ def train(args) -> float:
                 ep_axis="expert" if args.ep > 1 else None,
                 grad_clip=args.grad_clip,
                 grad_compress=args.grad_compress,
-                presynced=(
-                    (lambda p: p[0] == "layers")
-                    if getattr(getattr(model, "cfg", None),
-                               "grad_sync_axis", None)
-                    else None
-                ),
                 nonfinite_guard=args.nan_guard,
                 integrity_every=(
                     (args.integrity_every or None) if integrity else None
@@ -2194,7 +2150,7 @@ def train(args) -> float:
     )
     # Alerting + run summary: both consume ONLY numbers the window
     # boundary below already computed (same zero-extra-syncs discipline
-    # as the meters above — bench.py pins it).
+    # as the meters above).
     alert_engine = None
     if args.alerts is not None:
         from distributeddataparallel_tpu.observability import (

@@ -283,7 +283,7 @@ def analyze(seq_len: int, microbatches=(1, 2)) -> dict:
     # the implemented full-gather step: the gathered param tree is live
     # at peak, so peak EXCEEDS zero2 by P/N while stored drops to
     # (params + opt)/N — the stored column is what checkpoint/resident
-    # HWM telemetry sees (bench zero_sharding, mesh_sim).
+    # HWM telemetry sees (mesh_sim).
     ZN = 8
     P = params_bytes
     adamw_opt = _tree_bytes(

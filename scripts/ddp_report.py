@@ -698,9 +698,7 @@ def render_markdown(a: dict, events_dir: str) -> str:
         elif el["downtimes"]:
             lines += [
                 "",
-                "No cold restarts in this timeline to reclaim against — "
-                "bench.py's `elastic_resize` section measures resize vs "
-                "supervised restart head-to-head.",
+                "No cold restarts in this timeline to reclaim against.",
             ]
     lines.append("")
 

@@ -5,8 +5,9 @@ Usage:
     # Gate an events dir (run_summary extracted from its timeline):
     python scripts/perf_gate.py EVENTS_DIR --store runs/ --baseline main
 
-    # Gate a bench headline file (BENCH_*.json `parsed.headline`):
-    python scripts/perf_gate.py BENCH_r05.json --store runs/ --baseline bench
+    # Gate a headline file (a JSON object with a flat `parsed.headline`;
+    # no script in the repo writes one any more — ROADMAP D19):
+    python scripts/perf_gate.py HEADLINE.json --store runs/ --baseline bench
 
     # Promote the current run to be the named baseline:
     python scripts/perf_gate.py EVENTS_DIR --store runs/ --baseline main \
@@ -18,7 +19,7 @@ skipped ("missing"), never failed — a run that didn't enable --mfu
 must not fail the MFU gate silently; it must say so.
 
 RUN may be: an events directory (summary rebuilt from its merged
-timeline), a run_summary JSON file, or a BENCH_*.json whose
+timeline), a run_summary JSON file, or a JSON file whose
 ``parsed.headline`` flat metrics are gated pairwise (direction inferred
 from the metric name: bubble/step_s/bytes/overhead/us/restart metrics
 are lower-better, everything else higher-better).
@@ -79,8 +80,8 @@ REGRESS_EXIT = 3
 #:    _frac/_fraction), and failure-adjacent counts (restart, dropped).
 #:
 #: Anything unmatched defaults to "higher" (plain throughput/score
-#: names).  tests/test_protocol_lint.py gates this table against every
-#: headline metric the bench scripts actually emit.
+#: names).  tests/test_protocol_lint.py gates this table against its
+#: recorded list of headline names.
 _DIRECTION_TABLE: tuple[tuple[re.Pattern, str], ...] = (
     (re.compile(r"(tok_s|img_s|_per_s|reclaimed_s|gain_frac|_hit_frac"
                 r"|_avoided_frac|_speedup)$"), "higher"),

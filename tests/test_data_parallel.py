@@ -4,6 +4,7 @@ equivalence, param replication (SURVEY.md §4 'multi-device without a cluster').
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from distributeddataparallel_tpu.parallel.data_parallel import (
@@ -46,17 +47,29 @@ def test_all_reduce_mean_matches_manual(devices):
         np.testing.assert_allclose(out[k], expected[k], rtol=1e-5, atol=1e-7)
 
 
-def test_bucketed_equals_unbucketed(devices):
+@pytest.mark.parametrize(
+    "sizes,small",
+    [
+        (((8, 16), (128,), (4, 4, 4), (1000,)), 2048),
+        # leaves of 28 to 16384 bytes round a 1 KiB edge: some share a
+        # bucket, some overflow one alone
+        (((64, 64), (7,), (33, 5), (256,), (2, 3, 4)), 1024),
+    ],
+    ids=["2KiB", "straddle_1KiB"],
+)
+def test_bucketed_equals_unbucketed(devices, sizes, small):
     mesh = make_mesh(("data",))
     n = mesh.shape["data"]
-    trees = [_grad_tree(jax.random.PRNGKey(100 + i)) for i in range(n)]
+    trees = [
+        _grad_tree(jax.random.PRNGKey(100 + i), sizes) for i in range(n)
+    ]
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
     def f(shard):
         local = jax.tree.map(lambda x: x[0], shard)
         plain = all_reduce_gradients(local, "data", op="mean")
         # tiny bucket size forces multiple buckets; large forces one
-        multi = bucket_gradients(local, "data", op="mean", bucket_bytes=2048)
+        multi = bucket_gradients(local, "data", op="mean", bucket_bytes=small)
         single = bucket_gradients(local, "data", op="mean", bucket_bytes=1 << 30)
         return plain, multi, single
 

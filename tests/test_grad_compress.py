@@ -122,7 +122,7 @@ def test_tpu_compress_wire_dtype(devices):
     the wire (no silent re-promotion), AOT-compiled for the 8-chip v5e
     topology."""
     pytest.importorskip("jax.experimental.topologies")
-    from distributeddataparallel_tpu.parallel.overlap import (
+    from distributeddataparallel_tpu.runtime.distributed import (
         tpu_topology_mesh,
     )
 

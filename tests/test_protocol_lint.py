@@ -477,9 +477,10 @@ def test_ddplint_list_rules_covers_new_layers():
 # --------------------------------------- perf_gate direction table
 
 
-#: every numeric metric the bench headline actually emits (bench.py
-#: ``parsed.headline``), with its documented gate direction — the
-#: whole contract the ordered _DIRECTION_TABLE must reproduce
+#: every numeric metric of the ``parsed.headline`` format perf_gate's
+#: "bench" source reads (no script in the repo writes it any more —
+#: ROADMAP D19), with its documented gate direction — the whole
+#: contract the ordered _DIRECTION_TABLE must reproduce
 BENCH_HEADLINE_DIRECTIONS = {
     "resnet50_img_s_chip": "higher",
     "resnet50_mfu": "higher",

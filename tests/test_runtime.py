@@ -51,6 +51,11 @@ def test_make_mesh_2d(devices):
         dist.make_mesh(("data", "model"), shape=(3, 2))
 
 
+def test_compiler_stamp():
+    stamp = dist.compiler_stamp()
+    assert stamp["jax"]  # at minimum the jax version is always present
+
+
 def test_spawn_single_inprocess():
     out = []
     spawn(lambda i, x: out.append((i, x)), args=(42,), nprocs=1)
