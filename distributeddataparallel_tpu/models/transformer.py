@@ -752,16 +752,17 @@ def conv_init(taps: int):
 class Mamba2Mixer(nn.Module):
     """Mamba-2 mixer (Dao & Gu 2024) as ``GraniteMoeHybrid`` lays it out:
     one input projection to the gate ``z``, the convolved ``x | B | C``
-    and ``dt``; a causal depthwise convolution and SiLU; the state-space
-    scan (``ops.ssd``); the gated norm; the output projection.  Its five
-    parts carry ``scopes.MIXER_SCOPES``.  Data-parallel training only:
-    no tensor or context parallelism, and no decoding state yet."""
+    and ``dt``; a causal depthwise convolution and SiLU
+    (``ops.causal_conv``); the state-space scan (``ops.ssd``); the gated
+    norm; the output projection.  Its five parts carry
+    ``scopes.MIXER_SCOPES``.  Data-parallel training only: no tensor or
+    context parallelism, and no decoding state yet."""
 
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, u):
-        from distributeddataparallel_tpu.ops import ssd
+        from distributeddataparallel_tpu.ops import causal_conv, ssd
 
         cfg = self.cfg
         if cfg.decode or cfg.tp_axis is not None or cfg.cp_axis is not None:
@@ -778,10 +779,9 @@ class Mamba2Mixer(nn.Module):
             kernel_init=nn.initializers.normal(0.02),
         )
         with jax.named_scope(scopes.SSM_IN_PROJ):
-            z, xbc, dt = jnp.split(
-                dense(2 * inner + 2 * bc + H, "in_proj")(u),
-                [inner, 2 * inner + 2 * bc], axis=-1,
-            )
+            # z | x B C | dt; the convolution reads its part in place
+            proj = dense(2 * inner + 2 * bc + H, "in_proj")(u)
+            z, dt = proj[..., :inner], proj[..., 2 * inner + 2 * bc:]
         with jax.named_scope(scopes.SSM_CONV):
             taps = self.param(
                 "conv_kernel", conv_init(K), (K, inner + 2 * bc), jnp.float32,
@@ -789,14 +789,9 @@ class Mamba2Mixer(nn.Module):
             bias = self.param(
                 "conv_bias", conv_init(K), (inner + 2 * bc,), jnp.float32,
             )
-            # tap k reads the step K - 1 - k back: K - 1 zeros to the left
-            padded = jnp.pad(
-                xbc.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0))
+            x, Bm, Cm = causal_conv.causal_conv_silu(
+                proj, taps, bias, (inner, bc, bc), start=inner
             )
-            xbc = nn.silu(bias + sum(
-                taps[k] * padded[:, k:k + S] for k in range(K)
-            )).astype(cfg.dtype)
-            x, Bm, Cm = jnp.split(xbc, [inner, inner + bc], axis=-1)
         with jax.named_scope(scopes.SSD):
             dt_bias = self.param("dt_bias", dt_bias_init, (H,), jnp.float32)
             a_log = self.param("A_log", a_log_init, (H,), jnp.float32)

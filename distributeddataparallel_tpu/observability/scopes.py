@@ -45,8 +45,13 @@ SSM_OUT_PROJ = "ssm_out_proj"
 #: the two ``pallas_call``s of ``ops/ssd.py``, launched under ``SSD``
 SSD_FWD = "ssd_fwd"
 SSD_BWD = "ssd_bwd"
+#: the two ``pallas_call``s of ``ops/causal_conv.py``, launched under
+#: ``SSM_CONV``
+CONV_FWD = "conv_fwd"
+CONV_BWD = "conv_bwd"
 
 STEP_SCOPES = (EMBED, HEAD, LOSS, METRICS, GRAD_SYNC, GRAD_CLIP, OPTIMIZER)
 KERNEL_NAMES = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
 MIXER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSD, SSM_GATE_NORM, SSM_OUT_PROJ)
 SSD_KERNEL_NAMES = (SSD_FWD, SSD_BWD)
+CONV_KERNEL_NAMES = (CONV_FWD, CONV_BWD)

@@ -45,6 +45,10 @@ gained (PERF.md section 6, PR 30).
 **What stays ``jax.numpy``.**  Steps 2 and 3 (the chunk's own state and
 ``_carry_states``), the running sums and the padding, differentiated by
 JAX; and ``_plain_within_chunk``, steps 1 and 4 as PR 28 wrote them.
+What comes before the scan in the mixer, the causal convolution that
+makes ``x``, ``B`` and ``C``, is an op of its own (``ops/causal_conv.py``,
+PR 32), whose kernels write the three arrays sequence minor as
+``_within_chunk`` reads them: no copy lies between the two custom calls.
 
 **Which shapes take which path.**  ``ssd_chunked`` picks from what it is
 given (``supported``): the kernels on a TPU backend where the chunk is a

@@ -430,6 +430,20 @@ def v5e_chip():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e!r}")
 
 
+@pytest.fixture()
+def no_compile_cache():
+    """An entry written without a chip cannot be read back: keep these
+    compiles out of the persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize(
     "B,Sq,Skv,H,Hkv,D,dtype,causal",
     [
@@ -444,26 +458,15 @@ def v5e_chip():
     ],
 )
 def test_flash_forward_compiles_for_v5e(
-    v5e_chip, B, Sq, Skv, H, Hkv, D, dtype, causal
+    v5e_chip, no_compile_cache, B, Sq, Skv, H, Hkv, D, dtype, causal
 ):
-    from jax.experimental.compilation_cache import compilation_cache
-
     q = jax.ShapeDtypeStruct((B, Sq, H, D), dtype, sharding=v5e_chip)
     kv = jax.ShapeDtypeStruct((B, Skv, Hkv, D), dtype, sharding=v5e_chip)
-    # an entry written without a chip cannot be read back: keep this
-    # compile out of the persistent cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(
-            lambda q, k, v: pallas_attention._flash_fwd_impl(
-                q, k, v, causal=causal, interpret=False
-            )
-        ).lower(q, kv, kv).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    text = jax.jit(
+        lambda q, k, v: pallas_attention._flash_fwd_impl(
+            q, k, v, causal=causal, interpret=False
+        )
+    ).lower(q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "flash_fwd" in text
 
@@ -476,14 +479,14 @@ def test_flash_forward_compiles_for_v5e(
         (1, 256, 8, 128, 1, 256, 128, jnp.bfloat16),    # one sub-tile a chunk
     ],
 )
-def test_ssd_kernels_compile_for_v5e(v5e_chip, b, s, h, p, g, n, chunk, dtype):
+def test_ssd_kernels_compile_for_v5e(
+    v5e_chip, no_compile_cache, b, s, h, p, g, n, chunk, dtype
+):
     """The state-space scan's forward and backward kernels (``ops/ssd.py``,
     PR 30), kept in this file because only the process that described the
     topology may compile for it.  Mosaic refused one version of the
     backward that every interpret-mode test had passed (a lane slice of
     a row it held replicated)."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from distributeddataparallel_tpu.ops import ssd
 
     def sds(shape, dt):
@@ -500,15 +503,115 @@ def test_ssd_kernels_compile_for_v5e(v5e_chip, b, s, h, p, g, n, chunk, dtype):
         with mock.patch.object(ssd, "supported", lambda *_: True):
             return ssd.ssd_chunked(*a, chunk=chunk).astype(f32).sum()
 
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(
-            jax.value_and_grad(loss, argnums=range(6))
-        ).lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    text = jax.jit(
+        jax.value_and_grad(loss, argnums=range(6))
+    ).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     assert "ssd_fwd" in text and "ssd_bwd" in text
+
+
+@pytest.mark.parametrize(
+    "s,width,start,dtype",
+    [
+        (4096, 8512, 4096, jnp.bfloat16),   # the granite cell
+        (2048, 4352, 0, jnp.float32),       # xbc an array of its own, f32
+        (8192, 8512, 4096, jnp.bfloat16),   # 64 rows a block
+    ],
+)
+def test_conv_kernels_compile_for_v5e(
+    v5e_chip, no_compile_cache, s, width, start, dtype
+):
+    """The causal convolution's forward and backward kernels
+    (``ops/causal_conv.py``, PR 32) at the cell's channels: lane rotates of
+    a chunk with its halo, dynamic row groups, narrow stores of the taps'
+    gradients — what the interpreter takes and Mosaic might not."""
+    from distributeddataparallel_tpu.ops import causal_conv
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+
+    f32 = jnp.float32
+    splits = (4096, 128, 128)
+    args = (sds((2, s, width), dtype), sds((4, 4352), f32), sds((4352,), f32))
+
+    def loss(*a):
+        # a CPU backend: what `supported` would allow on the chip is asked
+        # for by hand, after it has said so for the described chip
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            assert causal_conv.supported(*a[:2], splits, start)
+            parts = causal_conv.causal_conv_silu(*a, splits, start=start)
+        return sum(p.astype(f32).sum() for p in parts)
+
+    text = jax.jit(
+        jax.value_and_grad(loss, argnums=range(3))
+    ).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "conv_fwd" in text and "conv_bwd" in text
+
+
+def _instructions(text):
+    """``{name: (op, [operand names], op_name)}`` of a compiled module's
+    text."""
+    import re
+
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = .*? ([\w\-]+)\(([^)]*)\)", line)
+        if m:
+            scope = re.search(r'op_name="([^"]*)"', line)
+            out[m.group(1)] = (
+                m.group(2), re.findall(r"%([\w.\-]+)", m.group(3)),
+                scope.group(1) if scope else "",
+            )
+    return out
+
+
+def test_the_granite_step_holds_the_conv_kernels_and_no_copy_round_them(
+    v5e_chip, no_compile_cache
+):
+    """The granite cell's whole step, compiled for the described chip (what
+    ``benchmarks/aot_fit_hybrid.py`` prints, ~1 min): nine ``mamba`` layers
+    launch ``conv_fwd`` twice (remat) and ``conv_bwd`` once beside the 31
+    custom calls there were; ``conv_fwd`` reads ``in_proj``'s own result
+    and the scan's kernels read ``conv_fwd``'s, through bitcasts alone — XLA
+    copies a *slice* for a custom call, which is why the op takes the
+    projection whole and hands back three arrays."""
+    import types
+
+    from benchmarks import aot_fit, harness
+
+    cell = harness.load_cell("granite-4.0-h-micro.train-s4096")
+    seen = {}
+    topo = types.SimpleNamespace(devices=sorted(
+        v5e_chip.device_set, key=lambda d: d.id))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.object(
+                aot_fit, "describe",
+                lambda name, compiled, hbm: seen.update(
+                    text=compiled.as_text())):
+        aot_fit.fit_train(
+            {"cell": cell, "config": cell["config"],
+             "traffic": cell["traffic"], "hbm": 16e9}, topo,
+        )
+    ins = _instructions(seen["text"])
+
+    def calls(kernel):
+        return [n for n, (op, _, scope) in ins.items()
+                if op == "custom-call" and f"/{kernel}/pallas_call" in scope]
+
+    def source(name):  # through what moves no byte
+        while ins[name][0] in ("bitcast", "get-tuple-element"):
+            name = ins[name][1][0]
+        return name
+
+    conv_fwd, conv_bwd = calls("conv_fwd"), calls("conv_bwd")
+    assert seen["text"].count("tpu_custom_call") == 31 + 27
+    assert len(conv_fwd) == 18 and len(conv_bwd) == 9
+    for call in conv_fwd + conv_bwd:
+        op, _, scope = ins[source(ins[call][1][0])]
+        assert op == "fusion" and "/ssm_in_proj/" in scope, (call, op, scope)
+    scans = calls("ssd_fwd") + calls("ssd_bwd")
+    assert len(scans) == 27
+    for call in scans:
+        x = source(ins[call][1][1])  # operands: D, x, ...
+        assert x in conv_fwd, (call, x, ins[x])

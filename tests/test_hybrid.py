@@ -4,6 +4,8 @@ plain reference, the tiny hybrid cell end to end with its control and its
 faults, the benchmark's counts against the program's, and what a
 GPT-2-shaped configuration must not have noticed."""
 
+import contextlib
+import functools
 import json
 import os
 import shutil
@@ -27,7 +29,11 @@ from benchmarks import harness, hybrid_flops, readings_hybrid  # noqa: E402
 from benchmarks.reference import granite_hybrid  # noqa: E402
 from distributeddataparallel_tpu.models import transformer as tfm  # noqa: E402
 from distributeddataparallel_tpu.observability import cost_model  # noqa: E402
-from distributeddataparallel_tpu.ops import pallas_attention, ssd  # noqa: E402
+from distributeddataparallel_tpu.ops import (  # noqa: E402
+    causal_conv,
+    pallas_attention,
+    ssd,
+)
 from distributeddataparallel_tpu.ops.attention import attention  # noqa: E402
 
 DATA = os.path.join(ROOT, "benchmarks", "tests", "data")
@@ -148,16 +154,20 @@ def test_scan_kernels_are_the_recurrence_and_the_plain_form(
         assert np.linalg.norm(a - b) <= 1e-2 * np.linalg.norm(b)
 
 
+@contextlib.contextmanager
 def on_the_kernel_path():
-    """``ssd.ssd_chunked`` with the kernels forced through the interpreter,
-    patched in under its own name so that the benchmark's fault hooks, which
-    patch that name and ``_carry_states``, find it."""
-    import functools
-
-    return mock.patch.object(
+    """``ssd.ssd_chunked`` and ``causal_conv.causal_conv_silu`` with the
+    kernels forced through the interpreter, patched in under their own names
+    so that the benchmark's fault hooks, which patch ``ssd_chunked`` and
+    ``_carry_states``, find them."""
+    with mock.patch.object(
         ssd, "ssd_chunked",
         functools.partial(ssd.ssd_chunked, _interpret=True),
-    )
+    ), mock.patch.object(
+        causal_conv, "causal_conv_silu",
+        functools.partial(causal_conv.causal_conv_silu, _interpret=True),
+    ):
+        yield
 
 
 def test_the_benchmarks_faults_bite_on_the_kernel_path():
@@ -178,6 +188,21 @@ def test_the_benchmarks_faults_bite_on_the_kernel_path():
     np.testing.assert_allclose(
         want, ssd.ssd_chunked(*args, chunk=8),
         atol=1e-5 * float(jnp.abs(want).max()))
+    # through the mixer, the convolution's kernels interpreted too: the
+    # faults patch names the mixer still calls, with what it still passes
+    _, model = tiny_model()
+    params = tiny_weights(model)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 36), 0, 256)
+    logits = lambda: model.apply({"params": params}, tokens)  # noqa: E731
+    plain = logits()
+    size = float(jnp.abs(plain).max())
+    with on_the_kernel_path():
+        sound = logits()
+        np.testing.assert_allclose(sound, plain, atol=2e-5 * size)
+        for fault in ("fault_no_chunk_carry", "fault_no_skip"):
+            with readings_hybrid.FAULTS[fault]():
+                # 7.8e-3 and 1.5e-2 of the largest logit; a sound run 2e-7
+                assert float(jnp.abs(logits() - sound).max()) > 1e-3 * size
 
 
 def test_the_kernels_take_the_cells_shapes_and_count_their_tiles():
@@ -228,13 +253,15 @@ def tiny_weights(model, seed=3):
     )
 
 
+@pytest.mark.parametrize("kernels", [False, True], ids=["jnp", "kernels"])
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_model_matches_the_plain_reference(remat):
+def test_model_matches_the_plain_reference(remat, kernels):
     """Logits, loss and every leaf's gradient, f32, pattern m m a m, a
     sequence of 36 (four chunks of 8 and a padded one).  Tolerance 2e-5 of
     each tensor's largest entry: f32 rounding through four layers, in two
     different orders of summation (chunked products against the
-    recurrence); a dropped term reads 1e-2 and more."""
+    recurrence); a dropped term reads 1e-2 and more.  ``kernels``: the
+    mixer's convolution and scan through their kernels, interpreted."""
     config, model = tiny_model(remat=remat)
     params = tiny_weights(model)
     flat = harness.flatten(params)
@@ -254,7 +281,10 @@ def test_model_matches_the_plain_reference(remat):
             jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
         ), logits
 
-    (loss, logits), grads = jax.value_and_grad(program_loss, has_aux=True)(params)
+    with on_the_kernel_path() if kernels else contextlib.nullcontext():
+        (loss, logits), grads = jax.value_and_grad(
+            program_loss, has_aux=True
+        )(params)
     (ref_loss, ref_logits), ref_grads = jax.value_and_grad(
         reference_loss, has_aux=True
     )(flat)
@@ -470,6 +500,8 @@ def test_mixer_readers_read_the_mixer_and_are_silent_without_one():
         (fwd + "mamba/ssd/dot_general", 3_000_000),
         (bwd + "mamba/ssd/dot_general", 5_000_000),
         (fwd + "mamba/ssm_in_proj/in_proj/dot_general", 2_000_000),
+        (fwd + "mamba/ssm_conv/jit(_fwd_launch)/conv_fwd/pallas_call", 600_000),
+        (bwd + "mamba/ssm_conv/jit(_bwd_launch)/conv_bwd/pallas_call", 900_000),
         (fwd + "mamba/reshape", 500_000),
         (fwd + "mlp/up_proj/dot_general", 7_000_000),
     ])
@@ -487,7 +519,8 @@ def test_mixer_readers_read_the_mixer_and_are_silent_without_one():
     }
     metric = lambda name: harness.load_module("layer_metrics", name).read  # noqa: E731
     assert metric("train_ssd_scan_ms")(ctx) == pytest.approx(4.0)
-    assert metric("train_ssm_mixer_ms")(ctx) == pytest.approx(5.25)
+    assert metric("train_ssm_conv_ms")(ctx) == pytest.approx(0.75)
+    assert metric("train_ssm_mixer_ms")(ctx) == pytest.approx(6.0)
     cost = hybrid_flops.scan_cost(config, 2, 4096)
     assert metric("ssd_scan_roofline")(ctx) == pytest.approx(
         100 * 2 * cost["flops"] / 197e12 / 0.008)
@@ -498,7 +531,8 @@ def test_mixer_readers_read_the_mixer_and_are_silent_without_one():
         _scoped_trace([(fwd + "mlp/up_proj/dot_general", 7_000_000)]), 1
     ) is None
     silent = dict(ctx, mixer_reduced=None)
-    for name in ("train_ssd_scan_ms", "train_ssm_mixer_ms", "ssd_scan_roofline"):
+    for name in ("train_ssd_scan_ms", "train_ssm_mixer_ms", "ssd_scan_roofline",
+                 "train_ssm_conv_ms"):
         assert metric(name)(silent) is None
     assert mixer_scopes.reduce({"names": [], "planes": []}, 1) is None
 

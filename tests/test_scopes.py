@@ -210,33 +210,43 @@ def test_mixer_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
         "jit(s)/jvp(M)/layer_0/mamba/ssd/dot_general") == "block"
 
 
-def test_scan_kernels_carry_the_mixers_ssd_scope_in_both_phases(devices):
-    """``ssd_fwd`` and ``ssd_bwd`` (PR 30) are launched from jitted
-    functions of their own and from a ``custom_vjp``: their operations
-    must still carry ``.../mamba/ssd/...``, which is where
-    ``train_ssd_scan_ms`` and ``ssd_scan_roofline`` look for them — the
+@pytest.mark.parametrize("module,entry,names,part", [
+    ("ssd", "ssd_chunked", scopes.SSD_KERNEL_NAMES, "ssd"),
+    ("causal_conv", "causal_conv_silu", scopes.CONV_KERNEL_NAMES, "ssm_conv"),
+], ids=["ssd", "conv"])
+def test_mixer_kernels_carry_their_parts_scope_in_both_phases(
+    devices, module, entry, names, part
+):
+    """``ssd_fwd`` / ``ssd_bwd`` (PR 30) and ``conv_fwd`` / ``conv_bwd``
+    (PR 32) are launched from jitted functions of their own and from a
+    ``custom_vjp``: their operations must still carry ``.../mamba/ssd/...``
+    and ``.../mamba/ssm_conv/...``, which is where ``train_ssd_scan_ms``,
+    ``ssd_scan_roofline`` and ``train_ssm_conv_ms`` look for them — the
     forward in the forward pass (and again under remat), the backward
     under ``transpose(``.  The kernels are forced through the interpreter;
     on the chip each is one custom call under the same name."""
     import functools
+    import importlib
     from unittest import mock
 
     from benchmarks import mixer_scopes
-    from distributeddataparallel_tpu.ops import ssd
 
     assert set(scopes.SSD_KERNEL_NAMES) == {"ssd_fwd", "ssd_bwd"}
+    assert set(scopes.CONV_KERNEL_NAMES) == {"conv_fwd", "conv_bwd"}
+    op = importlib.import_module(f"distributeddataparallel_tpu.ops.{module}")
     with mock.patch.object(
-        ssd, "ssd_chunked", functools.partial(ssd.ssd_chunked, _interpret=True)
+        op, entry, functools.partial(getattr(op, entry), _interpret=True)
     ):
         text = _tiny_hybrid_step_text()
+    fwd, bwd = names
     found = collections.Counter()
     for scope in _OP_NAME.findall(text):
-        for name in scopes.SSD_KERNEL_NAMES:
+        for name in names:
             if f"/{name}/" in scope:
-                assert mixer_scopes.part_of(scope) == "ssd", scope
+                assert mixer_scopes.part_of(scope) == part, scope
                 found[name, scope_reduce.phase_of(scope, "")] += 1
-    assert found["ssd_fwd", "fwd"] and found["ssd_fwd", "bwd"], found
-    assert found["ssd_bwd", "bwd"] and not found["ssd_bwd", "fwd"], found
+    assert found[fwd, "fwd"] and found[fwd, "bwd"], found
+    assert found[bwd, "bwd"] and not found[bwd, "fwd"], found
 
 
 def test_three_pallas_calls_have_three_names():
