@@ -51,7 +51,11 @@ from distributeddataparallel_tpu.parallel.tensor_parallel import (
 )
 
 
-LAYER_KINDS = frozenset({"attention", "mamba"})
+#: attention kinds beside "attention" (cfg.positional on every layer, no
+#: window): a window of ``sliding_window`` keys with rotated q and k, and
+#: every earlier key with no positions at all
+SLIDING, FULL = "sliding_attention", "full_attention"
+LAYER_KINDS = frozenset({"attention", "mamba", SLIDING, FULL})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,8 +163,55 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: float | None = None
     logits_scaling: float = 1.0
+    # Window and full attention in one stack (``layer_types`` of
+    # "sliding_attention" | "full_attention"): the first sees its own key
+    # and the ``sliding_window - 1`` before it and, where ``positional`` is
+    # "rope", rotates q and k; the second sees every earlier key and
+    # carries no positions.  Each of the next three is skipped at False:
+    # RMSNorm over each head's dims of q and k (before the rotation);
+    # sigmoid(x W_gate) times the attention's result, before ``o_proj``;
+    # a norm after each branch too, before its residual add.
+    sliding_window: int | None = None
+    qk_norm: bool = False
+    attn_output_gate: bool = False
+    post_norms: bool = False
+    # Expert layers as the published sparse models route them (all at
+    # their neutral values leave ``MoEMLP`` as it was).  The first
+    # ``num_dense_layers`` layers keep the dense MLP of ``d_ff``; experts
+    # are ``moe_d_ff`` wide (None: ``d_ff``); ``moe_score_func`` turns the
+    # router's logits into scores ("softmax" | "sigmoid");
+    # ``moe_expert_bias`` adds a per-expert bias to the scores for the
+    # selection only; ``moe_route_norm`` divides the chosen scores by
+    # their sum (None: where ``moe_top_k`` > 1) and ``moe_route_scale``
+    # multiplies them; ``moe_shared_experts`` gated MLPs of ``moe_d_ff``
+    # run on every token beside the routed ones.
+    num_dense_layers: int = 0
+    moe_d_ff: int | None = None
+    moe_score_func: str = "softmax"
+    moe_expert_bias: bool = False
+    moe_route_norm: bool | None = None
+    moe_route_scale: float = 1.0
+    moe_shared_experts: int = 0
+    # The share of the router's ``moe_experts`` held here, (first, count):
+    # one position of an expert-parallel deployment, run without its
+    # exchange.  The layer routes over all experts, computes every
+    # (token, choice) whose expert it holds — dropless: sorted by expert,
+    # one grouped product over the held experts' row groups, a weighted
+    # scatter-add back — and leaves out what the others would have added.
+    # None keeps the dispatches chosen by ``moe_capacity_factor``.
+    moe_experts_held: tuple[int, int] | None = None
 
     def __post_init__(self):
+        if self.scan_layers and self.num_dense_layers:
+            raise ValueError("scan_layers runs one kind of FFN only")
+        if self.moe_experts_held is not None:
+            first, count = self.moe_experts_held  # a JSON list
+            object.__setattr__(self, "moe_experts_held", (first, count))
+            if not (0 <= first and 0 < count and first + count <= self.moe_experts):
+                raise ValueError(
+                    f"moe_experts_held {(first, count)} is no share of "
+                    f"{self.moe_experts} experts"
+                )
         kinds = self.layer_types
         if kinds is None:
             return
@@ -172,6 +223,8 @@ class TransformerConfig:
             )
         if self.scan_layers and set(kinds) != {"attention"}:
             raise ValueError("scan_layers runs attention layers only")
+        if SLIDING in kinds and not self.sliding_window:
+            raise ValueError("sliding_attention layers need sliding_window")
 
     @property
     def kv_heads(self) -> int:
@@ -223,6 +276,30 @@ def granite_4_0_h_micro(**overrides) -> TransformerConfig:
         ssm_conv=4, ssm_chunk=256, embedding_multiplier=12.0,
         residual_multiplier=0.22, attention_multiplier=1.0 / 64,
         logits_scaling=8.0,
+    )
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
+def trinity_mini(**overrides) -> TransformerConfig:
+    """Trinity-Mini (``afmoe``, 26B-A3B): 32 layers in periods of three
+    window layers (2048 keys, RoPE) and one full layer (no positions), 32
+    query heads on 4 of 128 with q/k norms and an output gate, four norms a
+    layer, d 2048; two dense layers of 6144, then 128 sigmoid-routed experts
+    of 1024, 8 a token, normalised and scaled by 2.826, beside one shared
+    expert; embeddings times sqrt(2048), untied head over 200192 ids."""
+    base = dict(
+        vocab_size=200192, num_layers=32, num_heads=32, num_kv_heads=4,
+        head_dim=128, d_model=2048, d_ff=6144, max_seq_len=131072,
+        norm="rmsnorm", activation="swiglu", positional="rope",
+        rope_theta=10000.0, tie_embeddings=False, use_bias=False,
+        layer_types=((SLIDING,) * 3 + (FULL,)) * 8, sliding_window=2048,
+        qk_norm=True, attn_output_gate=True, post_norms=True,
+        embedding_multiplier=2048 ** 0.5, num_dense_layers=2,
+        moe_experts=128, moe_top_k=8, moe_d_ff=1024,
+        moe_score_func="sigmoid", moe_expert_bias=True, moe_route_norm=True,
+        moe_route_scale=2.826, moe_shared_experts=1,
+        moe_experts_held=(0, 128),
     )
     base.update(overrides)
     return TransformerConfig(**base)
@@ -308,12 +385,36 @@ class _RowParallelOut(nn.Module):
         return y
 
 
+def _output_gate(out, gate):
+    """The attention's result times sigmoid(gate), element-wise, before
+    ``o_proj``; the sigmoid in f32."""
+    return (out * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
+
+
+def _select(scores, bias, k: int):
+    """``(gates, idx)``: the ``k`` experts with the largest ``scores + bias``
+    and their bare scores — the bias decides the selection only."""
+    _, idx = jax.lax.top_k(scores + bias, k)
+    return jnp.take_along_axis(scores, idx, axis=-1), idx
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
+    kind: str = "attention"  # or SLIDING / FULL, of cfg.layer_types
 
     @nn.compact
     def __call__(self, x, *, positions=None, rope=None, deterministic=True):
         cfg = self.cfg
+        new = (self.kind != "attention" or cfg.qk_norm
+               or cfg.attn_output_gate)
+        if new and (cfg.decode or cfg.tp_axis is not None
+                    or cfg.cp_axis is not None):
+            raise ValueError(
+                "window / full attention kinds, qk_norm and "
+                "attn_output_gate run data-parallel training only "
+                "(no decode, tp_axis or cp_axis)"
+            )
+        window = cfg.sliding_window if self.kind == SLIDING else None
         B, S, _ = x.shape
         H, Hkv, D = cfg.num_heads, cfg.kv_heads, cfg.dims_per_head
         n_tp = tp_size(cfg.tp_axis)
@@ -331,7 +432,10 @@ class Attention(nn.Module):
         q = dense((Hl, D), "q_proj")(x)
         k = dense((Hkvl, D), "k_proj")(x)
         v = dense((Hkvl, D), "v_proj")(x)
-        if cfg.positional == "rope":
+        if cfg.qk_norm:
+            q = RMSNorm(name="q_norm")(q)
+            k = RMSNorm(name="k_norm")(k)
+        if cfg.positional == "rope" and self.kind != FULL:
             # Tables are computed once in TransformerLM and passed down so
             # they sit outside the scanned/remat'd block body.
             cos, sin = rope if rope is not None else rope_frequencies(
@@ -447,8 +551,10 @@ class Attention(nn.Module):
             # the shared head natively; the XLA path expands internally.
             out = attention(
                 q, k, v, causal=True, impl=cfg.attn_impl,
-                scale=cfg.attention_multiplier,
+                scale=cfg.attention_multiplier, window=window,
             )
+        if cfg.attn_output_gate:
+            out = _output_gate(out, dense((Hl, D), "gate_proj")(x))
         return _RowParallelOut(
             features=cfg.d_model,
             kernel_shape=(H, D, cfg.d_model),
@@ -526,6 +632,21 @@ class MoEMLP(nn.Module):
     on all sources' slots, all_to_alls back, combines its slice, and
     restores replication with an ``all_gather``.
 
+    **Dropless, a held share** (``cfg.moe_experts_held = (first, count)``,
+    ``ops.moe.dropless``): the layer is one position of an expert-parallel
+    deployment run without its exchange.  It routes over all
+    ``moe_experts``, holds ``count`` of them, and computes every (token,
+    choice) whose expert it holds: a sort by expert, one grouped product
+    over the held experts' row groups, a gate-weighted scatter-add back.
+    What the experts it does not hold would have added is left out.  Its
+    parts carry ``scopes.MOE_SCOPES``; the rows each held expert received
+    are sown as ``moe_load``.
+
+    Routing is read from the config: ``moe_score_func``, a selection bias
+    (``moe_expert_bias``: in ``top_k``'s argument only, never in the gate),
+    ``moe_route_norm``, ``moe_route_scale``; ``moe_shared_experts`` gated
+    MLPs run on every token beside the routed ones.
+
     Gradient completeness: replicated params' grads must come out
     complete and identical on every expert-axis position so the
     data-axis sync needs no EP-awareness.  The dense path achieves this
@@ -553,19 +674,64 @@ class MoEMLP(nn.Module):
             raise ValueError(f"ep={n_ep} must divide moe_experts={E}")
         if not 1 <= K <= E:
             raise ValueError(f"moe_top_k={K} must be in [1, {E}]")
-        El = E // n_ep
-        d, f = cfg.d_model, cfg.d_ff
+        held = cfg.moe_experts_held
+        if held is not None and (n_ep > 1 or cfg.tp_axis is not None):
+            raise ValueError(
+                "a held share of the experts (moe_experts_held) runs "
+                "without its exchange: no ep_axis or tp_axis"
+            )
+        El = held[1] if held is not None else E // n_ep
+        d, f = cfg.d_model, cfg.moe_d_ff or cfg.d_ff
 
-        # Router runs replicated (its params are tiny); f32 for a stable
-        # softmax.
-        logits = nn.Dense(
-            E, dtype=jnp.float32, use_bias=False, name="router",
-            kernel_init=nn.initializers.normal(0.02),
-        )(x.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)        # (B, S, E)
-        vals, idx = jax.lax.top_k(probs, K)            # (B, S, K)
-        if K > 1:
-            vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+        # Router runs replicated (its params are tiny); f32 for stable
+        # scores.
+        with jax.named_scope(scopes.MOE_ROUTER):
+            logits = nn.Dense(
+                E, dtype=jnp.float32, use_bias=False, name="router",
+                kernel_init=nn.initializers.normal(0.02),
+            )(x.astype(jnp.float32))
+            if cfg.moe_score_func == "softmax":
+                probs = jax.nn.softmax(logits, axis=-1)    # (B, S, E)
+            elif cfg.moe_score_func == "sigmoid":
+                probs = jax.nn.sigmoid(logits)
+            else:
+                raise ValueError(
+                    f"unknown moe_score_func {cfg.moe_score_func!r}"
+                )
+            if cfg.moe_expert_bias:
+                # in the selection only: the gate reads the bare score
+                bias = self.param(
+                    "expert_bias", nn.initializers.zeros, (E,), jnp.float32
+                )
+                vals, idx = _select(probs, bias, K)
+            else:
+                vals, idx = jax.lax.top_k(probs, K)        # (B, S, K)
+            if (K > 1 if cfg.moe_route_norm is None else cfg.moe_route_norm):
+                vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
+            if cfg.moe_route_scale != 1.0:
+                vals = vals * cfg.moe_route_scale
+
+        init = nn.initializers.normal(0.02)
+        w_up = self.param("experts_up", init, (El, d, f), jnp.float32)
+        w_down = self.param("experts_down", init, (El, f, d), jnp.float32)
+        w_gate = (
+            self.param("experts_gate", init, (El, d, f), jnp.float32)
+            if cfg.activation == "swiglu"
+            else None
+        )
+
+        def shared(out):
+            if not cfg.moe_shared_experts:
+                return out
+            with jax.named_scope(scopes.MOE_SHARED):
+                wide = dataclasses.replace(
+                    cfg, d_ff=f * cfg.moe_shared_experts
+                )
+                return out + MLP(wide, name="shared")(x)
+
+        if held is not None:
+            return shared(self._dropless(x, vals, idx, w_gate, w_up, w_down))
+
         sel = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # (B, S, K, E)
 
         # Load-balance auxiliary (Fedus et al. / GShard): E * sum f_e*P_e,
@@ -580,15 +746,6 @@ class MoEMLP(nn.Module):
             E * jnp.sum(frac * probs.mean(axis=(0, 1))),
         )
 
-        init = nn.initializers.normal(0.02)
-        w_up = self.param("experts_up", init, (El, d, f), jnp.float32)
-        w_down = self.param("experts_down", init, (El, f, d), jnp.float32)
-        w_gate = (
-            self.param("experts_gate", init, (El, d, f), jnp.float32)
-            if cfg.activation == "swiglu"
-            else None
-        )
-
         def experts(z):
             """Batched expert MLP: (El, n, d) -> (El, n, d)."""
             h = jnp.einsum("end,edf->enf", z, w_up.astype(cfg.dtype))
@@ -600,7 +757,7 @@ class MoEMLP(nn.Module):
             return jnp.einsum("enf,efd->end", h, w_down.astype(cfg.dtype))
 
         if cfg.moe_capacity_factor > 0:
-            return self._token_choice(x, vals, idx, experts, n_ep)
+            return shared(self._token_choice(x, vals, idx, experts, n_ep))
 
         # --- Dense einsum dispatch ---------------------------------------
         # Dense combine weights: w[b,s,e] = this token's gate for expert
@@ -637,7 +794,34 @@ class MoEMLP(nn.Module):
         )
         if cfg.ep_axis is not None and n_ep > 1:
             out = reduce_from_tp(out, cfg.ep_axis)
-        return out
+        return shared(out)
+
+    def _dropless(self, x, vals, idx, w_gate, w_up, w_down):
+        """The held experts' part (``ops.moe.dropless``)."""
+        from distributeddataparallel_tpu.ops import grouped_matmul as gm
+        from distributeddataparallel_tpu.ops import moe
+
+        cfg = self.cfg
+        B, S, d = x.shape
+        first, count = cfg.moe_experts_held
+        xt = x.reshape(B * S, d).astype(cfg.dtype)
+        w_up, w_down = w_up.astype(cfg.dtype), w_down.astype(cfg.dtype)
+
+        def experts(rows, layout):
+            h = gm.grouped_matmul(rows, w_up, layout)
+            if w_gate is not None:
+                h = nn.silu(gm.grouped_matmul(
+                    rows, w_gate.astype(cfg.dtype), layout)) * h
+            else:
+                h = nn.gelu(h, approximate=True)
+            return gm.grouped_matmul(h, w_down, layout)
+
+        out, sizes = moe.dropless(
+            xt, vals.reshape(B * S, -1), idx.reshape(B * S, -1),
+            cfg.moe_experts, first, count, experts, gm.row_tile(xt, w_up),
+        )
+        self.sow("intermediates", "moe_load", sizes)
+        return out.reshape(B, S, d)
 
     def _token_choice(self, x, vals, idx, experts, n_ep):
         """Capacity-bounded token-choice dispatch (ops.moe)."""
@@ -812,6 +996,7 @@ class Mamba2Mixer(nn.Module):
 class DecoderBlock(nn.Module):
     cfg: TransformerConfig
     kind: str = "attention"  # this layer's mixer, of cfg.layer_types
+    index: int = 0           # its place in the stack (cfg.num_dense_layers)
 
     @nn.compact
     def __call__(self, x, positions=None, rope=None, deterministic=True):
@@ -823,20 +1008,24 @@ class DecoderBlock(nn.Module):
                 branch = branch * cfg.residual_multiplier
             return x + drop(branch)
 
+        def after(branch, name):
+            return _make_norm(cfg, name)(branch) if cfg.post_norms else branch
+
         if self.kind == "mamba":
             y = _make_norm(cfg, "mamba_norm")(x)
             x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
         else:
             y = _make_norm(cfg, "attn_norm")(x)
-            x = add(x, Attention(cfg, name="attn")(
+            x = add(x, after(Attention(cfg, self.kind, name="attn")(
                 y, positions=positions, rope=rope, deterministic=deterministic
-            ))
+            ), "post_attn_norm"))
         y = _make_norm(cfg, "mlp_norm")(x)
         mlp = (
-            MoEMLP(cfg, name="mlp") if cfg.moe_experts > 0
+            MoEMLP(cfg, name="mlp")
+            if cfg.moe_experts > 0 and self.index >= cfg.num_dense_layers
             else MLP(cfg, name="mlp")
         )
-        return add(x, mlp(y))
+        return add(x, after(mlp(y), "post_mlp_norm"))
 
 
 class _ScanBlock(nn.Module):
@@ -1031,8 +1220,12 @@ class TransformerLM(nn.Module):
                 else DecoderBlock
             )
             kinds = cfg.layer_types or ("attention",) * cfg.num_layers
+            if cfg.num_dense_layers and cfg.moe_experts == 0:
+                raise ValueError("num_dense_layers counts layers before experts")
             for i, kind in enumerate(kinds):
-                x = block_cls(cfg, kind, name=f"layer_{i}")(
+                # the index is told only where it decides the layer's FFN
+                place = {"index": i} if cfg.num_dense_layers else {}
+                x = block_cls(cfg, kind, name=f"layer_{i}", **place)(
                     x, positions, rope, deterministic
                 )
 

@@ -121,6 +121,21 @@ def ssd_cost(batch: int, seq: int, heads: int, head_dim: int, state: int,
     return {"flops": 3 * tokens * per_token, "bytes": bytes_}
 
 
+def moe_cost(rows: int, d: int, f: int, groups: int) -> dict:
+    """FLOPs and least HBM bytes of one expert layer's grouped products
+    (``ops.moe.grouped_matmul``: gate, up and down of a gated MLP,
+    ``d -> f -> d``) in one train step, from shapes alone — whatever
+    implements them.  ``rows`` (token, choice) pairs over ``groups`` held
+    experts: three products of ``2 rows d f`` forward, times 3 for forward
+    and backward; bytes: the ``groups`` experts' three matrices read
+    forward, read backward and their gradients written (bf16), the rows
+    in and out and their gradients (bf16), once each."""
+    return {
+        "flops": 3 * 3 * 2 * rows * d * f,
+        "bytes": 2 * (3 * 3 * groups * d * f + 4 * rows * d),
+    }
+
+
 def simple_cnn_fwd_flops(
     *,
     batch: int,

@@ -50,8 +50,28 @@ SSD_BWD = "ssd_bwd"
 CONV_FWD = "conv_fwd"
 CONV_BWD = "conv_bwd"
 
+#: the five parts of ``models.transformer.MoEMLP`` where it holds a share
+#: of the experts (a Flax module named ``mlp``: ``layer_<i>/mlp/<part>/...``):
+#: router matmul, scores, ``top_k`` and gate weights; the sort, the group
+#: sizes and the row gather; the grouped products and the activation; the
+#: gate-weighted scatter-add; the shared expert.  A tuple of their own, read
+#: by the benchmark's ``moe_scopes`` and not by its bucket table (which
+#: counts all of it under ``mlp``)
+MOE_ROUTER = "moe_router"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_COMBINE = "moe_combine"
+MOE_SHARED = "moe_shared"
+#: the two ``pallas_call``s of ``ops/grouped_matmul.py``, launched under
+#: ``MOE_EXPERTS``: rows times an expert's matrix (and ``dy`` times its
+#: transpose), and the matrices' gradient
+MOE_GMM = "moe_gmm"
+MOE_TGMM = "moe_tgmm"
+
 STEP_SCOPES = (EMBED, HEAD, LOSS, METRICS, GRAD_SYNC, GRAD_CLIP, OPTIMIZER)
 KERNEL_NAMES = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
 MIXER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSD, SSM_GATE_NORM, SSM_OUT_PROJ)
 SSD_KERNEL_NAMES = (SSD_FWD, SSD_BWD)
 CONV_KERNEL_NAMES = (CONV_FWD, CONV_BWD)
+MOE_KERNEL_NAMES = (MOE_GMM, MOE_TGMM)
+MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, MOE_SHARED)

@@ -84,15 +84,20 @@ def causal_mask_bias(
     q_offset: jnp.ndarray | int = 0,
     kv_offset: jnp.ndarray | int = 0,
     dtype=jnp.float32,
+    window: int | None = None,
 ) -> jnp.ndarray:
-    """(q_len, kv_len) additive bias: 0 where kv_pos <= q_pos, NEG_INF above.
+    """(q_len, kv_len) additive bias: 0 where kv_pos <= q_pos, NEG_INF above
+    and, under a ``window``, where ``q_pos - kv_pos >= window``.
 
     Offsets give the *global* position of each chunk's first element, which
     is what ring attention needs to mask cross-chunk blocks correctly.
     """
     q_pos = q_offset + jnp.arange(q_len)[:, None]
     kv_pos = kv_offset + jnp.arange(kv_len)[None, :]
-    return jnp.where(kv_pos <= q_pos, 0.0, NEG_INF).astype(dtype)
+    seen = kv_pos <= q_pos
+    if window is not None:
+        seen = seen & (q_pos - kv_pos < window)
+    return jnp.where(seen, 0.0, NEG_INF).astype(dtype)
 
 
 def dot_product_attention(
@@ -103,13 +108,17 @@ def dot_product_attention(
     causal: bool = True,
     bias: jnp.ndarray | None = None,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """XLA reference attention. q: (B,Sq,H,D); k/v: (B,Skv,H,D) -> (B,Sq,H,D).
 
     Softmax in float32; matmuls in the input dtype (bf16 on TPU hits the
     MXU; the f32 softmax runs on the VPU and fuses with the scale/mask).
-    ``scale`` multiplies the scores; None is 1/sqrt(D).
+    ``scale`` multiplies the scores; None is 1/sqrt(D).  A ``window``
+    (causal only) hides the keys ``window`` or more behind a query.
     """
+    if window is not None and not causal:
+        raise ValueError("a window bounds causal attention only")
     *_, Sq, H, D = q.shape
     Skv = k.shape[1]
     if scale is None:
@@ -118,7 +127,9 @@ def dot_product_attention(
     if causal:
         # Sq != Skv (decode / chunked queries): queries are the LAST Sq
         # positions of the kv sequence, so a 1-token query sees everything.
-        logits = logits + causal_mask_bias(Sq, Skv, q_offset=Skv - Sq)[None, None]
+        logits = logits + causal_mask_bias(
+            Sq, Skv, q_offset=Skv - Sq, window=window
+        )[None, None]
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
     weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
@@ -133,9 +144,12 @@ def attention(
     causal: bool = True,
     impl: str = "auto",
     scale: float | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Dispatch: 'xla' reference, 'pallas' flash kernel, or 'auto'.
-    ``scale`` multiplies the scores; None is 1/sqrt(head_dim).
+    ``scale`` multiplies the scores; None is 1/sqrt(head_dim).  A static
+    ``window`` (causal only; None: none) lets a query see its own key and
+    the ``window - 1`` before it.
 
     'auto' uses the Pallas flash kernel on TPU whenever the shapes are
     ``supported()`` and the XLA reference otherwise; the choice is made
@@ -156,7 +170,7 @@ def attention(
 
         if pallas_attention.supported(q, k, v):
             return pallas_attention.flash_attention(
-                q, k, v, causal, False, scale
+                q, k, v, causal, False, scale, window
             )
         if impl == "pallas":
             raise ValueError(
@@ -171,4 +185,6 @@ def attention(
             )
         k = repeat_kv(k, H // Hkv)
         v = repeat_kv(v, H // Hkv)
-    return dot_product_attention(q, k, v, causal=causal, scale=scale)
+    return dot_product_attention(
+        q, k, v, causal=causal, scale=scale, window=window
+    )

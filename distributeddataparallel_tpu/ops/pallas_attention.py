@@ -33,6 +33,14 @@ the LM configs (BASELINE 4-5), written against the Pallas TPU guide
   either direction — backward peak memory is O(S) per device, which is
   what bounds long-context training.
 
+- A static ``window`` (None: none) bounds the visible keys on the left
+  too: key j is visible to query i iff ``j <= i`` and ``i - j < window``
+  (``_kv_window`` and the four functions that state the convention).  The
+  forward's grid then has only as many K/V fetches as a q block's window
+  can touch, starting at the window's first tile; the backward grids walk
+  the live tiles of a row or column alone.  Tiles left of the window are
+  never entered and their K/V never fetched.
+
 CPU tests run the same kernel under ``interpret=True``.
 """
 
@@ -87,21 +95,36 @@ def supported(q, k, v) -> bool:
     )
 
 
-def _block_live(i, j, *, causal: bool, block_q: int, block_k: int, q_offset: int):
+def _block_live(i, j, *, causal: bool, block_q: int, block_k: int,
+                q_offset: int, window: int | None = None):
     """Causal block-skip predicate shared by forward and backward kernels:
     the (q block i, kv block j) tile is live unless it sits strictly above
-    the diagonal.  q_offset aligns query rows to the END of the kv
+    the diagonal or, under a ``window``, wholly left of what its first
+    query sees.  q_offset aligns query rows to the END of the kv
     sequence (the Sq != Skv decode convention)."""
+    if not causal:
+        return True
     q_last = q_offset + i * block_q + block_q - 1
-    return (not causal) or (j * block_k <= q_last)
+    live = j * block_k <= q_last
+    if window is not None:
+        q_first = q_offset + i * block_q
+        live = live & (j * block_k + block_k - 1 + window > q_first)
+    return live
 
 
-def _block_unmasked(i, j, *, causal: bool, block_q: int, block_k: int, q_offset: int):
+def _block_unmasked(i, j, *, causal: bool, block_q: int, block_k: int,
+                    q_offset: int, window: int | None = None):
     """The (q block i, kv block j) tile lies wholly at or below the
-    diagonal — its last key is visible to its first query — so it needs
-    no mask.  Non-causal tiles never do."""
+    diagonal — its last key is visible to its first query — and, under a
+    ``window``, wholly inside it — its first key is visible to its last
+    query — so it needs no mask.  Non-causal tiles never do."""
+    if not causal:
+        return True
     q_first = q_offset + i * block_q
-    return (not causal) or (j * block_k + block_k - 1 <= q_first)
+    free = j * block_k + block_k - 1 <= q_first
+    if window is not None:
+        free = free & (j * block_k + window > q_first + block_q - 1)
+    return free
 
 
 def _kv_span(i, *, causal: bool, block_q: int, block_k: int, q_offset: int, n_k: int):
@@ -114,6 +137,38 @@ def _kv_span(i, *, causal: bool, block_q: int, block_k: int, q_offset: int, n_k:
         return n_k, n_k
     q_first = q_offset + i * block_q
     return (q_first + 1) // block_k, (q_first + block_q - 1) // block_k + 1
+
+
+def _kv_window(i, *, block_q: int, block_k: int, q_offset: int,
+               window: int | None):
+    """``(start, lo)`` for q block ``i`` under a ``window``: kv tiles
+    ``[0, start)`` lie wholly left of the window (dead), ``[start, lo)``
+    are crossed by its edge, and from ``lo`` on a tile is ``_block_unmasked``
+    as far as ``_kv_span``'s ``full`` reaches (``lo`` may lie past ``full``:
+    then edge and diagonal cross the same tiles and none is unmasked).
+    ``(0, 0)`` without a window.  Counted in closed form like ``_kv_span``;
+    ``i`` may be a Python int or a traced scalar."""
+    if window is None:
+        return 0, 0
+    # floor divisions of what may be negative, clipped at tile 0: the
+    # clip is taken first, so that no negative number is divided
+    top = max if isinstance(i, int) else jnp.maximum
+    q_first = q_offset + i * block_q
+    return (top(q_first - window + 1, 0) // block_k,
+            top(q_first + block_q - 1 - window + block_k, 0) // block_k)
+
+
+def _q_span(j, *, block_q: int, block_k: int, q_offset: int,
+            window: int | None, n_q: int):
+    """``(first, last)`` q blocks (inclusive) whose tile with kv block
+    ``j`` is ``_block_live`` — ``_kv_span`` and ``_kv_window`` read by
+    column, for the dk/dv kernel's walk.  Causal only."""
+    top, least = (max, min) if isinstance(j, int) else (jnp.maximum, jnp.minimum)
+    first = top(j * block_k - q_offset, 0) // block_q
+    if window is None:
+        return first, n_q - 1
+    hi = (j + 1) * block_k + window - 2 - q_offset
+    return first, least(top(hi, 0) // block_q, n_q - 1)
 
 
 class FwdPlan(NamedTuple):
@@ -139,51 +194,77 @@ class TileCounts(NamedTuple):
 _KV_FETCH_BYTES = 1 << 20
 
 
-def _fwd_plan(Sq: int, Skv: int, D: int, itemsize: int) -> FwdPlan:
+def _fwd_plan(Sq: int, Skv: int, D: int, itemsize: int,
+              window: int | None = None) -> FwdPlan:
     """The forward's tile plan, chosen from what the operands show.
 
     Score tiles are ``_pick_block`` squares as in the backward kernels.
     K/V are fetched as one block for the whole q block where that fits
     ``_KV_FETCH_BYTES`` (all of Skv at the training shapes: no dead grid
     steps), else in the largest multiple of the tile that divides Skv.
+    Under a ``window`` a fetch is at most half of it, so that what a q
+    block fetches stays near what it can see.
     """
     block_q = _pick_block(Sq)
     block_k = _pick_block(Skv)
     n_k = Skv // block_k
     fit = max(1, _KV_FETCH_BYTES // (block_k * D * itemsize))
+    if window is not None:
+        fit = min(fit, max(1, window // (2 * block_k)))
     per_fetch = max(r for r in range(1, n_k + 1) if n_k % r == 0 and r <= fit)
     return FwdPlan(block_q, block_k, per_fetch * block_k)
 
 
-def fwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
-                    plan: FwdPlan) -> TileCounts:
-    """How often each path of the forward kernel engages — static, like
-    the mechanism: ``_kv_span`` summed over the q blocks."""
-    n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
-    spans = [
-        _kv_span(i, causal=causal, block_q=plan.block_q,
-                 block_k=plan.block_k, q_offset=q_offset, n_k=n_k)
-        for i in range(n_q)
-    ]
-    unmasked = sum(full for full, _ in spans)
-    live = sum(live for _, live in spans)
-    return TileCounts(
-        unmasked, live - unmasked, n_q * n_k - live,
-        n_q * (Skv // plan.block_kv),
+def _fetch_steps(Sq: int, Skv: int, q_offset: int, plan: FwdPlan,
+                 window: int | None) -> int:
+    """K/V fetches a q block's grid row makes: all of them without a
+    window, else as many as the widest q block's live tiles touch."""
+    per_fetch = plan.block_kv // plan.block_k
+    if window is None:
+        return Skv // plan.block_kv
+    geom = dict(block_q=plan.block_q, block_k=plan.block_k, q_offset=q_offset)
+    return max(
+        (_kv_span(i, causal=True, n_k=0, **geom)[1] - 1) // per_fetch
+        - _kv_window(i, window=window, **geom)[0] // per_fetch + 1
+        for i in range(Sq // plan.block_q)
     )
 
 
-def _causal_mask_scores(s, i, j, *, block_q: int, block_k: int, q_offset: int):
-    """Mask the (BQ, BK) score tile above the diagonal with NEG_INF —
-    the single in-kernel statement of the position convention (one copy,
-    so forward and backward can never drift)."""
+def fwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
+                    plan: FwdPlan, window: int | None = None) -> TileCounts:
+    """How often each path of the forward kernel engages — static, like
+    the mechanism: ``_kv_span`` and ``_kv_window`` summed over the q
+    blocks."""
+    n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
+    geom = dict(block_q=plan.block_q, block_k=plan.block_k, q_offset=q_offset)
+    unmasked = live = 0
+    for i in range(n_q):
+        full, end = _kv_span(i, causal=causal, n_k=n_k, **geom)
+        start, lo = _kv_window(i, window=window, **geom)
+        unmasked += max(full - lo, 0)
+        live += end - start
+    return TileCounts(
+        unmasked, live - unmasked, n_q * n_k - live,
+        n_q * _fetch_steps(Sq, Skv, q_offset, plan, window),
+    )
+
+
+def _causal_mask_scores(s, i, j, *, block_q: int, block_k: int, q_offset: int,
+                        window: int | None = None):
+    """Mask the (BQ, BK) score tile above the diagonal, and left of the
+    ``window``, with NEG_INF — the single in-kernel statement of the
+    position convention (one copy, so forward and backward can never
+    drift)."""
     q_pos = q_offset + i * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
     k_pos = j * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1
     )
-    return jnp.where(k_pos <= q_pos, s, NEG_INF)
+    seen = k_pos <= q_pos
+    if window is not None:
+        seen = seen & (q_pos - k_pos < window)
+    return jnp.where(seen, s, NEG_INF)
 
 
 def _lanes(x, n: int):
@@ -203,16 +284,22 @@ def _flash_kernel(
                           # satisfy the TPU (8, 128) block-tiling minimum
     m_ref, l_ref, acc_ref,  # VMEM scratch: (BQ, 128), (BQ, 128), (BQ, D)
     *, causal: bool, block_k: int, scale: float, q_offset: int,
+    window: int | None = None,
 ):
     """One grid step owns one q block of one (batch*head) row and one
     fetched K/V block of ``BKV`` rows, and walks that block in score tiles
     of ``block_k`` rows: first the tiles below the diagonal (no mask),
     then the tiles the diagonal crosses; tiles above it are never entered.
+    Under a ``window`` the row's first step fetches the block that holds
+    the window's first tile, and the walk starts there: the tiles its edge
+    crosses (mask), the tiles between edge and diagonal (none), the
+    diagonal's (mask).
 
     The row statistics m, l, the correction and lse are (BQ, 128)
     lane-replicated from the reduction (``keepdims``) to the store.  The
-    scratch has no initial state: kv tile 0, which every q block sees,
-    writes it (``opening``) where the others update it.
+    scratch has no initial state: the first live kv tile (tile 0 without a
+    window), which every q block sees, writes it (``opening``) where the
+    others update it.
     """
     _, block_q, D = q_ref.shape
     per_fetch = k_ref.shape[1] // block_k
@@ -222,6 +309,10 @@ def _flash_kernel(
     geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
     full, live = _kv_span(i, causal=causal, n_k=per_fetch * nf, **geom)
     first = jf * per_fetch  # the kv tile this fetch starts at
+    if window is not None:
+        start, lo = _kv_window(i, window=window, **geom)
+        first = (start // per_fetch + jf) * per_fetch
+        geom["window"] = window
 
     q = q_ref[0]  # (BQ, D)
     # scale moves onto q where that is bit-exact (a power of two, 2^-3 at
@@ -270,7 +361,13 @@ def _flash_kernel(
 
     @pl.when(jf == 0)
     def _open():
-        if causal:
+        if window is not None:
+            jax.lax.cond(
+                (lo <= start) & (start < full),
+                lambda: tile(start, masked=False, opening=True),
+                lambda: tile(start, masked=True, opening=True),
+            )
+        elif causal:
             jax.lax.cond(
                 full > 0,
                 lambda: tile(0, masked=False, opening=True),
@@ -279,9 +376,14 @@ def _flash_kernel(
         else:
             tile(0, masked=False, opening=True)
 
-    run(1, full, masked=False)
-    if causal:
-        run(jnp.maximum(full, 1), live, masked=True)
+    if window is not None:
+        run(start + 1, jnp.minimum(lo, full), masked=True)
+        run(jnp.maximum(lo, start + 1), full, masked=False)
+        run(jnp.maximum(full, start + 1), live, masked=True)
+    else:
+        run(1, full, masked=False)
+        if causal:
+            run(jnp.maximum(full, 1), live, masked=True)
 
     @pl.when(jf == nf - 1)
     def _finish():
@@ -301,7 +403,9 @@ def _gqa_kv_row(b, *, H: int, Hkv: int):
 
 
 def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool,
-                    scale: float | None = None):
+                    scale: float | None = None, window: int | None = None):
+    if window is not None and not causal:
+        raise ValueError("a window bounds causal attention only")
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     Hkv = k.shape[2]
@@ -322,7 +426,7 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool,
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Skv, D)
     out, lse = _fwd_launch(
         qf, kf, vf, H=H, Hkv=Hkv, causal=causal, interpret=interpret,
-        scale=scale,
+        scale=scale, window=window,
     )
     out = out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     # lse stays in its (B*H, 8, Sq) sublane-broadcast layout: the backward
@@ -332,10 +436,11 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("H", "Hkv", "causal", "interpret", "scale")
+    jax.jit,
+    static_argnames=("H", "Hkv", "causal", "interpret", "scale", "window"),
 )
 def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
-                scale: float | None = None):
+                scale: float | None = None, window: int | None = None):
     """The forward's one ``pallas_call``, on flat (rows, S, D) operands.
 
     Jitted on its own so that a model's layers share one trace and one
@@ -349,14 +454,21 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     q_offset = Skv - Sq
-    plan = _fwd_plan(Sq, Skv, D, qf.dtype.itemsize)
-    counts = fwd_tile_counts(Sq, Skv, causal, q_offset, plan)
+    plan = _fwd_plan(Sq, Skv, D, qf.dtype.itemsize, window)
+    counts = fwd_tile_counts(Sq, Skv, causal, q_offset, plan, window)
     block_q, block_k, block_kv = plan
     per_fetch = block_kv // block_k
     kv_row = functools.partial(_gqa_kv_row, H=H, Hkv=Hkv)
 
     def kv_index(b, i, jf):
-        if causal and Skv > block_kv:
+        if window is not None:
+            # the row's steps name the blocks from the window's first tile
+            # to the diagonal's; a step past it names that last one again
+            geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
+            start, _ = _kv_window(i, window=window, **geom)
+            _, live = _kv_span(i, causal=True, n_k=Skv // block_k, **geom)
+            jf = jnp.minimum(start // per_fetch + jf, (live - 1) // per_fetch)
+        elif causal and Skv > block_kv:
             # a step above the diagonal names the block already in VMEM:
             # no DMA is issued for K/V it will not read
             _, live = _kv_span(
@@ -369,13 +481,15 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
     kernel = functools.partial(
         _flash_kernel,
         causal=causal, block_k=block_k, scale=scale, q_offset=q_offset,
+        **({} if window is None else {"window": window}),
     )
     from jax.experimental.pallas import tpu as pltpu
 
     tiles = rows * (counts.unmasked + counts.masked)
     return pl.pallas_call(
         kernel,
-        grid=(rows, Sq // block_q, Skv // block_kv),
+        grid=(rows, Sq // block_q,
+              _fetch_steps(Sq, Skv, q_offset, plan, window)),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, jf: (b, i, 0)),
             pl.BlockSpec((1, block_kv, D), kv_index),
@@ -405,27 +519,30 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
     )(qf, kf, vf)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
-                    scale: float | None = None):
+                    scale: float | None = None, window: int | None = None):
     """Flash attention: q,k,v (B,S,H,D) -> (B,S,H,D), causal by default;
-    ``scale`` multiplies the scores (None: 1/sqrt(D))."""
+    ``scale`` multiplies the scores (None: 1/sqrt(D)); under a ``window``
+    a query sees its own key and the ``window - 1`` before it."""
     out, _ = _flash_fwd_impl(
-        q, k, v, causal=causal, interpret=interpret, scale=scale
+        q, k, v, causal=causal, interpret=interpret, scale=scale,
+        window=window,
     )
     return out
 
 
-def _fwd(q, k, v, causal, interpret, scale):
+def _fwd(q, k, v, causal, interpret, scale, window):
     out, lse = _flash_fwd_impl(
-        q, k, v, causal=causal, interpret=interpret, scale=scale
+        q, k, v, causal=causal, interpret=interpret, scale=scale,
+        window=window,
     )
     return out, (q, k, v, out, lse)
 
 
 def _recompute_p_ds(
     q, k, v, do, lse, delta, *,
-    i, j, causal, block_q, block_k, scale, q_offset,
+    i, j, causal, block_q, block_k, scale, q_offset, window=None,
 ):
     """Shared blockwise backward math for one (q block i, kv block j) tile.
 
@@ -442,7 +559,8 @@ def _recompute_p_ds(
     ) * scale  # (BQ, BK)
     if causal:
         s = _causal_mask_scores(
-            s, i, j, block_q=block_q, block_k=block_k, q_offset=q_offset
+            s, i, j, block_q=block_q, block_k=block_k, q_offset=q_offset,
+            **({} if window is None else {"window": window}),
         )
     p = jnp.exp(s - lse[:, None])  # masked entries: exp(NEG_INF - lse) = 0
     dp = jax.lax.dot_general(
@@ -458,30 +576,40 @@ def _bwd_dq_kernel(
     dq_ref,                                           # (1, BQ, D)
     dq_acc,                                           # VMEM (BQ, D) f32
     *, causal: bool, block_q: int, block_k: int, scale: float, q_offset: int,
+    window: int | None = None,
 ):
+    """Grid (B*H, q blocks, kv steps).  Without a window a step is a kv
+    block; under one, the q block's step ``y`` is its ``y``-th live kv
+    block, counted from the window's first (``_kv_window``), and a step
+    past the diagonal's does nothing."""
     i = pl.program_id(1)  # q block (outer)
     j = pl.program_id(2)  # kv block (inner: dq accumulates over it)
     nj = pl.num_programs(2)
+    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
+                q_offset=q_offset)
+    step = j
+    if window is not None:
+        geom["window"] = window
+        j = j + _kv_window(i, block_q=block_q, block_k=block_k,
+                           q_offset=q_offset, window=window)[0]
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_block_live(i, j, causal=causal, block_q=block_q,
-                         block_k=block_k, q_offset=q_offset))
+    @pl.when(_block_live(i, j, **geom))
     def _body():
         _, ds = _recompute_p_ds(
             q_ref[0], k_ref[0], v_ref[0], do_ref[0],
             lse_ref[0, 0], delta_ref[0, 0],
-            i=i, j=j, causal=causal, block_q=block_q, block_k=block_k,
-            scale=scale, q_offset=q_offset,
+            i=i, j=j, scale=scale, **geom,
         )
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == nj - 1)
+    @pl.when(step == nj - 1)
     def _finish():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
@@ -491,32 +619,44 @@ def _bwd_dkv_kernel(
     dk_ref, dv_ref,                                   # (1, BK, D) each
     dk_acc, dv_acc,                                   # VMEM (BK, D) f32
     *, causal: bool, block_q: int, block_k: int, scale: float,
-    q_offset: int, group: int,
+    q_offset: int, group: int, window: int | None = None, n_q: int = 0,
 ):
-    """Grid (B*Hkv, kv blocks, q blocks * group): the inner index walks
+    """Grid (B*Hkv, kv blocks, q steps * group): the inner index walks
     every (q block, group-member q head) pair feeding this KV HEAD's
     block, so GQA's shared kv gradients accumulate in one scratch pass —
-    no repeated-kv tensor, no cross-iteration output hazard."""
+    no repeated-kv tensor, no cross-iteration output hazard.  Without a
+    window a q step is a q block; under one, the kv block's step is its
+    live q blocks in order (``_q_span``), and a step past the last one
+    does nothing."""
     j = pl.program_id(1)   # kv block (outer)
     t = pl.program_id(2)   # inner: q block index * group + group member
     nt = pl.num_programs(2)
     i = t // group         # q block (the causal predicate needs it)
+    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
+                q_offset=q_offset)
+    if window is not None:
+        geom["window"] = window
+        first, last = _q_span(j, block_q=block_q, block_k=block_k,
+                              q_offset=q_offset, window=window, n_q=n_q)
+        i = i + first
 
     @pl.when(t == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_block_live(i, j, causal=causal, block_q=block_q,
-                         block_k=block_k, q_offset=q_offset))
+    live = _block_live(i, j, **geom)
+    if window is not None:
+        live = live & (i <= last)  # a step past the column's last q block
+
+    @pl.when(live)
     def _body():
         q = q_ref[0]
         do = do_ref[0]
         p, ds = _recompute_p_ds(
             q, k_ref[0], v_ref[0], do,
             lse_ref[0, 0], delta_ref[0, 0],
-            i=i, j=j, causal=causal, block_q=block_q, block_k=block_k,
-            scale=scale, q_offset=q_offset,
+            i=i, j=j, scale=scale, **geom,
         )
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -533,7 +673,7 @@ def _bwd_dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(causal, interpret, scale, res, do):
+def _bwd(causal, interpret, scale, window, res, do):
     """Blockwise flash backward: two Pallas kernels, O(S) peak memory.
 
     Probability tiles are recomputed per (q block, kv block) pair from the
@@ -585,10 +725,31 @@ def _bwd(causal, interpret, scale, res, do):
         causal=causal, block_q=block_q, block_k=block_k, scale=scale,
         q_offset=q_offset,
     )
+    n_q, n_k = Sq // block_q, Skv // block_k
+    kv_steps, q_steps = n_k, n_q
+    if window is not None:
+        # the inner grid axes walk a row's or a column's live tiles alone:
+        # as many steps as the widest has, each naming its own block and,
+        # past the last live one, that one again (no DMA)
+        kw["window"] = window
+        geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
+
+        def kv_blk(x, y):
+            start, _ = _kv_window(x, window=window, **geom)
+            _, live = _kv_span(x, causal=True, n_k=n_k, **geom)
+            return jnp.minimum(start + y, live - 1)
+
+        kv_steps = max(
+            _kv_span(i, causal=True, n_k=n_k, **geom)[1]
+            - _kv_window(i, window=window, **geom)[0] for i in range(n_q)
+        )
+        row_specs[1] = row_specs[2] = pl.BlockSpec(
+            (1, block_k, D), lambda b, x, y: (kv_row(b), kv_blk(x, y), 0)
+        )
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
-        grid=(B * H, Sq // block_q, Skv // block_k),
+        grid=(B * H, n_q, kv_steps),
         in_specs=row_specs,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, x, y: (b, x, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
@@ -603,20 +764,28 @@ def _bwd(causal, interpret, scale, res, do):
     def q_row(b, t):
         return (b // Hkv) * H + (b % Hkv) * group + t % group
 
-    def q_blk(t):
-        return t // group
+    def q_blk(y, t):
+        if window is None:
+            return t // group
+        first, last = _q_span(y, window=window, n_q=n_q, **geom)
+        return jnp.minimum(first + t // group, last)
+
+    if window is not None:
+        kw["n_q"] = n_q
+        spans = [_q_span(j, window=window, n_q=n_q, **geom) for j in range(n_k)]
+        q_steps = max(last - first + 1 for first, last in spans)
 
     kv_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, y, t: (q_row(b, t), q_blk(t), 0)),  # q
+        pl.BlockSpec((1, block_q, D), lambda b, y, t: (q_row(b, t), q_blk(y, t), 0)),  # q
         pl.BlockSpec((1, block_k, D), lambda b, y, t: (b, y, 0)),   # k
         pl.BlockSpec((1, block_k, D), lambda b, y, t: (b, y, 0)),   # v
-        pl.BlockSpec((1, block_q, D), lambda b, y, t: (q_row(b, t), q_blk(t), 0)),  # do
-        pl.BlockSpec((1, 8, block_q), lambda b, y, t: (q_row(b, t), 0, q_blk(t))),  # lse
-        pl.BlockSpec((1, 8, block_q), lambda b, y, t: (q_row(b, t), 0, q_blk(t))),  # delta
+        pl.BlockSpec((1, block_q, D), lambda b, y, t: (q_row(b, t), q_blk(y, t), 0)),  # do
+        pl.BlockSpec((1, 8, block_q), lambda b, y, t: (q_row(b, t), 0, q_blk(y, t))),  # lse
+        pl.BlockSpec((1, 8, block_q), lambda b, y, t: (q_row(b, t), 0, q_blk(y, t))),  # delta
     ]
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, group=group, **kw),
-        grid=(B * Hkv, Skv // block_k, (Sq // block_q) * group),
+        grid=(B * Hkv, n_k, q_steps * group),
         in_specs=kv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, y, t: (b, y, 0)),

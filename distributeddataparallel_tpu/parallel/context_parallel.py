@@ -127,7 +127,9 @@ def _flash_ring_bwd(axis_name, interpret, res, do):
         lse.reshape(B * H, 1, S), (B * H, 8, S)
     )
     # Hop 0: own chunk, causal diagonal.
-    dq, dk, dv = flash_bwd(True, interpret, None, (q, k, v, out, lse8), do)
+    dq, dk, dv = flash_bwd(
+        True, interpret, None, None, (q, k, v, out, lse8), do
+    )
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def hop(carry, s):
@@ -137,7 +139,7 @@ def _flash_ring_bwd(axis_name, interpret, res, do):
         dkc = lax.ppermute(dkc, axis_name, perm)
         dvc = lax.ppermute(dvc, axis_name, perm)
         dq_h, dk_h, dv_h = flash_bwd(
-            False, interpret, None, (q, kc, vc, out, lse8), do
+            False, interpret, None, None, (q, kc, vc, out, lse8), do
         )
         live = (idx - s >= 0).astype(dq.dtype)
         dq = dq + dq_h * live

@@ -410,6 +410,128 @@ def test_fwd_tile_counts_cover_the_grid_and_agree_with_predicates():
             assert c.unmasked + c.masked + c.skipped == n_q * n_k
 
 
+# --- a window on the left (PR 33) ----------------------------------------
+# key j is visible to query i iff j <= i and i - j < window
+
+
+def _visible(Sq, Skv, window):
+    i = (Skv - Sq) + np.arange(Sq)[:, None]
+    j = np.arange(Skv)[None, :]
+    return (j <= i) & (i - j < window)
+
+
+@pytest.mark.parametrize("window", [1, 5, 16, 100])
+def test_window_on_the_xla_path_is_the_mask_written_from_positions(window):
+    from distributeddataparallel_tpu.ops.attention import attention
+
+    q, k, v = _qkv(jax.random.PRNGKey(33))
+    got = attention(q, k, v, impl="xla", window=window)
+    s = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = np.where(_visible(16, 16, window), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bkhd->bqhd", p / p.sum(-1, keepdims=True), v)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    if window >= 16:  # every earlier key: causal, to the bit
+        np.testing.assert_array_equal(got, attention(q, k, v, impl="xla"))
+    with pytest.raises(ValueError, match="causal"):
+        attention(q, k, v, impl="xla", causal=False, window=window)
+
+
+@pytest.mark.parametrize("shape,window", [
+    ((1, 384, 384, 4, 2, 16), 1),
+    ((1, 384, 384, 4, 2, 16), 100),      # inside a tile of 128
+    ((1, 384, 384, 4, 2, 16), 128),      # a tile
+    ((1, 384, 384, 4, 2, 16), 200),      # a tile and a part
+    ((1, 384, 384, 4, 2, 16), 384),      # the sequence: causal
+    ((1, 384, 384, 4, 2, 16), 1000),     # past it: causal
+    ((1, 1024, 1024, 2, 1, 16), 700),    # tiles of 512, fetches of one tile
+    ((1, 2048, 2048, 1, 1, 16), 1024),   # fetches of 512 of 2048: 3 a q block
+    ((1, 256, 768, 2, 2, 16), 300),      # queries at the end of the keys
+], ids=lambda x: str(x).replace(" ", ""))
+def test_flash_kernels_with_a_window_match_the_xla_path(shape, window):
+    """All three kernels through the interpreter, values and gradients,
+    against the XLA path's bias; a window that reaches the first key is
+    causal attention."""
+    from distributeddataparallel_tpu.ops.attention import attention
+
+    B, Sq, Skv, H, Hkv, D = shape
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(33), 3)
+    args = (jax.random.normal(kq, (B, Sq, H, D)),
+            jax.random.normal(kk, (B, Skv, Hkv, D)),
+            jax.random.normal(kv, (B, Skv, Hkv, D)))
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))), argnums=(0, 1, 2)
+        )(*args)
+
+    flash = lambda w: lambda q, k, v: pallas_attention.flash_attention(  # noqa: E731
+        q, k, v, True, True, None, w)
+    got = both(flash(window))
+    with jax.default_matmul_precision("highest"):
+        want = both(lambda q, k, v: attention(
+            q, k, v, impl="xla", window=window))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            a, b, atol=2e-5 * max(1.0, float(jnp.abs(b).max())))
+    if window >= Skv:
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(both(flash(None)))):
+            np.testing.assert_allclose(a, b, atol=2e-6)
+    with pytest.raises(ValueError, match="causal"):
+        pallas_attention.flash_attention(*args, False, True, None, window)
+
+
+def test_fwd_tile_counts_with_a_window():
+    """The trinity cell's sliding layers: S 8192, tiles of 512, window
+    2048.  A q block from the fifth on has 5 live tiles of 16 — one the
+    window's edge crosses, three whole, the diagonal's — and the row's
+    three fetches of 1024 keys cover them."""
+    pa = pallas_attention
+    plan = pa._fwd_plan(8192, 8192, 128, 2, 2048)
+    assert plan == (512, 512, 1024)
+    assert pa._fwd_plan(8192, 8192, 128, 2) == (512, 512, 4096)
+    geom = dict(block_q=512, block_k=512, q_offset=0)
+    for i in range(16):
+        start, lo = pa._kv_window(i, window=2048, **geom)
+        full, live = pa._kv_span(i, causal=True, n_k=16, **geom)
+        assert (start, lo, full, live) == (max(i - 4, 0), max(i - 3, 0), i, i + 1)
+    assert pa.fwd_tile_counts(8192, 8192, True, 0, plan, 2048) == (
+        6 + 12 * 3, 4 + 12 * 2, 256 - 70, 16 * 3)
+    assert pa.fwd_tile_counts(8192, 8192, True, 0, pa._fwd_plan(
+        8192, 8192, 128, 2)) == (120, 16, 120, 32)
+    # closed forms against the predicates, tile by tile, by row and column
+    for Sq, Skv, window in [(384, 384, 1), (384, 384, 100), (384, 384, 128),
+                            (384, 384, 200), (1024, 1024, 700), (256, 768, 300),
+                            (2048, 2048, 512), (1024, 1024, 5000)]:
+        plan = pa._fwd_plan(Sq, Skv, 64, 2, window)
+        geom = dict(block_q=plan.block_q, block_k=plan.block_k,
+                    q_offset=Skv - Sq)
+        n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
+        live = np.array([[bool(pa._block_live(
+            i, j, causal=True, window=window, **geom)) for j in range(n_k)]
+            for i in range(n_q)])
+        free = np.array([[bool(pa._block_unmasked(
+            i, j, causal=True, window=window, **geom)) for j in range(n_k)]
+            for i in range(n_q)])
+        seen = _visible(Sq, Skv, window)
+        for i in range(n_q):
+            start, lo = pa._kv_window(i, window=window, **geom)
+            full, end = pa._kv_span(i, causal=True, n_k=n_k, **geom)
+            assert list(live[i]) == [start <= j < end for j in range(n_k)]
+            assert list(free[i]) == [lo <= j < full for j in range(n_k)]
+            for j in range(n_k):
+                tile = seen[i * plan.block_q:(i + 1) * plan.block_q,
+                            j * plan.block_k:(j + 1) * plan.block_k]
+                assert live[i, j] == tile.any() and free[i, j] == tile.all()
+        for j in range(n_k):
+            first, last = pa._q_span(j, window=window, n_q=n_q, **geom)
+            assert [i for i in range(n_q) if live[i, j]] == list(
+                range(first, last + 1)) or not live[:, j].any()
+        c = pa.fwd_tile_counts(Sq, Skv, True, Skv - Sq, plan, window)
+        assert (c.unmasked, c.unmasked + c.masked) == (free.sum(), live.sum())
+        assert c.unmasked + c.masked + c.skipped == n_q * n_k
+
+
 # --- the forward kernel through the chip's own compiler (no chip) --------
 # Interpret mode cannot see what Mosaic refuses (a misaligned slice, too
 # much VMEM, a layout it cannot make); an AOT compile for a described v5e
@@ -549,6 +671,34 @@ def test_conv_kernels_compile_for_v5e(
     assert "conv_fwd" in text and "conv_bwd" in text
 
 
+@pytest.mark.parametrize("m,k,n", [
+    (16384 + 4096, 2048, 1024),   # the trinity cell's usual buffer: up, gate
+    (16384 + 4096, 1024, 2048),   # and down
+    (65536 + 4096, 2048, 1024),   # its worst case
+])
+def test_grouped_kernels_compile_for_v5e(v5e_chip, no_compile_cache, m, k, n):
+    """The expert layer's grouped kernels (``ops/grouped_matmul.py``,
+    PR 33) at the trinity cell's widths, 16 experts, bf16: ``moe_gmm`` plain
+    and transposed and ``moe_tgmm``, kept in this file because only the
+    process that described the topology may compile for it."""
+    from distributeddataparallel_tpu.ops import grouped_matmul as gm
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e_chip)
+    args = (sds((m, k), jnp.bfloat16), sds((16, k, n), jnp.bfloat16),
+            sds((m, n), jnp.bfloat16), sds((m // gm.ROW_TILE,), jnp.int32),
+            sds((), jnp.int32))
+
+    def both(rows, w, dy, tile_group, live):
+        out, vjp = jax.vjp(
+            lambda r, w: gm._gmm(r, w, tile_group, live, False), rows, w)
+        return out, vjp(dy)
+
+    text = jax.jit(both).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "moe_gmm" in text and "moe_tgmm" in text
+
+
 def _instructions(text):
     """``{name: (op, [operand names], op_name)}`` of a compiled module's
     text."""
@@ -594,6 +744,9 @@ def test_the_granite_step_holds_the_conv_kernels_and_no_copy_round_them(
              "traffic": cell["traffic"], "hbm": 16e9}, topo,
         )
     ins = _instructions(seen["text"])
+    # PR 33 put a window through the three flash kernels: with none given the
+    # step is the parent's, instruction for instruction (24,849 of them)
+    assert sum(" = " in line for line in seen["text"].splitlines()) == 24_849
 
     def calls(kernel):
         return [n for n, (op, _, scope) in ins.items()
@@ -615,3 +768,73 @@ def test_the_granite_step_holds_the_conv_kernels_and_no_copy_round_them(
     for call in scans:
         x = source(ins[call][1][1])  # operands: D, x, ...
         assert x in conv_fwd, (call, x, ins[x])
+
+
+def _step_text(v5e_chip, cell, fit, **overrides):
+    """The optimized HLO of ``cell``'s train step at its published widths,
+    compiled for the described chip, with ``overrides`` of depth."""
+    import types
+
+    config = dict(cell["config"], overrides=dict(
+        cell["config"]["overrides"], **overrides))
+    topo = types.SimpleNamespace(devices=sorted(
+        v5e_chip.device_set, key=lambda d: d.id))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return fit({"cell": cell, "config": config,
+                    "traffic": cell["traffic"], "hbm": 16e9}, topo)
+
+
+def test_a_gpt2_step_has_not_noticed_the_window(v5e_chip, no_compile_cache):
+    """Two layers of cell 1's step at its widths: 4,863 instructions and 6
+    custom calls, as the parent of PR 33 compiles it (the whole 24-layer step
+    was compared text against text when the window went in: equal but for the
+    source lines in the kernels' bodies)."""
+    from benchmarks import aot_fit, harness
+
+    seen = {}
+    with mock.patch.object(
+            aot_fit, "describe",
+            lambda name, compiled, hbm: seen.update(text=compiled.as_text())):
+        _step_text(v5e_chip, harness.load_cell("gpt2-medium.train-b8x1024"),
+                   aot_fit.fit_train, num_layers=2)
+    assert sum(" = " in line for line in seen["text"].splitlines()) == 4_863
+    assert seen["text"].count("tpu_custom_call") == 6
+
+
+def test_the_trinity_step_holds_its_kernels_and_mosaic_takes_them(
+    v5e_chip, no_compile_cache
+):
+    """The trinity cell's step at its published widths and its own length,
+    cut to two layers (the dense sliding layer and a full layer with
+    experts; ``benchmarks/aot_fit_moe.py`` compiles all five, ~3 min): the
+    chip's own compiler takes the windowed flash kernels and the grouped
+    kernels, each under the scope its reader looks for.  A layer launches
+    ``flash_fwd`` twice (remat) and the two backward kernels once.  The
+    expert layer's usual buffer launches ``moe_gmm`` 6 times forward (3
+    products, remat) and 3 backward, and ``moe_tgmm`` 3 times; its
+    worst-case buffer, which keeps nothing for its backward but its
+    arguments, 3 more forward."""
+    from benchmarks import aot_fit_moe, harness, moe_scopes
+
+    text = _step_text(
+        v5e_chip, harness.load_cell("trinity-mini.train-s8192"),
+        aot_fit_moe.fit, num_layers=2,
+        layer_types=["sliding_attention", "full_attention"],
+    ).as_text()
+    ins = _instructions(text)
+
+    def calls(kernel):
+        return [scope for op, _, scope in ins.values()
+                if op == "custom-call" and f"/{kernel}/pallas_call" in scope]
+
+    assert text.count("tpu_custom_call") == 2 * 4 + 12 + 15
+    assert len(calls("flash_fwd")) == 4
+    assert len(calls("flash_bwd_dq")) == len(calls("flash_bwd_dkv")) == 2
+    gmm, tgmm = calls("moe_gmm"), calls("moe_tgmm")
+    assert (len(gmm), len(tgmm)) == (9 + 12, 3 + 3)
+    for scope in gmm + tgmm:
+        assert moe_scopes.part_of(scope) == "moe_experts", scope
+    # remat's second forward runs in the backward pass, beside d rows
+    assert sum("transpose(" in s for s in gmm) == 6 + 9
+    assert all("transpose(" in s for s in tgmm)
+    assert "ragged-dot" not in text

@@ -249,6 +249,84 @@ def test_mixer_kernels_carry_their_parts_scope_in_both_phases(
     assert found[bwd, "bwd"] and not found[bwd, "fwd"], found
 
 
+def _tiny_afmoe_step_text():
+    """The compiled train step of a three-layer afmoe stack (a dense
+    sliding layer, a sliding and a full layer with experts, a share of 4
+    of 8 held) under remat, as HLO text."""
+    from distributeddataparallel_tpu.models.transformer import trinity_mini
+
+    cfg = trinity_mini(
+        vocab_size=128, num_layers=3, num_dense_layers=1,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "full_attention"),
+        d_model=32, num_heads=2, num_kv_heads=1, head_dim=16, d_ff=64,
+        moe_d_ff=32, max_seq_len=16, sliding_window=4, moe_experts=8,
+        moe_top_k=2, moe_experts_held=(0, 4), attn_impl="xla", remat=True,
+    )
+    model = TransformerLM(cfg)
+    mesh = ddp.make_mesh(("data",), devices=jax.devices()[:1])
+
+    def loss_fn(params, batch, rng):
+        logits = model.apply({"params": params}, batch["tokens"][:, :-1])
+        return lm_cross_entropy(logits, batch["tokens"][:, 1:]), {}
+
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    state = ddp.broadcast_params(
+        ddp.TrainState.create(
+            apply_fn=model.apply, params=params, tx=optax.adamw(1e-3)
+        ),
+        mesh,
+    )
+    batch = shard_batch({"tokens": jnp.zeros((2, 17), jnp.int32)}, mesh)
+    return ddp.make_train_step(loss_fn, mesh=mesh).lower(
+        state, batch, jax.random.PRNGKey(0)
+    ).compile().as_text()
+
+
+def test_moe_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
+    """The expert FFN's five scopes (PR 33) are a tuple of their own, read
+    by ``benchmarks/moe_scopes.py``'s table and not by
+    ``scope_reduce.BUCKETS``, which counts all of it under ``mlp``; a
+    compiled afmoe step carries each of them, forward and backward, and
+    the dense layer's FFN none."""
+    from benchmarks import moe_scopes
+
+    assert scopes.MOE_SCOPES == (
+        "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+        "moe_shared")
+    assert not set(scopes.MOE_SCOPES) & set(
+        scopes.STEP_SCOPES + scopes.MIXER_SCOPES)
+    # the grouped kernels' names; ``tests/test_attention.py`` finds them in
+    # the cell's compiled step, under ``moe_experts`` in both phases
+    assert scopes.MOE_KERNEL_NAMES == ("moe_gmm", "moe_tgmm")
+    assert [name for name, _ in moe_scopes.PARTS] == list(scopes.MOE_SCOPES)
+    for name in scopes.MOE_SCOPES:
+        for path in (f"jit(step)/jvp(M)/layer_1/mlp/{name}/add",
+                     f"jit(s)/transpose(jvp(M))/layer_3/mlp/{name}/cond/mul"):
+            hits = [part for part, rx in moe_scopes.PARTS
+                    if re.search(rx, path)]
+            assert hits == [name], (path, hits)
+            assert moe_scopes.part_of(path) == name
+            assert scope_reduce.bucket_of(path) == "mlp"
+    assert moe_scopes.part_of("jit(s)/jvp(M)/layer_0/mlp/up_proj/dot") is None
+    assert moe_scopes.part_of("jit(s)/jvp(M)/layer_1/mlp_norm/mul") is None
+
+    found = collections.Counter()
+    dense = 0
+    for scope in _OP_NAME.findall(_tiny_afmoe_step_text()):
+        part = moe_scopes.part_of(scope)
+        if part is not None:
+            assert "/layer_0/" not in scope, scope
+            found[part, scope_reduce.phase_of(scope, "")] += 1
+        elif "/layer_0/mlp/" in scope:
+            dense += 1
+    assert dense
+    for name in scopes.MOE_SCOPES:
+        assert found[name, "fwd"] and found[name, "bwd"], (name, found)
+
+
 def test_three_pallas_calls_have_three_names():
     from distributeddataparallel_tpu.ops.pallas_attention import (
         flash_attention,
