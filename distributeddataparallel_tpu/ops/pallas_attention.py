@@ -21,9 +21,18 @@ the LM configs (BASELINE 4-5), written against the Pallas TPU guide
   is never materialized — O(S) memory instead of O(S²).  In the forward
   the row statistics stay (rows, 128) lane-replicated from the reduction
   to the store (a 1-D row vector costs a relayout a tile).
-- Backward kernels: grid (batch*heads, q_blocks, kv_blocks); causal
-  blocks strictly above the diagonal are skipped with ``pl.when``
-  (predicated off — no MXU work; their K/V blocks are still fetched).
+- Backward kernels (``_bwd_plan``): where a (batch, head) row's operands
+  fit the forward's fetch budget (all of S at the training shapes) one
+  grid step owns the row and walks its live tiles alone, unrolled where
+  they are few, so that no step is dead and the compiler may overlap one
+  tile's vector work with the next one's products; else the grid walks a
+  row's or a column's live tiles and a step past the last one names the
+  block already in VMEM.  The mask runs only on the tiles the diagonal or
+  the window's edge crosses, a power-of-two scale is folded into an
+  operand, and the dk/dv kernel computes its tiles transposed, (BK, BQ):
+  lse and delta are then rows, the (8, Sq) layout they arrive in, and
+  both accumulations are plain products.  ``bwd_tile_counts`` says how
+  often each path engages.
 - Backward: ``custom_vjp`` saving (q, k, v, out, lse); gradients use the
   standard flash-attention identities with the saved log-sum-exp,
   recomputing probability tiles BLOCKWISE in two Pallas kernels (the
@@ -250,16 +259,16 @@ def fwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
 
 
 def _causal_mask_scores(s, i, j, *, block_q: int, block_k: int, q_offset: int,
-                        window: int | None = None):
-    """Mask the (BQ, BK) score tile above the diagonal, and left of the
-    ``window``, with NEG_INF — the single in-kernel statement of the
-    position convention (one copy, so forward and backward can never
-    drift)."""
+                        window: int | None = None, transposed: bool = False):
+    """Mask the (BQ, BK) score tile — (BK, BQ) if ``transposed`` — above
+    the diagonal, and left of the ``window``, with NEG_INF — the single
+    in-kernel statement of the position convention (one copy, so forward
+    and backward can never drift)."""
     q_pos = q_offset + i * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
+        jnp.int32, s.shape, int(transposed)
     )
     k_pos = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
+        jnp.int32, s.shape, 1 - int(transposed)
     )
     seen = k_pos <= q_pos
     if window is not None:
@@ -540,11 +549,122 @@ def _fwd(q, k, v, causal, interpret, scale, window):
     return out, (q, k, v, out, lse)
 
 
-def _recompute_p_ds(
-    q, k, v, do, lse, delta, *,
-    i, j, causal, block_q, block_k, scale, q_offset, window=None,
-):
-    """Shared blockwise backward math for one (q block i, kv block j) tile.
+class BwdPlan(NamedTuple):
+    """The backward kernels' tiles and fetches, from shapes alone
+    (``_bwd_plan``)."""
+
+    block_q: int
+    block_k: int
+    dq_whole: bool   # dq: a (batch*head) row arrives whole, one grid step
+    dkv_whole: bool  # dk/dv: a kv head and its group's rows likewise
+    unrolled: bool   # a whole row's walk is unrolled at trace time
+
+
+class BwdTileCounts(NamedTuple):
+    """``bwd_tile_counts``: the dq kernel's tiles and steps per
+    (batch*head) row, the dk/dv kernel's per (batch*kv head) row (every
+    member of the group counted)."""
+
+    dq: TileCounts
+    dkv: TileCounts
+
+
+#: a whole row's walk is unrolled up to this many tiles (group members
+#: counted): straight-line code lets the compiler overlap one tile's
+#: vector work with the next one's products; past it, loops
+_UNROLL_TILES = 16
+
+
+def _bwd_plan(Sq: int, Skv: int, D: int, itemsize: int, group: int = 1,
+              window: int | None = None) -> BwdPlan:
+    """The backward's plan, chosen from what the operands show.
+
+    Score tiles are ``_pick_block`` squares.  Without a window, a kernel's
+    operands arrive a whole row at a time where each fits
+    ``_KV_FETCH_BYTES`` (the forward's budget: all of S at the GPT-2
+    shape): one grid step a row, no step that does nothing, and the live
+    tiles walked inside — unrolled where they are few.  Else, and under a
+    window, the grid walks a row's or a column's live tiles and a step
+    past the last one names the block already in VMEM (no DMA).
+    """
+    block_q, block_k = _pick_block(Sq), _pick_block(Skv)
+    fits = lambda rows: rows * D * itemsize <= _KV_FETCH_BYTES  # noqa: E731
+    whole = window is None and fits(Skv) and fits(Sq)
+    tiles = (Sq // block_q) * (Skv // block_k) * group
+    return BwdPlan(block_q, block_k, whole, whole and fits(group * Sq),
+                   whole and tiles <= _UNROLL_TILES)
+
+
+def _q_walk(j, *, causal: bool, block_q: int, block_k: int, q_offset: int,
+            window: int | None, n_q: int):
+    """``(first, free, end)`` for kv block ``j``: q blocks ``[first, end)``
+    are ``_block_live``; without a window those from ``free`` on are
+    ``_block_unmasked`` and the ones before it crossed by the diagonal
+    (under a window ``free`` is not used: the edge crosses tiles further
+    down, and the grid tests each).  ``end <= first``: a column no query
+    sees.  ``_kv_span`` read by column; ``j`` static or traced."""
+    if not causal:
+        return 0, 0, n_q
+    first, last = _q_span(j, block_q=block_q, block_k=block_k,
+                          q_offset=q_offset, window=window, n_q=n_q)
+    top, least = (max, min) if isinstance(j, int) else (jnp.maximum, jnp.minimum)
+    end = last + 1
+    if window is not None:
+        # ``_q_span`` clips at q block 0: a column wholly left of the
+        # first query's window has no live tile, not that one
+        seen = (j + 1) * block_k + window - 2 - q_offset >= 0
+        end = (end if seen else first) if isinstance(j, int) else jnp.where(
+            seen, end, first)
+    # the first q block whose first query sees the column's last key
+    free = top((j + 1) * block_k - 1 - q_offset + block_q - 1, 0) // block_q
+    return first, least(free, n_q), end
+
+
+def _bwd_steps(Sq: int, Skv: int, causal: bool, q_offset: int, plan: BwdPlan,
+               window: int | None) -> tuple[int, int]:
+    """Inner grid extents ``(kv_steps, q_steps)`` of the two kernels where
+    the grid walks: as many steps as the widest row or column has live
+    tiles."""
+    n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
+    geom = dict(block_q=plan.block_q, block_k=plan.block_k, q_offset=q_offset)
+    kv_steps = max(
+        _kv_span(i, causal=causal, n_k=n_k, **geom)[1]
+        - _kv_window(i, window=window, **geom)[0] for i in range(n_q)
+    )
+    walks = [_q_walk(j, causal=causal, window=window, n_q=n_q, **geom)
+             for j in range(n_k)]
+    return kv_steps, max(1, max(end - first for first, _, end in walks))
+
+
+def bwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
+                    plan: BwdPlan, window: int | None = None,
+                    group: int = 1) -> BwdTileCounts:
+    """How often each path of the two backward kernels engages — static,
+    like ``fwd_tile_counts``: tiles run without a mask / with one / never
+    entered, and the grid steps launched."""
+    n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
+    # the tiles are the forward's at the same squares, whatever it fetches
+    tiles = fwd_tile_counts(
+        Sq, Skv, causal, q_offset,
+        FwdPlan(plan.block_q, plan.block_k, plan.block_k), window)[:3]
+    kv_steps, q_steps = _bwd_steps(Sq, Skv, causal, q_offset, plan, window)
+    return BwdTileCounts(
+        TileCounts(*tiles, 1 if plan.dq_whole else n_q * kv_steps),
+        TileCounts(*(group * t for t in tiles),
+                   1 if plan.dkv_whole else n_k * q_steps * group),
+    )
+
+
+def _nt(a, b):
+    """``a @ b.T`` on the MXU, f32 out."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _recompute_p_ds(rows, cols, d_rows, d_cols, lse, delta, *, scale, mask):
+    """Shared blockwise backward math for one score tile, in either
+    orientation.
 
     Recomputes the probability tile from the saved log-sum-exp and applies
     the flash-attention identities:
@@ -552,125 +672,196 @@ def _recompute_p_ds(
         p  = exp(s - lse)               (exact softmax row, no renorm pass)
         dp = do vᵀ
         ds = p * (dp - delta)           delta = rowsum(do * out), saved
+
+    The dq kernel passes ``(q, k, do, v)`` and the statistics as (BQ, 1)
+    columns: tiles are (BQ, BK).  The dk/dv kernel passes ``(k, q, v, do)``
+    and the statistics as (1, BQ) rows: tiles are (BK, BQ).  ``scale`` is
+    None where the caller folded it into an operand; ``mask`` masks the
+    tile or is None.
     """
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # (BQ, BK)
-    if causal:
-        s = _causal_mask_scores(
-            s, i, j, block_q=block_q, block_k=block_k, q_offset=q_offset,
-            **({} if window is None else {"window": window}),
-        )
-    p = jnp.exp(s - lse[:, None])  # masked entries: exp(NEG_INF - lse) = 0
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (BQ, BK)
-    ds = p * (dp - delta[:, None])
+    s = _nt(rows, cols)
+    if scale is not None:
+        s = s * scale
+    if mask is not None:
+        s = mask(s)
+    p = jnp.exp(s - lse)  # masked entries: exp(NEG_INF - lse) = 0
+    ds = p * (_nt(d_rows, d_cols) - delta)
     return p, ds
 
 
+def _fold(x, scale: float):
+    """``(x * scale, None)`` where the multiply is bit-exact on the
+    operand (a power of two: 2^-3 at D = 64), else ``(x, scale)``: one
+    (rows, D) multiply a tile, not one (BQ, BK)."""
+    if math.frexp(scale)[0] == 0.5:
+        return (x.astype(jnp.float32) * scale).astype(x.dtype), None
+    return x, scale
+
+
+def _tile_rows(x, block: int):
+    """Rows ``[x * block, (x + 1) * block)``; ``x`` static or traced."""
+    if isinstance(x, int):
+        return pl.ds(x * block, block)
+    return pl.ds(pl.multiple_of(x * block, block), block)
+
+
+def _walk(lo, hi, body, unrolled: bool):
+    """``body(x)`` for ``x`` in ``[lo, hi)``, ascending: at trace time
+    (the bounds are then Python ints) or as a loop."""
+    if unrolled:
+        for x in range(lo, hi):
+            body(x)
+    else:
+        jax.lax.fori_loop(lo, hi, lambda x, _: body(x), None)
+
+
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,  # inputs
-    dq_ref,                                           # (1, BQ, D)
-    dq_acc,                                           # VMEM (BQ, D) f32
+    q_ref, k_ref, v_ref, do_ref,  # (1, BQ | Sq, D), (1, BK | Skv, D) x2, as q
+    lse_ref, delta_ref,           # (1, 8, BQ | Sq)
+    dq_ref,                       # as q
+    dq_acc,                       # VMEM (BQ, D) f32
     *, causal: bool, block_q: int, block_k: int, scale: float, q_offset: int,
-    window: int | None = None,
+    n_q: int, n_k: int, whole: bool, unrolled: bool, window: int | None = None,
 ):
-    """Grid (B*H, q blocks, kv steps).  Without a window a step is a kv
-    block; under one, the q block's step ``y`` is its ``y``-th live kv
-    block, counted from the window's first (``_kv_window``), and a step
+    """Every q block of a (batch*head) row against its live kv tiles, kv
+    ascending.  ``whole``: grid (B*H,), the row's operands in VMEM, and
+    per q block two walks — the tiles below the diagonal without a mask,
+    then the ones it crosses; no step is dead.  Else grid (B*H, q blocks,
+    kv steps): step ``y`` is the q block's ``y``-th live tile counted from
+    the window's first (``_kv_window``; tile 0 without one), and a step
     past the diagonal's does nothing."""
-    i = pl.program_id(1)  # q block (outer)
-    j = pl.program_id(2)  # kv block (inner: dq accumulates over it)
-    nj = pl.num_programs(2)
-    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
-                q_offset=q_offset)
-    step = j
-    if window is not None:
-        geom["window"] = window
-        j = j + _kv_window(i, block_q=block_q, block_k=block_k,
-                           q_offset=q_offset, window=window)[0]
+    geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
+    windowed = dict(geom, window=window)
 
-    @pl.when(step == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    @pl.when(_block_live(i, j, **geom))
-    def _body():
+    def tile(i, j, qs, ks, *, masked: bool):
+        q, fold = _fold(q_ref[0, qs, :], scale)
+        k = k_ref[0, ks, :]
         _, ds = _recompute_p_ds(
-            q_ref[0], k_ref[0], v_ref[0], do_ref[0],
-            lse_ref[0, 0], delta_ref[0, 0],
-            i=i, j=j, scale=scale, **geom,
+            q, k, do_ref[0, qs, :], v_ref[0, ks, :],
+            lse_ref[0, 0, qs][:, None], delta_ref[0, 0, qs][:, None],
+            scale=fold,
+            mask=functools.partial(_causal_mask_scores, i=i, j=j, **windowed)
+            if masked else None,
         )
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+        dq_acc[...] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(step == nj - 1)
-    def _finish():
-        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+    def _open():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def _finish(qs):
+        dq_ref[0, qs, :] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    def row(i):
+        qs = _tile_rows(i, block_q)
+        full, live = _kv_span(i, causal=causal, n_k=n_k, **geom)
+        _open()
+        _walk(0, full, lambda j: tile(
+            i, j, qs, _tile_rows(j, block_k), masked=False), unrolled)
+        if causal:
+            _walk(full, live, lambda j: tile(
+                i, j, qs, _tile_rows(j, block_k), masked=True), unrolled)
+        _finish(qs)
+
+    if whole:
+        _walk(0, n_q, row, unrolled)
+        return
+    i, y = pl.program_id(1), pl.program_id(2)
+    every = slice(None)
+    pl.when(y == 0)(_open)
+    if causal:
+        full, live = _kv_span(i, causal=True, n_k=n_k, **geom)
+        start, lo = _kv_window(i, window=window, **geom)
+        j = start + y
+        free = (lo <= j) & (j < full)
+        pl.when(free)(lambda: tile(i, j, every, every, masked=False))
+        pl.when((j < live) & ~free)(lambda: tile(i, j, every, every, masked=True))
+    else:
+        tile(i, y, every, every, masked=False)
+    pl.when(y == pl.num_programs(2) - 1)(lambda: _finish(every))
 
 
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,  # inputs
-    dk_ref, dv_ref,                                   # (1, BK, D) each
-    dk_acc, dv_acc,                                   # VMEM (BK, D) f32
-    *, causal: bool, block_q: int, block_k: int, scale: float,
-    q_offset: int, group: int, window: int | None = None, n_q: int = 0,
+    q_ref, k_ref, v_ref, do_ref,  # (1 | group, BQ | Sq, D), (1, BK | Skv, D) x2, as q
+    lse_ref, delta_ref,           # (1 | group, 8, BQ | Sq)
+    dk_ref, dv_ref,               # as k
+    dk_acc, dv_acc,               # VMEM (BK, D) f32
+    *, causal: bool, block_q: int, block_k: int, scale: float, q_offset: int,
+    group: int, n_q: int, n_k: int, whole: bool, unrolled: bool,
+    window: int | None = None,
 ):
-    """Grid (B*Hkv, kv blocks, q steps * group): the inner index walks
-    every (q block, group-member q head) pair feeding this KV HEAD's
-    block, so GQA's shared kv gradients accumulate in one scratch pass —
-    no repeated-kv tensor, no cross-iteration output hazard.  Without a
-    window a q step is a q block; under one, the kv block's step is its
-    live q blocks in order (``_q_span``), and a step past the last one
-    does nothing."""
-    j = pl.program_id(1)   # kv block (outer)
-    t = pl.program_id(2)   # inner: q block index * group + group member
-    nt = pl.num_programs(2)
-    i = t // group         # q block (the causal predicate needs it)
-    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
-                q_offset=q_offset)
-    if window is not None:
-        geom["window"] = window
-        first, last = _q_span(j, block_q=block_q, block_k=block_k,
-                              q_offset=q_offset, window=window, n_q=n_q)
-        i = i + first
+    """Every kv block of one KV HEAD against its live (q block, group
+    member) pairs — q block ascending, member inside — so GQA's shared kv
+    gradients accumulate in one scratch pass.  The tile is computed
+    TRANSPOSED, (BK, BQ): lse and delta are then wanted as rows broadcast
+    down the sublanes, which is the (8, BQ) layout they arrive in, and
+    ``dv += pᵀ do``, ``dk += dsᵀ q`` are plain products.  ``whole``: grid
+    (B*Hkv,), K / V and the group's q / do / lse / delta in VMEM, and per
+    kv block two walks — the q blocks the diagonal crosses, then the ones
+    below it; no step is dead.  Else grid (B*Hkv, kv blocks, q steps *
+    group): step ``t`` is the column's ``t``-th live pair counted from its
+    first q block (``_q_span``), and a step past the last does nothing."""
+    geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
+    windowed = dict(geom, window=window)
 
-    @pl.when(t == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    live = _block_live(i, j, **geom)
-    if window is not None:
-        live = live & (i <= last)  # a step past the column's last q block
-
-    @pl.when(live)
-    def _body():
-        q = q_ref[0]
-        do = do_ref[0]
+    def tile(i, j, g, qs, ks, *, masked: bool):
+        k, fold = _fold(k_ref[0, ks, :], scale)
+        q, do = q_ref[g, qs, :], do_ref[g, qs, :]
         p, ds = _recompute_p_ds(
-            q, k_ref[0], v_ref[0], do,
-            lse_ref[0, 0], delta_ref[0, 0],
-            i=i, j=j, scale=scale, **geom,
+            k, q, v_ref[0, ks, :], do,
+            lse_ref[g, :1, qs], delta_ref[g, :1, qs],
+            scale=fold,
+            mask=functools.partial(_causal_mask_scores, i=i, j=j,
+                                   transposed=True, **windowed)
+            if masked else None,
         )
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dk_acc[...] += jnp.dot(ds.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
 
-    @pl.when(t == nt - 1)
-    def _finish():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    def _open():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def _finish(ks):
+        dk_ref[0, ks, :] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, ks, :] = dv_acc[...].astype(dv_ref.dtype)
+
+    def column(j):
+        ks = _tile_rows(j, block_k)
+        first, free, end = _q_walk(j, causal=causal, window=None, n_q=n_q, **geom)
+
+        def pair(t, *, masked: bool):
+            i, g = (t // group, t % group) if group > 1 else (t, 0)
+            tile(i, j, g, _tile_rows(i, block_q), ks, masked=masked)
+
+        _open()
+        _walk(first * group, free * group,
+              lambda t: pair(t, masked=True), unrolled)
+        _walk(free * group, end * group,
+              lambda t: pair(t, masked=False), unrolled)
+        _finish(ks)
+
+    if whole:
+        _walk(0, n_k, column, unrolled)
+        return
+    j, t = pl.program_id(1), pl.program_id(2)
+    every = slice(None)
+    pl.when(t == 0)(_open)
+    if causal:
+        first, _, end = _q_walk(j, causal=True, window=window, n_q=n_q, **geom)
+        i = first + t // group
+        free = _block_unmasked(i, j, causal=True, **windowed)
+        pl.when((i < end) & free)(
+            lambda: tile(i, j, 0, every, every, masked=False))
+        pl.when((i < end) & ~free)(
+            lambda: tile(i, j, 0, every, every, masked=True))
+    else:
+        tile(t // group, j, 0, every, every, masked=False)
+    pl.when(t == pl.num_programs(2) - 1)(lambda: _finish(every))
 
 
 def _bwd(causal, interpret, scale, window, res, do):
@@ -686,12 +877,6 @@ def _bwd(causal, interpret, scale, window, res, do):
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     Hkv = k.shape[2]
-    group = H // Hkv
-    block_q = _pick_block(Sq)
-    block_k = _pick_block(Skv)
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    q_offset = Skv - Sq
 
     # (B, S, H, D) -> (B*H, S, D) flat layout, matching the forward; kv
     # stays at its own head count (GQA shares it across the group).
@@ -700,7 +885,6 @@ def _bwd(causal, interpret, scale, window, res, do):
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Skv, D)
     dof = do.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
     outf = out.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
-    kv_row = functools.partial(_gqa_kv_row, H=H, Hkv=Hkv)
 
     delta = jnp.sum(
         dof.astype(jnp.float32) * outf.astype(jnp.float32), axis=-1
@@ -708,92 +892,119 @@ def _bwd(causal, interpret, scale, window, res, do):
     # Row vectors enter the kernels broadcast over 8 sublanes (the TPU
     # (8, 128) tiling minimum).  lse arrives from the forward already in
     # that layout; only delta needs the broadcast.
-    lse8 = lse
     delta8 = jnp.broadcast_to(delta[:, None, :], (B * H, 8, Sq))
+    dq, dk, dv = _bwd_launch(
+        qf, kf, vf, dof, lse, delta8, H=H, Hkv=Hkv, causal=causal,
+        interpret=interpret, scale=scale, window=window,
+    )
+    dq = dq.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    dk = dk.reshape(B, Hkv, Skv, D).transpose(0, 2, 1, 3)
+    dv = dv.reshape(B, Hkv, Skv, D).transpose(0, 2, 1, 3)
+    return dq, dk, dv
 
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("H", "Hkv", "causal", "interpret", "scale", "window"),
+)
+def _bwd_launch(qf, kf, vf, dof, lse8, delta8, *, H: int, Hkv: int,
+                causal: bool, interpret: bool, scale: float | None = None,
+                window: int | None = None):
+    """The backward's two ``pallas_call``s, on flat (rows, S, D) operands
+    and (rows, 8, Sq) statistics; jitted on its own for ``_fwd_launch``'s
+    reason (a model's layers share one trace and lowering of each kernel).
+
+    Neither call carries a ``CostEstimate``, as in the parent: with one,
+    XLA prefetches other operands round the kernels and re-tiles the
+    matmuls that read them (one fusion in the GPT-2 step, eleven in two
+    layers of the trinity step, AOT), which moves the low digits of every
+    cell's gradients; without, the compiled steps are the parent's to the
+    last instruction outside the two custom calls.  ``bwd_tile_counts``
+    is the count it would be fed from.
+    """
     from jax.experimental.pallas import tpu as pltpu
 
-    row_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, x, y: (b, x, 0)),   # q
-        pl.BlockSpec((1, block_k, D), lambda b, x, y: (kv_row(b), y, 0)),  # k
-        pl.BlockSpec((1, block_k, D), lambda b, x, y: (kv_row(b), y, 0)),  # v
-        pl.BlockSpec((1, block_q, D), lambda b, x, y: (b, x, 0)),   # do
-        pl.BlockSpec((1, 8, block_q), lambda b, x, y: (b, 0, x)),   # lse
-        pl.BlockSpec((1, 8, block_q), lambda b, x, y: (b, 0, x)),   # delta
-    ]
-    kw = dict(
-        causal=causal, block_q=block_q, block_k=block_k, scale=scale,
-        q_offset=q_offset,
-    )
+    rows, Sq, D = qf.shape
+    kv_rows, Skv, _ = kf.shape
+    group = H // Hkv
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
+    q_offset = Skv - Sq
+    plan = _bwd_plan(Sq, Skv, D, qf.dtype.itemsize, group, window)
+    block_q, block_k = plan.block_q, plan.block_k
     n_q, n_k = Sq // block_q, Skv // block_k
-    kv_steps, q_steps = n_k, n_q
-    if window is not None:
-        # the inner grid axes walk a row's or a column's live tiles alone:
-        # as many steps as the widest has, each naming its own block and,
-        # past the last live one, that one again (no DMA)
-        kw["window"] = window
-        geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
+    kv_steps, q_steps = _bwd_steps(Sq, Skv, causal, q_offset, plan, window)
+    geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
+    kw = dict(causal=causal, scale=scale, n_q=n_q, n_k=n_k, window=window,
+              unrolled=plan.unrolled, **geom)
+    kv_row = functools.partial(_gqa_kv_row, H=H, Hkv=Hkv)
 
+    if plan.dq_whole:
+        grid = (rows,)
+        q_spec = pl.BlockSpec((1, Sq, D), lambda b: (b, 0, 0))
+        stat_spec = pl.BlockSpec((1, 8, Sq), lambda b: (b, 0, 0))
+        kv_spec = pl.BlockSpec((1, Skv, D), lambda b: (kv_row(b), 0, 0))
+    else:
+        # a q block's steps name its live kv blocks in order and, past the
+        # last one, that one again (no DMA)
         def kv_blk(x, y):
             start, _ = _kv_window(x, window=window, **geom)
-            _, live = _kv_span(x, causal=True, n_k=n_k, **geom)
+            _, live = _kv_span(x, causal=causal, n_k=n_k, **geom)
             return jnp.minimum(start + y, live - 1)
 
-        kv_steps = max(
-            _kv_span(i, causal=True, n_k=n_k, **geom)[1]
-            - _kv_window(i, window=window, **geom)[0] for i in range(n_q)
-        )
-        row_specs[1] = row_specs[2] = pl.BlockSpec(
-            (1, block_k, D), lambda b, x, y: (kv_row(b), kv_blk(x, y), 0)
-        )
-
+        grid = (rows, n_q, kv_steps)
+        q_spec = pl.BlockSpec((1, block_q, D), lambda b, x, y: (b, x, 0))
+        stat_spec = pl.BlockSpec((1, 8, block_q), lambda b, x, y: (b, 0, x))
+        kv_spec = pl.BlockSpec(
+            (1, block_k, D), lambda b, x, y: (kv_row(b), kv_blk(x, y), 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kw),
-        grid=(B * H, n_q, kv_steps),
-        in_specs=row_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, x, y: (b, x, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        functools.partial(_bwd_dq_kernel, whole=plan.dq_whole, **kw),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qf.shape, qf.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
         name=scopes.FLASH_BWD_DQ,
     )(qf, kf, vf, dof, lse8, delta8)
 
-    # dkv grid: one row per KV head; the inner index t walks every
-    # (q block, group member) pair so the group's q heads accumulate into
-    # the shared kv gradient consecutively (no output-revisit hazard).
-    def q_row(b, t):
-        return (b // Hkv) * H + (b % Hkv) * group + t % group
+    # dk/dv: one grid row per KV head; its group's q heads are the
+    # ``group`` flat rows from ``b * group``
+    if plan.dkv_whole:
+        grid = (kv_rows,)
+        q_spec = pl.BlockSpec((group, Sq, D), lambda b: (b, 0, 0))
+        stat_spec = pl.BlockSpec((group, 8, Sq), lambda b: (b, 0, 0))
+        kv_spec = pl.BlockSpec((1, Skv, D), lambda b: (b, 0, 0))
+    else:
+        # a kv block's steps name its live (q block, member) pairs in
+        # order and, past the last pair, that one again (no DMA)
+        def q_at(b, y, t):
+            first, _, end = _q_walk(
+                y, causal=causal, window=window, n_q=n_q, **geom)
+            t = jnp.clip(t, 0, jnp.maximum((end - first) * group - 1, 0))
+            return b * group + t % group, first + t // group
 
-    def q_blk(y, t):
-        if window is None:
-            return t // group
-        first, last = _q_span(y, window=window, n_q=n_q, **geom)
-        return jnp.minimum(first + t // group, last)
+        def q_index(b, y, t):
+            row, blk = q_at(b, y, t)
+            return (row, blk, 0)
 
-    if window is not None:
-        kw["n_q"] = n_q
-        spans = [_q_span(j, window=window, n_q=n_q, **geom) for j in range(n_k)]
-        q_steps = max(last - first + 1 for first, last in spans)
+        def stat_index(b, y, t):
+            row, blk = q_at(b, y, t)
+            return (row, 0, blk)
 
-    kv_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, y, t: (q_row(b, t), q_blk(y, t), 0)),  # q
-        pl.BlockSpec((1, block_k, D), lambda b, y, t: (b, y, 0)),   # k
-        pl.BlockSpec((1, block_k, D), lambda b, y, t: (b, y, 0)),   # v
-        pl.BlockSpec((1, block_q, D), lambda b, y, t: (q_row(b, t), q_blk(y, t), 0)),  # do
-        pl.BlockSpec((1, 8, block_q), lambda b, y, t: (q_row(b, t), 0, q_blk(y, t))),  # lse
-        pl.BlockSpec((1, 8, block_q), lambda b, y, t: (q_row(b, t), 0, q_blk(y, t))),  # delta
-    ]
+        grid = (kv_rows, n_k, q_steps * group)
+        q_spec = pl.BlockSpec((1, block_q, D), q_index)
+        stat_spec = pl.BlockSpec((1, 8, block_q), stat_index)
+        kv_spec = pl.BlockSpec((1, block_k, D), lambda b, y, t: (b, y, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, group=group, **kw),
-        grid=(B * Hkv, n_k, q_steps * group),
-        in_specs=kv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, y, t: (b, y, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, y, t: (b, y, 0)),
-        ],
+        functools.partial(_bwd_dkv_kernel, group=group, whole=plan.dkv_whole,
+                          **kw),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B * Hkv, Skv, D), k.dtype),
-            jax.ShapeDtypeStruct((B * Hkv, Skv, D), v.dtype),
+            jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+            jax.ShapeDtypeStruct(vf.shape, vf.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
@@ -802,10 +1013,6 @@ def _bwd(causal, interpret, scale, window, res, do):
         interpret=interpret,
         name=scopes.FLASH_BWD_DKV,
     )(qf, kf, vf, dof, lse8, delta8)
-
-    dq = dq.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
-    dk = dk.reshape(B, Hkv, Skv, D).transpose(0, 2, 1, 3)
-    dv = dv.reshape(B, Hkv, Skv, D).transpose(0, 2, 1, 3)
     return dq, dk, dv
 
 

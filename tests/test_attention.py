@@ -1,6 +1,7 @@
 """Attention op tests: RoPE properties, causal masking, GQA expansion, and
 the Pallas flash kernel vs the XLA reference (interpret mode on CPU)."""
 
+import contextlib
 from unittest import mock
 
 import jax
@@ -276,6 +277,7 @@ _PLAN_SHAPES = {
     "decode_inside_a_block": (1, 384, 1024, 2, 2, 16),   # q_offset 640
     "decode_one_q_tile": (1, 128, 512, 2, 2, 16),        # q_offset 384
     "gqa_4_to_1": (2, 256, 256, 4, 1, 16),
+    "gqa_8_to_1": (1, 256, 256, 8, 1, 16),
 }
 
 
@@ -308,18 +310,56 @@ def test_flash_forward_out_and_lse_match_reference(name, causal):
     )
 
 
-@pytest.mark.parametrize("name", ["3x3_of_128", "decode_inside_a_block"])
-def test_flash_grads_match_reference_where_plan_changes(name):
-    """The backward kernels read the forward's lse."""
+@contextlib.contextmanager
+def _backward_path(path):
+    """Steer the backward's plan at a small shape: ``whole`` is what the
+    shape gets (operands a row at a time, the walk unrolled), ``loops``
+    the same with the walk as loops (what S 2048 and up get), ``grid`` the
+    clamped grid (what does not fit the fetch budget gets: one 128-row
+    tile of D=16 float32 fits it here).  The jitted launches keep their
+    traces by shape, so they are dropped before and after."""
+    patch = {"whole": {}, "loops": {"_UNROLL_TILES": 0},
+             "grid": {"_KV_FETCH_BYTES": 128 * 16 * 4}}[path]
+
+    def drop():
+        pallas_attention._fwd_launch.clear_cache()
+        pallas_attention._bwd_launch.clear_cache()
+
+    with contextlib.ExitStack() as stack:
+        for name, value in patch.items():
+            stack.enter_context(mock.patch.object(pallas_attention, name, value))
+        if patch:
+            drop()
+            stack.callback(drop)
+        yield
+
+
+@pytest.mark.parametrize("path", ["whole", "loops", "grid"])
+@pytest.mark.parametrize("name", [
+    "3x3_of_128", "decode_inside_a_block",
+    # PR 35, where the backward's plan changes: GQA groups of 4 and 8 (the
+    # dk/dv kernel walks every member), queries at the end of the keys
+    "gqa_4_to_1", "gqa_8_to_1", "decode_block_multiple",
+])
+def test_flash_grads_match_reference_where_plan_changes(name, path):
+    """The backward kernels read the forward's lse, on each of their
+    paths."""
     q, k, v = _plan_inputs(name)
+    B, Sq, Skv, H, Hkv, D = _PLAN_SHAPES[name]
+    with _backward_path(path):
+        plan = pallas_attention._bwd_plan(Sq, Skv, D, 4, H // Hkv)
+        assert (plan.dq_whole, plan.dkv_whole) == (path != "grid",) * 2
+        assert plan.unrolled == (path == "whole")
 
-    def loss_flash(q, k, v):
-        return jnp.sum(pallas_attention.flash_attention(q, k, v, True, True) ** 2)
+        def loss_flash(q, k, v):
+            return jnp.sum(
+                pallas_attention.flash_attention(q, k, v, True, True) ** 2)
 
-    def loss_ref(q, k, v):
-        return jnp.sum(dot_product_attention(q, k, v, causal=True) ** 2)
+        def loss_ref(q, k, v):
+            k, v = repeat_kv(k, H // Hkv), repeat_kv(v, H // Hkv)
+            return jnp.sum(dot_product_attention(q, k, v, causal=True) ** 2)
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     with jax.default_matmul_precision("highest"):
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for n, a, b in zip("qkv", gf, gr):
@@ -447,6 +487,10 @@ def test_window_on_the_xla_path_is_the_mask_written_from_positions(window):
     ((1, 1024, 1024, 2, 1, 16), 700),    # tiles of 512, fetches of one tile
     ((1, 2048, 2048, 1, 1, 16), 1024),   # fetches of 512 of 2048: 3 a q block
     ((1, 256, 768, 2, 2, 16), 300),      # queries at the end of the keys
+    # PR 35: the dk/dv kernel's walk of (q block, member) pairs under a window
+    ((1, 384, 384, 4, 1, 16), 200),      # a group of 4
+    ((1, 384, 384, 8, 1, 16), 130),      # a group of 8
+    ((1, 256, 768, 8, 2, 16), 100),      # groups of 4, columns no query sees
 ], ids=lambda x: str(x).replace(" ", ""))
 def test_flash_kernels_with_a_window_match_the_xla_path(shape, window):
     """All three kernels through the interpreter, values and gradients,
@@ -532,6 +576,91 @@ def test_fwd_tile_counts_with_a_window():
         assert c.unmasked + c.masked + c.skipped == n_q * n_k
 
 
+# --- the backward's plan and its counter (PR 35) ---------------------------
+
+
+@pytest.mark.parametrize(
+    "Sq,Skv,D,group,window,plan,dq,dkv",
+    [
+        # the GPT-2 cells: a row arrives whole, 1 / 2 / 1 of 4 tiles, one
+        # step a row (the parent launched 4 and 4 a row, one of each dead)
+        (1024, 1024, 64, 1, None, (512, 512, True, True, True),
+         (1, 2, 1, 1), (1, 2, 1, 1)),
+        # the granite cell: dq whole (K, V, q, do 512 KiB each), dk/dv not
+        # (a group of 4: 2 MiB of q) — its grid walks 8 blocks x 8 x 4
+        (4096, 4096, 64, 4, None, (512, 512, True, False, False),
+         (28, 8, 28, 1), (112, 32, 112, 8 * 8 * 4)),
+        # the trinity cell's full layer: K / V of 2 MiB do not fit
+        (8192, 8192, 128, 8, None, (512, 512, False, False, False),
+         (120, 16, 120, 16 * 16), (960, 128, 960, 16 * 16 * 8)),
+        # its sliding layers: 42 / 28 / 186 of 256, five steps a q block
+        # or a kv block and member
+        (8192, 8192, 128, 8, 2048, (512, 512, False, False, False),
+         (42, 28, 186, 16 * 5), (336, 224, 1488, 16 * 5 * 8)),
+        # decode: 128 queries at the end of 1024 keys, groups of 4
+        (128, 1024, 128, 4, None, (128, 512, True, True, True),
+         (1, 1, 0, 1), (4, 4, 0, 1)),
+        # few rows, many tiles: whole, walked by loops
+        (2048, 2048, 64, 4, None, (512, 512, True, True, False),
+         (6, 4, 6, 1), (24, 16, 24, 1)),
+    ],
+)
+def test_bwd_tile_plan_counts(Sq, Skv, D, group, window, plan, dq, dkv):
+    pa = pallas_attention
+    got = pa._bwd_plan(Sq, Skv, D, 2, group, window)
+    assert got == plan
+    assert pa.bwd_tile_counts(
+        Sq, Skv, True, Skv - Sq, got, window, group) == (dq, dkv)
+
+
+def test_bwd_tile_counts_cover_the_grid_and_agree_with_predicates():
+    """unmasked + masked + skipped is every (q block, kv block) pair for
+    both kernels, the tiles are the forward's, and the column walk's
+    closed form (``_q_walk``) is the two predicates read by column."""
+    pa = pallas_attention
+    for Sq, Skv, window in [
+        (128, 128, None), (384, 384, None), (1024, 1024, None),
+        (128, 1024, None), (384, 1024, None), (256, 768, None),
+        (512, 2048, None), (1024, 1536, None), (384, 384, 100),
+        (384, 384, 200), (1024, 1024, 700), (256, 768, 300),
+        (2048, 2048, 512), (256, 768, 100),
+    ]:
+        for causal, group in [(True, 1), (True, 4), (False, 2)]:
+            if window is not None and not causal:
+                continue
+            plan = pa._bwd_plan(Sq, Skv, 64, 2, group, window)
+            geom = dict(block_q=plan.block_q, block_k=plan.block_k,
+                        q_offset=Skv - Sq)
+            n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
+            live = np.array([[bool(pa._block_live(
+                i, j, causal=causal, window=window, **geom))
+                for j in range(n_k)] for i in range(n_q)])
+            free = np.array([[bool(pa._block_unmasked(
+                i, j, causal=causal, window=window, **geom))
+                for j in range(n_k)] for i in range(n_q)])
+            for j in range(n_k):
+                first, lo, end = pa._q_walk(
+                    j, causal=causal, window=window, n_q=n_q, **geom)
+                assert [i for i in range(n_q) if live[i, j]] == list(
+                    range(first, end))
+                if window is None:  # below ``lo`` the diagonal crosses
+                    assert list(free[:, j]) == [i >= lo for i in range(n_q)]
+            c = pa.bwd_tile_counts(Sq, Skv, causal, Skv - Sq, plan, window, group)
+            fwd = pa.fwd_tile_counts(
+                Sq, Skv, causal, Skv - Sq,
+                pa._fwd_plan(Sq, Skv, 64, 2, window), window)
+            assert c.dq[:3] == fwd[:3]
+            assert c.dkv[:3] == tuple(group * t for t in fwd[:3])
+            assert (c.dq.unmasked, c.dq.unmasked + c.dq.masked) == (
+                free.sum(), live.sum())
+            assert sum(c.dq[:3]) == n_q * n_k
+            if plan.dq_whole:
+                assert (c.dq.steps, c.dkv.steps) == (1, 1)
+            else:  # the grid has room for the widest row and column
+                assert c.dq.steps == n_q * live.sum(1).max()
+                assert c.dkv.steps == n_k * max(live.sum(0).max(), 1) * group
+
+
 # --- the forward kernel through the chip's own compiler (no chip) --------
 # Interpret mode cannot see what Mosaic refuses (a misaligned slice, too
 # much VMEM, a layout it cannot make); an AOT compile for a described v5e
@@ -591,6 +720,43 @@ def test_flash_forward_compiles_for_v5e(
     ).lower(q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "flash_fwd" in text
+
+
+@pytest.mark.parametrize(
+    "B,Sq,Skv,H,Hkv,D,dtype,causal,scale,window",
+    [
+        # the four shapes the cells reach
+        (8, 1024, 1024, 16, 16, 64, jnp.bfloat16, True, None, None),
+        (2, 4096, 4096, 32, 8, 64, jnp.bfloat16, True, 1 / 64, None),
+        (1, 8192, 8192, 32, 4, 128, jnp.bfloat16, True, None, 2048),
+        (1, 8192, 8192, 32, 4, 128, jnp.bfloat16, True, None, None),
+        # and the plan's other corners
+        (2, 1024, 1024, 12, 12, 64, jnp.float32, True, None, None),   # chip_smoke
+        (2, 1024, 1024, 8, 8, 64, jnp.bfloat16, False, None, None),   # a ring hop
+        (2, 128, 1024, 8, 2, 128, jnp.bfloat16, True, None, None),    # GQA decode
+        (1, 8192, 8192, 2, 2, 64, jnp.bfloat16, True, None, None),    # 1 MiB operands whole, loops
+        (1, 2048, 2048, 2, 2, 128, jnp.float32, True, None, None),    # the same in f32, unrolled
+        (1, 1024, 1024, 4, 4, 200, jnp.bfloat16, True, None, None),   # D off the lanes
+    ],
+)
+def test_flash_backward_compiles_for_v5e(
+    v5e_chip, no_compile_cache, B, Sq, Skv, H, Hkv, D, dtype, causal, scale,
+    window,
+):
+    """Both backward kernels through the chip's own compiler, on each of
+    the plan's paths (a row whole and unrolled, whole and looped, the
+    clamped grid with and without a window)."""
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+
+    q, kv = sds((B, Sq, H, D)), sds((B, Skv, Hkv, D))
+    text = jax.jit(
+        lambda q, k, v, out, lse, do: pallas_attention._bwd(
+            causal, False, scale, window, (q, k, v, out, lse), do)
+    ).lower(q, kv, kv, q, sds((B * H, 8, Sq), jnp.float32), q
+            ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
 
 
 @pytest.mark.parametrize(
@@ -745,7 +911,9 @@ def test_the_granite_step_holds_the_conv_kernels_and_no_copy_round_them(
         )
     ins = _instructions(seen["text"])
     # PR 33 put a window through the three flash kernels: with none given the
-    # step is the parent's, instruction for instruction (24,849 of them)
+    # step is the parent's, instruction for instruction (24,849 of them);
+    # PR 35 rewrote the two backward kernels and left it so (with a
+    # ``CostEstimate`` on them XLA prefetches one more operand: 24,852)
     assert sum(" = " in line for line in seen["text"].splitlines()) == 24_849
 
     def calls(kernel):
@@ -788,7 +956,10 @@ def test_a_gpt2_step_has_not_noticed_the_window(v5e_chip, no_compile_cache):
     """Two layers of cell 1's step at its widths: 4,863 instructions and 6
     custom calls, as the parent of PR 33 compiles it (the whole 24-layer step
     was compared text against text when the window went in: equal but for the
-    source lines in the kernels' bodies)."""
+    source lines in the kernels' bodies) and as PR 35 left it, whose backward
+    kernels changed inside the custom calls alone (with a ``CostEstimate`` on
+    them XLA slices one more prefetch to VMEM, 4,872, and re-tiles the matmul
+    that reads it)."""
     from benchmarks import aot_fit, harness
 
     seen = {}

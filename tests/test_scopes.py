@@ -46,10 +46,11 @@ _WORK = re.compile(
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-def _compiled_step_text(n_devices: int, scan_layers: bool) -> str:
+def _compiled_step_text(n_devices: int, scan_layers: bool,
+                        attn_impl: str = "xla", seq: int = 16) -> str:
     cfg = gpt2_124m(
         vocab_size=128, d_model=32, num_layers=2, num_heads=2, d_ff=64,
-        max_seq_len=16, scan_layers=scan_layers, attn_impl="xla",
+        max_seq_len=seq, scan_layers=scan_layers, attn_impl=attn_impl,
     )
     model = TransformerLM(cfg)
     mesh = ddp.make_mesh(("data",), devices=jax.devices()[:n_devices])
@@ -72,7 +73,7 @@ def _compiled_step_text(n_devices: int, scan_layers: bool) -> str:
     )
     step = ddp.make_train_step(loss_fn, mesh=mesh, grad_clip=1.0)
     batch = shard_batch(
-        {"tokens": jnp.zeros((2 * n_devices, 17), jnp.int32)}, mesh
+        {"tokens": jnp.zeros((2 * n_devices, seq + 1), jnp.int32)}, mesh
     )
     return step.lower(state, batch, jax.random.PRNGKey(0)).compile().as_text()
 
@@ -325,6 +326,44 @@ def test_moe_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
     assert dense
     for name in scopes.MOE_SCOPES:
         assert found[name, "fwd"] and found[name, "bwd"], (name, found)
+
+
+def test_flash_kernels_carry_attn_scope_and_phase_in_a_compiled_step(devices):
+    """The backward kernels are launched from a jitted ``_bwd_launch`` (PR
+    35) as the forward is from ``_fwd_launch`` (PR 26), inside a
+    ``custom_vjp``: their operations must still carry ``.../attn/...`` and
+    their own name, which is where ``train_attn_ms`` and the four
+    ``flash_*_roofline`` readers look for them — ``flash_fwd`` in the
+    forward pass, ``flash_bwd_dq`` / ``flash_bwd_dkv`` under ``transpose(``
+    and nowhere else.  The kernels are forced through the interpreter; on
+    the chip each is one custom call under the same name."""
+    from unittest import mock
+
+    from distributeddataparallel_tpu.ops import pallas_attention
+
+    flash = pallas_attention.flash_attention
+    # "auto" with the backend's say left out: the model's init, at 8
+    # tokens, takes the XLA path as it would on the chip
+    with mock.patch.object(pallas_attention, "supported",
+                           lambda q, k, v: q.shape[1] % 128 == 0), \
+            mock.patch.object(
+                pallas_attention, "flash_attention",
+                lambda q, k, v, causal, interpret, scale, window: flash(
+                    q, k, v, causal, True, scale, window)):
+        text = _compiled_step_text(1, False, attn_impl="auto", seq=128)
+    buckets = dict(zip(scopes.KERNEL_NAMES, scope_reduce.KERNEL_BUCKETS))
+    found = collections.Counter()
+    for scope in _OP_NAME.findall(text):
+        for name in scopes.KERNEL_NAMES:
+            if f"/{name}/" in scope:
+                assert scope_reduce.bucket_of(scope) == buckets[name], scope
+                assert "/attn/" in scope, scope
+                found[name, scope_reduce.phase_of(scope, "")] += 1
+    fwd, dq, dkv = scopes.FLASH_FWD, scopes.FLASH_BWD_DQ, scopes.FLASH_BWD_DKV
+    assert found[fwd, "fwd"] and not found[fwd, "bwd"], found
+    for name in (dq, dkv):
+        assert found[name, "bwd"] and not found[name, "fwd"], found
+    assert "jit(_bwd_launch)" in text and "jit(_fwd_launch)" in text
 
 
 def test_three_pallas_calls_have_three_names():
