@@ -55,7 +55,11 @@ from distributeddataparallel_tpu.parallel.tensor_parallel import (
 #: window): a window of ``sliding_window`` keys with rotated q and k, and
 #: every earlier key with no positions at all
 SLIDING, FULL = "sliding_attention", "full_attention"
-LAYER_KINDS = frozenset({"attention", "mamba", SLIDING, FULL})
+#: exact attention inside blocks of ``sliding_window`` positions and one
+#: learned summary per ``eva_chunk`` keys of every earlier block, under one
+#: softmax (``ops.eva``); q and k rotated where ``positional`` is "rope"
+EVA = "eva_attention"
+LAYER_KINDS = frozenset({"attention", "mamba", SLIDING, FULL, EVA})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,6 +204,19 @@ class TransformerConfig:
     # scatter-add back — and leaves out what the others would have added.
     # None keeps the dispatches chosen by ``moe_capacity_factor``.
     moe_experts_held: tuple[int, int] | None = None
+    # A byte-level model's four (each skipped at its neutral value).
+    # ``layer_types`` of "eva_attention": blocks of ``sliding_window``
+    # positions, chunks of ``eva_chunk`` keys, and two learned vectors a
+    # head (``ops.eva``).  ``norm_unit_offset``: an RMSNorm scales by
+    # ``1 + offset``, its parameter drawn round 0.  ``fp32_residual``: the
+    # residual stream is kept and added in float32 while the branches
+    # compute in ``dtype``.  ``num_pred_heads`` P > 1: the head gives P x
+    # vocab logits a position, viewed (B, S, P, vocab); head h is scored on
+    # the token ``1 + h`` ahead (``ops.losses.multi_token_cross_entropy``).
+    eva_chunk: int = 0
+    norm_unit_offset: bool = False
+    fp32_residual: bool = False
+    num_pred_heads: int = 1
 
     def __post_init__(self):
         if self.scan_layers and self.num_dense_layers:
@@ -225,6 +242,13 @@ class TransformerConfig:
             raise ValueError("scan_layers runs attention layers only")
         if SLIDING in kinds and not self.sliding_window:
             raise ValueError("sliding_attention layers need sliding_window")
+        if EVA in kinds and not (
+                self.sliding_window and self.eva_chunk
+                and self.sliding_window % self.eva_chunk == 0):
+            raise ValueError(
+                "eva_attention layers need sliding_window, a whole number "
+                "of chunks of eva_chunk"
+            )
 
     @property
     def kv_heads(self) -> int:
@@ -305,6 +329,24 @@ def trinity_mini(**overrides) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def evabyte(**overrides) -> TransformerConfig:
+    """EvaByte 6.5B (``evabyte``, ``attention_class`` "eva"): 32 layers of
+    chunk-summarised attention (blocks of 2048, chunks of 16; 32 heads of
+    128, rotated at theta 1e5) and a gated SiLU MLP of 11008 at d 4096, no
+    bias; RMSNorms that scale by ``1 + offset``; a float32 residual stream;
+    320 byte and special ids, untied, eight prediction heads."""
+    base = dict(
+        vocab_size=320, num_layers=32, num_heads=32, d_model=4096,
+        d_ff=11008, max_seq_len=32768, norm="rmsnorm", activation="swiglu",
+        positional="rope", rope_theta=100000.0, tie_embeddings=False,
+        use_bias=False, sliding_window=2048, eva_chunk=16,
+        norm_unit_offset=True, fp32_residual=True, num_pred_heads=8,
+    )
+    base.update(overrides)
+    base.setdefault("layer_types", (EVA,) * base["num_layers"])
+    return TransformerConfig(**base)
+
+
 def tiny_lm(**overrides) -> TransformerConfig:
     """Test-sized config (fast CPU init/compile)."""
     base = dict(
@@ -325,22 +367,37 @@ def moe_aux_from_intermediates(col) -> Any:
 
 
 class RMSNorm(nn.Module):
-    """Llama-style RMS normalization; stats in f32, scale param f32."""
+    """Llama-style RMS normalization; stats in f32, scale param f32.
+    ``unit_offset``: the learned parameter is ``offset``, drawn round 0,
+    and the scale ``1 + offset``.  ``out_dtype`` (None: the input's) is
+    what the result is cast to: a float32 residual stream feeds branches
+    that compute in a narrower type."""
 
     epsilon: float = 1e-5
+    unit_offset: bool = False
+    out_dtype: Any = None
 
     @nn.compact
     def __call__(self, x):
-        dtype = x.dtype
+        dtype = self.out_dtype or x.dtype
         x = x.astype(jnp.float32)
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        if self.unit_offset:
+            scale = 1.0 + self.param(
+                "offset", nn.initializers.zeros, (x.shape[-1],))
+        else:
+            scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.epsilon)
         return (x * scale).astype(dtype)
 
 
 def _make_norm(cfg: TransformerConfig, name: str):
     if cfg.norm == "rmsnorm":
-        return RMSNorm(name=name)
+        return RMSNorm(
+            name=name, unit_offset=cfg.norm_unit_offset,
+            out_dtype=cfg.dtype if cfg.fp32_residual else None,
+        )
+    if cfg.norm_unit_offset or cfg.fp32_residual:
+        raise ValueError("norm_unit_offset and fp32_residual are RMSNorm's")
     # LayerNorm math in f32 regardless of activation dtype.
     return nn.LayerNorm(epsilon=1e-5, dtype=jnp.float32, name=name)
 
@@ -410,7 +467,7 @@ class Attention(nn.Module):
         if new and (cfg.decode or cfg.tp_axis is not None
                     or cfg.cp_axis is not None):
             raise ValueError(
-                "window / full attention kinds, qk_norm and "
+                "window / full / eva attention kinds, qk_norm and "
                 "attn_output_gate run data-parallel training only "
                 "(no decode, tp_axis or cp_axis)"
             )
@@ -516,6 +573,21 @@ class Attention(nn.Module):
                     q, kf, vf, causal=False, bias=bias[None, None],
                     scale=cfg.attention_multiplier,
                 )
+        elif self.kind == EVA:
+            from distributeddataparallel_tpu.ops.eva import eva_attention
+
+            if Hkvl != Hl:
+                raise ValueError("eva_attention layers share no kv heads")
+            # one learned query and one key offset a head, for the pooling;
+            # drawn as the other matrices are, not by the published init_fn
+            init = nn.initializers.normal(0.02)
+            phi = self.param("adaptive_phi", init, (H, D), jnp.float32)
+            mu = self.param("adaptive_mu_k", init, (H, D), jnp.float32)
+            out = eva_attention(
+                q, k, v, phi, mu, window=cfg.sliding_window,
+                chunk=cfg.eva_chunk, scale=cfg.attention_multiplier,
+                impl=cfg.attn_impl,
+            )
         elif cfg.cp_axis is not None and cfg.attention_multiplier is not None:
             raise ValueError(
                 "attention_multiplier is not carried through the "
@@ -1006,6 +1078,8 @@ class DecoderBlock(nn.Module):
         def add(x, branch):
             if cfg.residual_multiplier != 1.0:
                 branch = branch * cfg.residual_multiplier
+            if cfg.fp32_residual:  # the branch computed narrow, added wide
+                branch = branch.astype(jnp.float32)
             return x + drop(branch)
 
         def after(branch, name):
@@ -1185,7 +1259,9 @@ class TransformerLM(nn.Module):
             param_dtype=jnp.float32,
         )
         with jax.named_scope(scopes.EMBED):
-            x = embed(tokens).astype(cfg.dtype)
+            x = embed(tokens)
+            if not cfg.fp32_residual:  # else the stream stays float32
+                x = x.astype(cfg.dtype)
             if cfg.embedding_multiplier != 1.0:
                 x = x * cfg.embedding_multiplier
             if cfg.positional == "learned":
@@ -1246,8 +1322,12 @@ class TransformerLM(nn.Module):
                 )
             else:
                 logits = LMHead(
-                    cfg.vocab_size, cfg.dtype, name="lm_head"
+                    cfg.vocab_size * cfg.num_pred_heads, cfg.dtype,
+                    name="lm_head",
                 )(x)
             if cfg.logits_scaling != 1.0:
                 logits = logits / cfg.logits_scaling
+            if cfg.num_pred_heads > 1:
+                logits = logits.reshape(
+                    B, S, cfg.num_pred_heads, cfg.vocab_size)
         return logits

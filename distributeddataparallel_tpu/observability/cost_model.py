@@ -136,6 +136,39 @@ def moe_cost(rows: int, d: int, f: int, groups: int) -> dict:
     }
 
 
+def eva_pair_counts(seq: int, window: int, chunk: int) -> tuple[int, int]:
+    """``(local, remote)`` (query, key) and (query, summary) pairs of one
+    head and one sequence under ``ops.eva``'s rule: a query sees its own
+    block's keys up to itself, and one summary a chunk of every earlier
+    block.  At 16,384 positions in blocks of 2,048 and chunks of 16:
+    16,785,408 and 7,340,032."""
+    if seq <= window:
+        return seq * (seq + 1) // 2, 0
+    n = seq // window
+    return (n * window * (window + 1) // 2,
+            window * (window // chunk) * n * (n - 1) // 2)
+
+
+def eva_cost(batch: int, seq: int, heads: int, head_dim: int, window: int,
+             chunk: int) -> dict:
+    """FLOPs and least HBM bytes of one layer's attention over the
+    summaries (``ops.eva.remote_attention``) in one train step, from
+    shapes alone — whatever implements it.  The score and the value
+    product over the (query, summary) pairs, times 3 for forward and
+    backward; bytes: the queries past the first block, their result and
+    the gradients of both (bf16), the summaries of all blocks but the
+    last, keys and values, and their gradients (bf16), the row statistic
+    and its gradient (float32), once each."""
+    _, pairs = eva_pair_counts(seq, window, chunk)
+    rows = max(seq - window, 0)
+    kept = rows // chunk
+    return {
+        "flops": 3 * 2 * 2 * batch * heads * head_dim * pairs,
+        "bytes": batch * heads * (
+            2 * head_dim * (4 * rows + 4 * kept) + 4 * 2 * rows),
+    }
+
+
 def simple_cnn_fwd_flops(
     *,
     batch: int,

@@ -68,6 +68,20 @@ MOE_SHARED = "moe_shared"
 MOE_GMM = "moe_gmm"
 MOE_TGMM = "moe_tgmm"
 
+#: the four parts of ``ops.eva.eva_attention`` inside the Flax module
+#: ``attn`` (``layer_<i>/attn/<part>/...``): exact causal attention inside
+#: each window (the three flash kernels run under it, on windows folded
+#: into the batch), the pooling of each chunk's keys and values into one
+#: summary, every query's attention over the summaries of all earlier
+#: windows (the flash kernels again, under the staircase rule), and the
+#: merge of the two softmaxes under one normaliser.  A tuple of their own,
+#: read by the benchmark's ``eva_scopes`` and not by its bucket table
+#: (which counts all of it under ``attn`` and the kernels' own buckets)
+EVA_LOCAL = "eva_local"
+EVA_SUMMARIES = "eva_summaries"
+EVA_REMOTE = "eva_remote"
+EVA_MERGE = "eva_merge"
+
 STEP_SCOPES = (EMBED, HEAD, LOSS, METRICS, GRAD_SYNC, GRAD_CLIP, OPTIMIZER)
 KERNEL_NAMES = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
 MIXER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSD, SSM_GATE_NORM, SSM_OUT_PROJ)
@@ -75,3 +89,4 @@ SSD_KERNEL_NAMES = (SSD_FWD, SSD_BWD)
 CONV_KERNEL_NAMES = (CONV_FWD, CONV_BWD)
 MOE_KERNEL_NAMES = (MOE_GMM, MOE_TGMM)
 MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, MOE_SHARED)
+EVA_SCOPES = (EVA_LOCAL, EVA_SUMMARIES, EVA_REMOTE, EVA_MERGE)

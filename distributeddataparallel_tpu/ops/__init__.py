@@ -2,6 +2,7 @@ from distributeddataparallel_tpu.ops.losses import (  # noqa: F401
     cross_entropy_loss,
     accuracy,
     lm_cross_entropy,
+    multi_token_cross_entropy,
     per_example_accuracy,
     per_example_cross_entropy,
 )

@@ -109,6 +109,7 @@ def dot_product_attention(
     bias: jnp.ndarray | None = None,
     scale: float | None = None,
     window: int | None = None,
+    return_lse: bool = False,
 ) -> jnp.ndarray:
     """XLA reference attention. q: (B,Sq,H,D); k/v: (B,Skv,H,D) -> (B,Sq,H,D).
 
@@ -116,6 +117,8 @@ def dot_product_attention(
     MXU; the f32 softmax runs on the VPU and fuses with the scale/mask).
     ``scale`` multiplies the scores; None is 1/sqrt(D).  A ``window``
     (causal only) hides the keys ``window`` or more behind a query.
+    With ``return_lse`` the result is ``(out, lse)``, ``lse`` (B, Sq, H)
+    float32 the log of each row's summed exponentials.
     """
     if window is not None and not causal:
         raise ValueError("a window bounds causal attention only")
@@ -133,7 +136,11 @@ def dot_product_attention(
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
     weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+    out = jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+    if not return_lse:
+        return out
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)  # (B, H, Sq)
+    return out, lse.transpose(0, 2, 1)
 
 
 def attention(
@@ -145,11 +152,14 @@ def attention(
     impl: str = "auto",
     scale: float | None = None,
     window: int | None = None,
+    return_lse: bool = False,
 ) -> jnp.ndarray:
     """Dispatch: 'xla' reference, 'pallas' flash kernel, or 'auto'.
     ``scale`` multiplies the scores; None is 1/sqrt(head_dim).  A static
     ``window`` (causal only; None: none) lets a query see its own key and
-    the ``window - 1`` before it.
+    the ``window - 1`` before it.  With ``return_lse`` the result is
+    ``(out, lse)``, ``lse`` (B, Sq, H) float32, differentiable on either
+    path (``ops.eva`` merges two softmaxes by it).
 
     'auto' uses the Pallas flash kernel on TPU whenever the shapes are
     ``supported()`` and the XLA reference otherwise; the choice is made
@@ -170,7 +180,7 @@ def attention(
 
         if pallas_attention.supported(q, k, v):
             return pallas_attention.flash_attention(
-                q, k, v, causal, False, scale, window
+                q, k, v, causal, False, scale, window, return_lse=return_lse
             )
         if impl == "pallas":
             raise ValueError(
@@ -186,5 +196,6 @@ def attention(
         k = repeat_kv(k, H // Hkv)
         v = repeat_kv(v, H // Hkv)
     return dot_product_attention(
-        q, k, v, causal=causal, scale=scale, window=window
+        q, k, v, causal=causal, scale=scale, window=window,
+        return_lse=return_lse,
     )

@@ -61,3 +61,24 @@ def lm_cross_entropy(
         return ce.mean()
     mask = mask.astype(jnp.float32)
     return (ce * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+
+@jax.named_scope(scopes.LOSS)
+def multi_token_cross_entropy(
+    logits: jnp.ndarray, ids: jnp.ndarray
+) -> jnp.ndarray:
+    """Several tokens predicted a position: logits (B, S, P, V), ids
+    (B, S + 1) int.  Head ``h`` at position ``t`` is scored on ``ids[t + 1
+    + h]`` where the row has one (``t + 1 + h <= S``), masked elsewhere;
+    the loss is the mean over the P heads of each head's mean cross
+    entropy over its unmasked positions.  At P = 1 it is
+    ``lm_cross_entropy(logits[:, :, 0], ids[:, 1:])``."""
+    B, S, P, _ = logits.shape
+    ahead = jnp.arange(S)[:, None] + 1 + jnp.arange(P)[None, :]   # (S, P)
+    scored = (ahead <= S).astype(jnp.float32)
+    targets = ids[:, jnp.minimum(ahead, S)]                       # (B, S, P)
+    ce = optax.softmax_cross_entropy_with_integer_labels(
+        logits.astype(jnp.float32), targets
+    )
+    per_head = (ce * scored).sum(axis=(0, 1)) / (B * scored.sum(axis=0))
+    return per_head.mean()

@@ -50,6 +50,16 @@ the LM configs (BASELINE 4-5), written against the Pallas TPU guide
   the live tiles of a row or column alone.  Tiles left of the window are
   never entered and their K/V never fetched.
 
+- A static ``stair = (q_step, k_step)`` (None: none; not causal) is a
+  third rule of visibility: query i sees keys ``[0, (i // q_step + 1) *
+  k_step)`` — every summary of every earlier window, for ``ops/eva.py``.
+  With tiles that divide both steps a tile is wholly visible or wholly
+  not, so the rule is a span of kv tiles per q block (``_kv_span``) and a
+  first q block per kv block (``_q_walk``) and no mask at all.
+- ``flash_attention(..., return_lse=True)`` hands the row statistic out
+  too, (B, S, H) float32, and takes its cotangent: the backward's
+  ``delta`` becomes ``rowsum(do * out) - d lse`` and nothing else changes.
+
 CPU tests run the same kernel under ``interpret=True``.
 """
 
@@ -104,6 +114,32 @@ def supported(q, k, v) -> bool:
     )
 
 
+def _stair_blocks(Sq: int, Skv: int, stair: tuple[int, int] | None):
+    """Score-tile sides ``(block_q, block_k)``: ``_pick_block`` squares,
+    and under a staircase the largest that also divide its two steps, so
+    that a tile is wholly seen or wholly not."""
+    if stair is None:
+        return _pick_block(Sq), _pick_block(Skv)
+    return (_pick_block(math.gcd(Sq, stair[0])),
+            _pick_block(math.gcd(Skv, stair[1])))
+
+
+def stair_supported(q, k, v, stair) -> bool:
+    """``supported`` for the staircase rule: no causal alignment, so Sq
+    may pass Skv (every row sees at least ``k_step`` keys); the tiles
+    must divide both steps, so that no tile needs a mask."""
+    if jax.default_backend() != "tpu" or v.shape != k.shape:
+        return False
+    B, Sq, H, D = q.shape
+    block_q, block_k = _stair_blocks(Sq, k.shape[1], stair)
+    return (
+        H == k.shape[2]
+        and block_q is not None and block_k is not None
+        and -(-Sq // stair[0]) * stair[1] <= k.shape[1]
+        and D % 8 == 0 and D <= 256
+    )
+
+
 def _block_live(i, j, *, causal: bool, block_q: int, block_k: int,
                 q_offset: int, window: int | None = None):
     """Causal block-skip predicate shared by forward and backward kernels:
@@ -136,12 +172,22 @@ def _block_unmasked(i, j, *, causal: bool, block_q: int, block_k: int,
     return free
 
 
-def _kv_span(i, *, causal: bool, block_q: int, block_k: int, q_offset: int, n_k: int):
+def _kv_span(i, *, causal: bool, block_q: int, block_k: int, q_offset: int,
+             n_k: int, stair: tuple[int, int] | None = None):
     """``(full, live)`` for q block ``i``: kv tiles ``[0, full)`` are
     ``_block_unmasked``, ``[full, live)`` are live and crossed by the
     diagonal, ``[live, n_k)`` are dead — the two predicates counted in
     closed form (both are monotone in j), so a loop can stop where a grid
-    would test.  ``i`` may be a Python int or a traced scalar."""
+    would test.  ``i`` may be a Python int or a traced scalar.
+
+    Under a ``stair`` (q_step, k_step) — the one statement of that rule
+    for the forward and the dq kernel — the block's queries all lie on
+    step ``i * block_q // q_step`` and see ``k_step`` keys more a step:
+    ``full == live``, no tile is crossed."""
+    if stair is not None:
+        q_step, k_step = stair
+        seen = (i * block_q // q_step + 1) * (k_step // block_k)
+        return seen, seen
     if not causal:
         return n_k, n_k
     q_first = q_offset + i * block_q
@@ -204,7 +250,8 @@ _KV_FETCH_BYTES = 1 << 20
 
 
 def _fwd_plan(Sq: int, Skv: int, D: int, itemsize: int,
-              window: int | None = None) -> FwdPlan:
+              window: int | None = None,
+              stair: tuple[int, int] | None = None) -> FwdPlan:
     """The forward's tile plan, chosen from what the operands show.
 
     Score tiles are ``_pick_block`` squares as in the backward kernels.
@@ -214,8 +261,7 @@ def _fwd_plan(Sq: int, Skv: int, D: int, itemsize: int,
     Under a ``window`` a fetch is at most half of it, so that what a q
     block fetches stays near what it can see.
     """
-    block_q = _pick_block(Sq)
-    block_k = _pick_block(Skv)
+    block_q, block_k = _stair_blocks(Sq, Skv, stair)
     n_k = Skv // block_k
     fit = max(1, _KV_FETCH_BYTES // (block_k * D * itemsize))
     if window is not None:
@@ -240,7 +286,8 @@ def _fetch_steps(Sq: int, Skv: int, q_offset: int, plan: FwdPlan,
 
 
 def fwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
-                    plan: FwdPlan, window: int | None = None) -> TileCounts:
+                    plan: FwdPlan, window: int | None = None,
+                    stair: tuple[int, int] | None = None) -> TileCounts:
     """How often each path of the forward kernel engages — static, like
     the mechanism: ``_kv_span`` and ``_kv_window`` summed over the q
     blocks."""
@@ -248,7 +295,7 @@ def fwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
     geom = dict(block_q=plan.block_q, block_k=plan.block_k, q_offset=q_offset)
     unmasked = live = 0
     for i in range(n_q):
-        full, end = _kv_span(i, causal=causal, n_k=n_k, **geom)
+        full, end = _kv_span(i, causal=causal, n_k=n_k, stair=stair, **geom)
         start, lo = _kv_window(i, window=window, **geom)
         unmasked += max(full - lo, 0)
         live += end - start
@@ -293,7 +340,7 @@ def _flash_kernel(
                           # satisfy the TPU (8, 128) block-tiling minimum
     m_ref, l_ref, acc_ref,  # VMEM scratch: (BQ, 128), (BQ, 128), (BQ, D)
     *, causal: bool, block_k: int, scale: float, q_offset: int,
-    window: int | None = None,
+    window: int | None = None, stair: tuple[int, int] | None = None,
 ):
     """One grid step owns one q block of one (batch*head) row and one
     fetched K/V block of ``BKV`` rows, and walks that block in score tiles
@@ -316,7 +363,8 @@ def _flash_kernel(
     jf = pl.program_id(2)  # fetched K/V block index
     nf = pl.num_programs(2)
     geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
-    full, live = _kv_span(i, causal=causal, n_k=per_fetch * nf, **geom)
+    full, live = _kv_span(i, causal=causal, n_k=per_fetch * nf, stair=stair,
+                          **geom)
     first = jf * per_fetch  # the kv tile this fetch starts at
     if window is not None:
         start, lo = _kv_window(i, window=window, **geom)
@@ -412,9 +460,12 @@ def _gqa_kv_row(b, *, H: int, Hkv: int):
 
 
 def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool,
-                    scale: float | None = None, window: int | None = None):
+                    scale: float | None = None, window: int | None = None,
+                    stair: tuple[int, int] | None = None):
     if window is not None and not causal:
         raise ValueError("a window bounds causal attention only")
+    if stair is not None and (causal or window is not None):
+        raise ValueError("a staircase is a rule of its own: not causal, no window")
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     Hkv = k.shape[2]
@@ -435,7 +486,7 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool,
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Skv, D)
     out, lse = _fwd_launch(
         qf, kf, vf, H=H, Hkv=Hkv, causal=causal, interpret=interpret,
-        scale=scale, window=window,
+        scale=scale, window=window, stair=stair,
     )
     out = out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     # lse stays in its (B*H, 8, Sq) sublane-broadcast layout: the backward
@@ -446,10 +497,12 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, interpret: bool,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("H", "Hkv", "causal", "interpret", "scale", "window"),
+    static_argnames=("H", "Hkv", "causal", "interpret", "scale", "window",
+                     "stair"),
 )
 def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
-                scale: float | None = None, window: int | None = None):
+                scale: float | None = None, window: int | None = None,
+                stair: tuple[int, int] | None = None):
     """The forward's one ``pallas_call``, on flat (rows, S, D) operands.
 
     Jitted on its own so that a model's layers share one trace and one
@@ -463,8 +516,8 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     q_offset = Skv - Sq
-    plan = _fwd_plan(Sq, Skv, D, qf.dtype.itemsize, window)
-    counts = fwd_tile_counts(Sq, Skv, causal, q_offset, plan, window)
+    plan = _fwd_plan(Sq, Skv, D, qf.dtype.itemsize, window, stair)
+    counts = fwd_tile_counts(Sq, Skv, causal, q_offset, plan, window, stair)
     block_q, block_k, block_kv = plan
     per_fetch = block_kv // block_k
     kv_row = functools.partial(_gqa_kv_row, H=H, Hkv=Hkv)
@@ -477,12 +530,13 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
             start, _ = _kv_window(i, window=window, **geom)
             _, live = _kv_span(i, causal=True, n_k=Skv // block_k, **geom)
             jf = jnp.minimum(start // per_fetch + jf, (live - 1) // per_fetch)
-        elif causal and Skv > block_kv:
-            # a step above the diagonal names the block already in VMEM:
-            # no DMA is issued for K/V it will not read
+        elif (causal or stair is not None) and Skv > block_kv:
+            # a step above the diagonal (past the staircase's span) names
+            # the block already in VMEM: no DMA is issued for K/V it will
+            # not read
             _, live = _kv_span(
-                i, causal=True, block_q=block_q, block_k=block_k,
-                q_offset=q_offset, n_k=Skv // block_k,
+                i, causal=causal, block_q=block_q, block_k=block_k,
+                q_offset=q_offset, n_k=Skv // block_k, stair=stair,
             )
             jf = jnp.minimum(jf, (live - 1) // per_fetch)
         return (kv_row(b), jf, 0)
@@ -491,6 +545,7 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
         _flash_kernel,
         causal=causal, block_k=block_k, scale=scale, q_offset=q_offset,
         **({} if window is None else {"window": window}),
+        **({} if stair is None else {"stair": stair}),
     )
     from jax.experimental.pallas import tpu as pltpu
 
@@ -528,17 +583,36 @@ def _fwd_launch(qf, kf, vf, *, H: int, Hkv: int, causal: bool, interpret: bool,
     )(qf, kf, vf)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
-                    scale: float | None = None, window: int | None = None):
+                    scale: float | None = None, window: int | None = None,
+                    *, return_lse: bool = False,
+                    stair: tuple[int, int] | None = None):
     """Flash attention: q,k,v (B,S,H,D) -> (B,S,H,D), causal by default;
     ``scale`` multiplies the scores (None: 1/sqrt(D)); under a ``window``
-    a query sees its own key and the ``window - 1`` before it."""
+    a query sees its own key and the ``window - 1`` before it; under a
+    ``stair`` (q_step, k_step), with ``causal`` False, query i sees keys
+    ``[0, (i // q_step + 1) * k_step)``.  With ``return_lse`` the result
+    is ``(out, lse)``, ``lse`` (B, S, H) float32 the log of each row's
+    summed exponentials, differentiable like ``out`` — what a caller
+    needs to merge this softmax with another over other keys.  Without
+    it (and without a stair) the traced program is what it always was."""
+    if return_lse or stair is not None:
+        out, lse = _flash_lse(q, k, v, causal, interpret, scale, window, stair)
+        return (out, lse) if return_lse else out
+    return _flash_out(q, k, v, causal, interpret, scale, window)
+
+
+def _out_alone(q, k, v, causal, interpret, scale, window):
     out, _ = _flash_fwd_impl(
         q, k, v, causal=causal, interpret=interpret, scale=scale,
         window=window,
     )
     return out
+
+
+# the name this call has always carried in a jaxpr
+_out_alone.__name__ = _out_alone.__qualname__ = "flash_attention"
+_flash_out = jax.custom_vjp(_out_alone, nondiff_argnums=(3, 4, 5, 6))
 
 
 def _fwd(q, k, v, causal, interpret, scale, window):
@@ -547,6 +621,30 @@ def _fwd(q, k, v, causal, interpret, scale, window):
         window=window,
     )
     return out, (q, k, v, out, lse)
+
+
+def _rows_of(lse8, B: int, H: int):
+    """The kernels' (B*H, 8, Sq) sublane-broadcast statistic as (B, Sq, H)."""
+    return lse8[:, 0, :].reshape(B, H, -1).transpose(0, 2, 1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, causal, interpret, scale, window, stair):
+    return _fwd_lse(q, k, v, causal, interpret, scale, window, stair)[0]
+
+
+def _fwd_lse(q, k, v, causal, interpret, scale, window, stair):
+    out, lse8 = _flash_fwd_impl(
+        q, k, v, causal=causal, interpret=interpret, scale=scale,
+        window=window, stair=stair,
+    )
+    return (out, _rows_of(lse8, q.shape[0], q.shape[2])), (q, k, v, out, lse8)
+
+
+def _bwd_lse(causal, interpret, scale, window, stair, res, cotangents):
+    do, dlse = cotangents
+    return _bwd(causal, interpret, scale, window, res, do, dlse=dlse,
+                stair=stair)
 
 
 class BwdPlan(NamedTuple):
@@ -576,7 +674,8 @@ _UNROLL_TILES = 16
 
 
 def _bwd_plan(Sq: int, Skv: int, D: int, itemsize: int, group: int = 1,
-              window: int | None = None) -> BwdPlan:
+              window: int | None = None,
+              stair: tuple[int, int] | None = None) -> BwdPlan:
     """The backward's plan, chosen from what the operands show.
 
     Score tiles are ``_pick_block`` squares.  Without a window, a kernel's
@@ -587,7 +686,7 @@ def _bwd_plan(Sq: int, Skv: int, D: int, itemsize: int, group: int = 1,
     window, the grid walks a row's or a column's live tiles and a step
     past the last one names the block already in VMEM (no DMA).
     """
-    block_q, block_k = _pick_block(Sq), _pick_block(Skv)
+    block_q, block_k = _stair_blocks(Sq, Skv, stair)
     fits = lambda rows: rows * D * itemsize <= _KV_FETCH_BYTES  # noqa: E731
     whole = window is None and fits(Skv) and fits(Sq)
     tiles = (Sq // block_q) * (Skv // block_k) * group
@@ -596,13 +695,20 @@ def _bwd_plan(Sq: int, Skv: int, D: int, itemsize: int, group: int = 1,
 
 
 def _q_walk(j, *, causal: bool, block_q: int, block_k: int, q_offset: int,
-            window: int | None, n_q: int):
+            window: int | None, n_q: int,
+            stair: tuple[int, int] | None = None):
     """``(first, free, end)`` for kv block ``j``: q blocks ``[first, end)``
     are ``_block_live``; without a window those from ``free`` on are
     ``_block_unmasked`` and the ones before it crossed by the diagonal
     (under a window ``free`` is not used: the edge crosses tiles further
     down, and the grid tests each).  ``end <= first``: a column no query
-    sees.  ``_kv_span`` read by column; ``j`` static or traced."""
+    sees.  ``_kv_span`` read by column; ``j`` static or traced.  Under a
+    ``stair`` the column's keys belong to step ``j * block_k // k_step``,
+    which every q block from that step on sees whole."""
+    if stair is not None:
+        q_step, k_step = stair
+        first = (j * block_k // k_step) * (q_step // block_q)
+        return first, first, n_q
     if not causal:
         return 0, 0, n_q
     first, last = _q_span(j, block_q=block_q, block_k=block_k,
@@ -621,24 +727,26 @@ def _q_walk(j, *, causal: bool, block_q: int, block_k: int, q_offset: int,
 
 
 def _bwd_steps(Sq: int, Skv: int, causal: bool, q_offset: int, plan: BwdPlan,
-               window: int | None) -> tuple[int, int]:
+               window: int | None,
+               stair: tuple[int, int] | None = None) -> tuple[int, int]:
     """Inner grid extents ``(kv_steps, q_steps)`` of the two kernels where
     the grid walks: as many steps as the widest row or column has live
     tiles."""
     n_q, n_k = Sq // plan.block_q, Skv // plan.block_k
     geom = dict(block_q=plan.block_q, block_k=plan.block_k, q_offset=q_offset)
     kv_steps = max(
-        _kv_span(i, causal=causal, n_k=n_k, **geom)[1]
+        _kv_span(i, causal=causal, n_k=n_k, stair=stair, **geom)[1]
         - _kv_window(i, window=window, **geom)[0] for i in range(n_q)
     )
-    walks = [_q_walk(j, causal=causal, window=window, n_q=n_q, **geom)
-             for j in range(n_k)]
+    walks = [_q_walk(j, causal=causal, window=window, n_q=n_q, stair=stair,
+                     **geom) for j in range(n_k)]
     return kv_steps, max(1, max(end - first for first, _, end in walks))
 
 
 def bwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
                     plan: BwdPlan, window: int | None = None,
-                    group: int = 1) -> BwdTileCounts:
+                    group: int = 1,
+                    stair: tuple[int, int] | None = None) -> BwdTileCounts:
     """How often each path of the two backward kernels engages — static,
     like ``fwd_tile_counts``: tiles run without a mask / with one / never
     entered, and the grid steps launched."""
@@ -646,8 +754,9 @@ def bwd_tile_counts(Sq: int, Skv: int, causal: bool, q_offset: int,
     # the tiles are the forward's at the same squares, whatever it fetches
     tiles = fwd_tile_counts(
         Sq, Skv, causal, q_offset,
-        FwdPlan(plan.block_q, plan.block_k, plan.block_k), window)[:3]
-    kv_steps, q_steps = _bwd_steps(Sq, Skv, causal, q_offset, plan, window)
+        FwdPlan(plan.block_q, plan.block_k, plan.block_k), window, stair)[:3]
+    kv_steps, q_steps = _bwd_steps(Sq, Skv, causal, q_offset, plan, window,
+                                   stair)
     return BwdTileCounts(
         TileCounts(*tiles, 1 if plan.dq_whole else n_q * kv_steps),
         TileCounts(*(group * t for t in tiles),
@@ -722,6 +831,7 @@ def _bwd_dq_kernel(
     dq_acc,                       # VMEM (BQ, D) f32
     *, causal: bool, block_q: int, block_k: int, scale: float, q_offset: int,
     n_q: int, n_k: int, whole: bool, unrolled: bool, window: int | None = None,
+    stair: tuple[int, int] | None = None,
 ):
     """Every q block of a (batch*head) row against its live kv tiles, kv
     ascending.  ``whole``: grid (B*H,), the row's operands in VMEM, and
@@ -756,7 +866,7 @@ def _bwd_dq_kernel(
 
     def row(i):
         qs = _tile_rows(i, block_q)
-        full, live = _kv_span(i, causal=causal, n_k=n_k, **geom)
+        full, live = _kv_span(i, causal=causal, n_k=n_k, stair=stair, **geom)
         _open()
         _walk(0, full, lambda j: tile(
             i, j, qs, _tile_rows(j, block_k), masked=False), unrolled)
@@ -778,6 +888,9 @@ def _bwd_dq_kernel(
         free = (lo <= j) & (j < full)
         pl.when(free)(lambda: tile(i, j, every, every, masked=False))
         pl.when((j < live) & ~free)(lambda: tile(i, j, every, every, masked=True))
+    elif stair is not None:
+        _, live = _kv_span(i, causal=False, n_k=n_k, stair=stair, **geom)
+        pl.when(y < live)(lambda: tile(i, y, every, every, masked=False))
     else:
         tile(i, y, every, every, masked=False)
     pl.when(y == pl.num_programs(2) - 1)(lambda: _finish(every))
@@ -790,7 +903,7 @@ def _bwd_dkv_kernel(
     dk_acc, dv_acc,               # VMEM (BK, D) f32
     *, causal: bool, block_q: int, block_k: int, scale: float, q_offset: int,
     group: int, n_q: int, n_k: int, whole: bool, unrolled: bool,
-    window: int | None = None,
+    window: int | None = None, stair: tuple[int, int] | None = None,
 ):
     """Every kv block of one KV HEAD against its live (q block, group
     member) pairs — q block ascending, member inside — so GQA's shared kv
@@ -832,7 +945,8 @@ def _bwd_dkv_kernel(
 
     def column(j):
         ks = _tile_rows(j, block_k)
-        first, free, end = _q_walk(j, causal=causal, window=None, n_q=n_q, **geom)
+        first, free, end = _q_walk(j, causal=causal, window=None, n_q=n_q,
+                                   stair=stair, **geom)
 
         def pair(t, *, masked: bool):
             i, g = (t // group, t % group) if group > 1 else (t, 0)
@@ -859,19 +973,26 @@ def _bwd_dkv_kernel(
             lambda: tile(i, j, 0, every, every, masked=False))
         pl.when((i < end) & ~free)(
             lambda: tile(i, j, 0, every, every, masked=True))
+    elif stair is not None:
+        first, _, end = _q_walk(j, causal=False, window=None, n_q=n_q,
+                                stair=stair, **geom)
+        i = first + t // group
+        pl.when(i < end)(lambda: tile(i, j, 0, every, every, masked=False))
     else:
         tile(t // group, j, 0, every, every, masked=False)
     pl.when(t == pl.num_programs(2) - 1)(lambda: _finish(every))
 
 
-def _bwd(causal, interpret, scale, window, res, do):
+def _bwd(causal, interpret, scale, window, res, do, *, dlse=None, stair=None):
     """Blockwise flash backward: two Pallas kernels, O(S) peak memory.
 
     Probability tiles are recomputed per (q block, kv block) pair from the
     saved lse — the (S, S) matrix never exists.  dq runs with kv blocks
     innermost (accumulating dq_i in VMEM); dk/dv run with q blocks
     innermost (accumulating dk_j/dv_j).  ``delta = rowsum(do * out)`` is a
-    cheap O(S·D) XLA reduction done once up front.
+    cheap O(S·D) XLA reduction done once up front.  ``dlse`` (B, Sq, H) is
+    the cotangent of the row statistic where it was handed out: ``d s =
+    p * (dp - delta) + p * dlse``, so it is taken off ``delta``.
     """
     q, k, v, out, lse = res
     B, Sq, H, D = q.shape
@@ -889,13 +1010,16 @@ def _bwd(causal, interpret, scale, window, res, do):
     delta = jnp.sum(
         dof.astype(jnp.float32) * outf.astype(jnp.float32), axis=-1
     )  # (B*H, Sq)
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32).transpose(0, 2, 1).reshape(
+            B * H, Sq)
     # Row vectors enter the kernels broadcast over 8 sublanes (the TPU
     # (8, 128) tiling minimum).  lse arrives from the forward already in
     # that layout; only delta needs the broadcast.
     delta8 = jnp.broadcast_to(delta[:, None, :], (B * H, 8, Sq))
     dq, dk, dv = _bwd_launch(
         qf, kf, vf, dof, lse, delta8, H=H, Hkv=Hkv, causal=causal,
-        interpret=interpret, scale=scale, window=window,
+        interpret=interpret, scale=scale, window=window, stair=stair,
     )
     dq = dq.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     dk = dk.reshape(B, Hkv, Skv, D).transpose(0, 2, 1, 3)
@@ -905,11 +1029,13 @@ def _bwd(causal, interpret, scale, window, res, do):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("H", "Hkv", "causal", "interpret", "scale", "window"),
+    static_argnames=("H", "Hkv", "causal", "interpret", "scale", "window",
+                     "stair"),
 )
 def _bwd_launch(qf, kf, vf, dof, lse8, delta8, *, H: int, Hkv: int,
                 causal: bool, interpret: bool, scale: float | None = None,
-                window: int | None = None):
+                window: int | None = None,
+                stair: tuple[int, int] | None = None):
     """The backward's two ``pallas_call``s, on flat (rows, S, D) operands
     and (rows, 8, Sq) statistics; jitted on its own for ``_fwd_launch``'s
     reason (a model's layers share one trace and lowering of each kernel).
@@ -930,13 +1056,15 @@ def _bwd_launch(qf, kf, vf, dof, lse8, delta8, *, H: int, Hkv: int,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     q_offset = Skv - Sq
-    plan = _bwd_plan(Sq, Skv, D, qf.dtype.itemsize, group, window)
+    plan = _bwd_plan(Sq, Skv, D, qf.dtype.itemsize, group, window, stair)
     block_q, block_k = plan.block_q, plan.block_k
     n_q, n_k = Sq // block_q, Skv // block_k
-    kv_steps, q_steps = _bwd_steps(Sq, Skv, causal, q_offset, plan, window)
+    kv_steps, q_steps = _bwd_steps(Sq, Skv, causal, q_offset, plan, window,
+                                   stair)
     geom = dict(block_q=block_q, block_k=block_k, q_offset=q_offset)
     kw = dict(causal=causal, scale=scale, n_q=n_q, n_k=n_k, window=window,
-              unrolled=plan.unrolled, **geom)
+              unrolled=plan.unrolled, **geom,
+              **({} if stair is None else {"stair": stair}))
     kv_row = functools.partial(_gqa_kv_row, H=H, Hkv=Hkv)
 
     if plan.dq_whole:
@@ -949,7 +1077,7 @@ def _bwd_launch(qf, kf, vf, dof, lse8, delta8, *, H: int, Hkv: int,
         # last one, that one again (no DMA)
         def kv_blk(x, y):
             start, _ = _kv_window(x, window=window, **geom)
-            _, live = _kv_span(x, causal=causal, n_k=n_k, **geom)
+            _, live = _kv_span(x, causal=causal, n_k=n_k, stair=stair, **geom)
             return jnp.minimum(start + y, live - 1)
 
         grid = (rows, n_q, kv_steps)
@@ -980,7 +1108,7 @@ def _bwd_launch(qf, kf, vf, dof, lse8, delta8, *, H: int, Hkv: int,
         # order and, past the last pair, that one again (no DMA)
         def q_at(b, y, t):
             first, _, end = _q_walk(
-                y, causal=causal, window=window, n_q=n_q, **geom)
+                y, causal=causal, window=window, n_q=n_q, stair=stair, **geom)
             t = jnp.clip(t, 0, jnp.maximum((end - first) * group - 1, 0))
             return b * group + t % group, first + t // group
 
@@ -1016,4 +1144,5 @@ def _bwd_launch(qf, kf, vf, dof, lse8, delta8, *, H: int, Hkv: int,
     return dq, dk, dv
 
 
-flash_attention.defvjp(_fwd, _bwd)
+_flash_out.defvjp(_fwd, _bwd)
+_flash_lse.defvjp(_fwd_lse, _bwd_lse)

@@ -10,7 +10,9 @@ import numpy as np
 import pytest
 
 from distributeddataparallel_tpu.ops.attention import (
+    NEG_INF,
     apply_rope,
+    attention,
     causal_mask_bias,
     dot_product_attention,
     repeat_kv,
@@ -661,6 +663,136 @@ def test_bwd_tile_counts_cover_the_grid_and_agree_with_predicates():
                 assert c.dkv.steps == n_k * max(live.sum(0).max(), 1) * group
 
 
+# --- the row statistic handed out, and the staircase (PR 36) --------------
+
+@pytest.mark.parametrize("B,Sq,Skv,H,Hkv,D,causal,window", [
+    (1, 256, 256, 2, 2, 16, True, None),     # a row whole, unrolled
+    (1, 128, 256, 4, 2, 16, True, None),     # decode alignment, GQA
+    (1, 256, 256, 2, 1, 16, True, 128),      # a window: the clamped grid
+], ids=["square", "gqa-decode", "window"])
+def test_flash_lse_and_its_cotangent_match_the_xla_path(
+        B, Sq, Skv, H, Hkv, D, causal, window):
+    """``return_lse``: ``(out, lse)`` and the gradients of a loss that
+    reads both, against ``jax.grad`` of the ``xla`` attention (whose lse is
+    a logsumexp JAX differentiates by itself)."""
+    ks = jax.random.split(jax.random.PRNGKey(36), 5)
+    q = jax.random.normal(ks[0], (B, Sq, H, D))
+    k, v = (jax.random.normal(ks[1 + i], (B, Skv, Hkv, D)) for i in range(2))
+    do = jax.random.normal(ks[3], (B, Sq, H, D))
+    dl = jax.random.normal(ks[4], (B, Sq, H))
+
+    def through(f):
+        def loss(q, k, v):
+            out, lse = f(q, k, v)
+            return jnp.sum(out * do) + jnp.sum(lse * dl), (out, lse)
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+    flash = through(lambda q, k, v: pallas_attention.flash_attention(
+        q, k, v, causal, True, None, window, return_lse=True))
+    rep = H // Hkv
+    plain = through(lambda q, k, v: dot_product_attention(
+        q, repeat_kv(k, rep), repeat_kv(v, rep), causal=causal,
+        window=window, return_lse=True))
+    with jax.default_matmul_precision("highest"):
+        got, (out, lse) = flash(q, k, v)
+        want, (ref_out, ref_lse) = plain(q, k, v)
+    assert lse.shape == (B, Sq, H) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(out, ref_out, atol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, atol=2e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+    # and through the dispatcher, on the path a CPU takes
+    out2, lse2 = attention(q, k, v, causal=causal, impl="xla", window=window,
+                           return_lse=True)
+    np.testing.assert_allclose(out2, ref_out, atol=1e-6)
+    np.testing.assert_allclose(lse2, ref_lse, atol=1e-6)
+
+
+def _equations(jaxpr, acc):
+    for eqn in jaxpr.eqns:
+        acc[eqn.primitive.name] += 1
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _equations(inner, acc)
+    return acc
+
+
+@pytest.mark.parametrize("B,S,Skv,H,Hkv,D,causal,window,total,subs,exps", [
+    (1, 256, 256, 2, 2, 16, True, None, 318, 17, 8),
+    (1, 512, 512, 2, 1, 16, True, 128, 677, 57, 12),
+    (1, 128, 256, 2, 2, 16, False, None, 188, 9, 5),
+], ids=["square", "window", "non-causal"])
+def test_flash_without_lse_traces_the_parents_program(
+        B, S, Skv, H, Hkv, D, causal, window, total, subs, exps):
+    """Without ``return_lse`` and without a staircase, forward and backward
+    trace to what PR 36's parent traced: the counts of all equations, of
+    subtractions (``delta - d lse`` would be one more) and of exponentials,
+    taken on the parent commit (its whole jaxpr text was compared then,
+    equal to the last character outside source lines)."""
+    import collections
+
+    q = jnp.zeros((B, S, H, D), jnp.bfloat16)
+    k = jnp.zeros((B, Skv, Hkv, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return pallas_attention.flash_attention(
+            q, k, v, causal, True, None, window).astype(jnp.float32).sum()
+
+    acc = _equations(jax.make_jaxpr(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, k).jaxpr,
+        collections.Counter())
+    assert (sum(acc.values()), acc["sub"], acc["exp"]) == (total, subs, exps)
+    assert acc["pallas_call"] == 3
+    forward = str(jax.make_jaxpr(loss)(q, k, k))
+    assert "name=flash_attention" in forward  # the call's name, as ever
+
+
+@pytest.mark.parametrize("Sq,Skv,stair,path", [
+    (768, 384, (256, 128), "grid"),
+    (512, 256, (256, 128), "whole"),
+    (512, 256, (256, 128), "loops"),
+], ids=["clamped-grid", "row-whole-unrolled", "row-whole-loops"])
+def test_flash_kernels_under_a_staircase_match_the_mask(
+        Sq, Skv, stair, path):
+    """The staircase rule — query i sees keys ``[0, (i // q_step + 1) *
+    k_step)`` — through all three kernels on each of the backward plan's
+    paths, against the mask written from positions; and Sq > Skv, which
+    the causal kernels refuse, is this rule's usual shape."""
+    ks = jax.random.split(jax.random.PRNGKey(37), 5)
+    q = jax.random.normal(ks[0], (2, Sq, 2, 16))
+    k, v = (jax.random.normal(ks[1 + i], (2, Skv, 2, 16)) for i in range(2))
+    do = jax.random.normal(ks[3], (2, Sq, 2, 16))
+    dl = jax.random.normal(ks[4], (2, Sq, 2))
+    seen = (jnp.arange(Skv)[None, :]
+            < (jnp.arange(Sq)[:, None] // stair[0] + 1) * stair[1])
+    bias = jnp.where(seen, 0.0, NEG_INF)[None, None]
+
+    def through(f):
+        def loss(q, k, v):
+            out, lse = f(q, k, v)
+            return jnp.sum(out * do) + jnp.sum(lse * dl), (out, lse)
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+    plan = pallas_attention._bwd_plan(Sq, Skv, 16, 4, 1, None, stair)
+    with jax.default_matmul_precision("highest"), _backward_path(path):
+        assert (plan.dq_whole, plan.unrolled) == (True, True)  # unsteered
+        got, (out, lse) = through(
+            lambda q, k, v: pallas_attention.flash_attention(
+                q, k, v, False, True, None, None, return_lse=True,
+                stair=stair))(q, k, v)
+        want, (ref_out, ref_lse) = through(
+            lambda q, k, v: dot_product_attention(
+                q, k, v, causal=False, bias=bias, return_lse=True))(q, k, v)
+    np.testing.assert_allclose(out, ref_out, atol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, atol=2e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+    with pytest.raises(ValueError, match="rule of its own"):
+        pallas_attention.flash_attention(q, k, v, True, True, stair=stair)
+
+
 # --- the forward kernel through the chip's own compiler (no chip) --------
 # Interpret mode cannot see what Mosaic refuses (a misaligned slice, too
 # much VMEM, a layout it cannot make); an AOT compile for a described v5e
@@ -865,6 +997,36 @@ def test_grouped_kernels_compile_for_v5e(v5e_chip, no_compile_cache, m, k, n):
     assert "moe_gmm" in text and "moe_tgmm" in text
 
 
+def test_eva_attention_compiles_for_v5e(v5e_chip, no_compile_cache):
+    """``ops.eva.eva_attention`` forward and backward at the cell's shape,
+    (1, 16384, 32, 128) bf16 in windows of 2,048 and chunks of 16, through
+    the chip's own compiler: three kernels on eight folded rows of 2,048
+    under ``eva_local`` (MHA at D 128, a row whole and unrolled) and three
+    under ``eva_remote`` on the staircase (14,336 queries, 896 summaries,
+    (512, 128) tiles), each under its scope and phase."""
+    from distributeddataparallel_tpu.ops import eva
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+
+    def both(q, k, v, phi, mu, do):
+        out, vjp = jax.vjp(lambda *a: eva.eva_attention(
+            *a, window=2048, chunk=16, impl="pallas"), q, k, v, phi, mu)
+        return out, vjp(do)
+
+    x, p = sds((1, 16384, 32, 128)), sds((32, 128), jnp.float32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(both).lower(x, x, x, p, p, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 6
+    calls = [scope for op, _, scope in _instructions(text).values()
+             if op == "custom-call" and "/pallas_call" in scope]
+    for part in ("eva_local", "eva_remote"):
+        assert sorted(
+            (s.split("/")[-2], "transpose(" in s) for s in calls if part in s
+        ) == [("flash_bwd_dkv", True), ("flash_bwd_dq", True),
+              ("flash_fwd", False)], (part, calls)
+
+
 def _instructions(text):
     """``{name: (op, [operand names], op_name)}`` of a compiled module's
     text."""
@@ -1009,3 +1171,27 @@ def test_the_trinity_step_holds_its_kernels_and_mosaic_takes_them(
     assert sum("transpose(" in s for s in gmm) == 6 + 9
     assert all("transpose(" in s for s in tgmm)
     assert "ragged-dot" not in text
+
+
+def test_the_evabyte_step_holds_its_kernels_and_mosaic_takes_them(
+    v5e_chip, no_compile_cache
+):
+    """The EvaByte cell's step at its published widths and its own length,
+    cut to one layer (``benchmarks/aot_fit_eva.py`` compiles all four, ~1
+    min, 32 custom calls): the chip's own compiler takes the folded local
+    kernels and the staircase, each under the scope its reader looks for.
+    A layer launches ``flash_fwd`` twice in each part (remat) and the two
+    backward kernels once."""
+    from benchmarks import aot_fit_eva, eva_scopes, harness
+
+    text = _step_text(
+        v5e_chip, harness.load_cell("evabyte.train-s16384"),
+        aot_fit_eva.fit, num_layers=1,
+    ).as_text()
+    scopes_of = [scope for op, _, scope in _instructions(text).values()
+                 if op == "custom-call" and "/pallas_call" in scope]
+    assert text.count("tpu_custom_call") == 8 == len(scopes_of)
+    parts = [eva_scopes.part_of(s) for s in scopes_of]
+    assert parts.count("eva_local.kernels") == 4
+    assert parts.count("eva_remote") == 4
+    assert sum("transpose(" in s for s in scopes_of) == 2 + 4

@@ -328,6 +328,120 @@ def test_moe_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
         assert found[name, "fwd"] and found[name, "bwd"], (name, found)
 
 
+def _tiny_evabyte_step_text(seq=64, **overrides):
+    """The compiled train step of a two-layer evabyte stack (windows of
+    16 in chunks of 4, eight heads' loss) under remat, as HLO text."""
+    from distributeddataparallel_tpu.models.transformer import evabyte
+    from distributeddataparallel_tpu.ops import multi_token_cross_entropy
+
+    cfg = evabyte(**{**dict(
+        num_layers=2, d_model=32, num_heads=2, d_ff=64, max_seq_len=seq,
+        sliding_window=16, eva_chunk=4, attn_impl="xla", remat=True,
+    ), **overrides})
+    model = TransformerLM(cfg)
+    mesh = ddp.make_mesh(("data",), devices=jax.devices()[:1])
+
+    def loss_fn(params, batch, rng):
+        ids = batch["tokens"]
+        logits = model.apply({"params": params}, ids[:, :-1])
+        return multi_token_cross_entropy(logits, ids), {}
+
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    state = ddp.broadcast_params(
+        ddp.TrainState.create(
+            apply_fn=model.apply, params=params, tx=optax.adamw(1e-3)
+        ),
+        mesh,
+    )
+    batch = shard_batch({"tokens": jnp.zeros((1, seq + 1), jnp.int32)}, mesh)
+    return ddp.make_train_step(loss_fn, mesh=mesh).lower(
+        state, batch, jax.random.PRNGKey(0)
+    ).compile().as_text()
+
+
+def test_eva_scopes_fall_in_one_row_and_reach_the_compiled_step(devices):
+    """Chunk-summarised attention's four scopes (PR 36) are a tuple of
+    their own, read by ``benchmarks/eva_scopes.py``'s table and not by
+    ``scope_reduce.BUCKETS``, which counts them under ``attn`` and the
+    flash kernels under their own names wherever they run; a compiled
+    evabyte step carries each of them, forward and backward, and the
+    projections none."""
+    from benchmarks import eva_scopes
+
+    assert scopes.EVA_SCOPES == (
+        "eva_local", "eva_summaries", "eva_remote", "eva_merge")
+    assert not set(scopes.EVA_SCOPES) & set(
+        scopes.STEP_SCOPES + scopes.MIXER_SCOPES + scopes.MOE_SCOPES)
+    # the local part's kernels are a row of their own, before their scope's
+    assert [name for name, _ in eva_scopes.PARTS] == [
+        "eva_local.kernels", *scopes.EVA_SCOPES]
+    for name in scopes.EVA_SCOPES:
+        for path in (f"jit(step)/jvp(M)/layer_1/attn/{name}/add",
+                     f"jit(s)/transpose(jvp(M))/layer_3/attn/{name}/mul"):
+            assert eva_scopes.part_of(path) == name
+            assert scope_reduce.bucket_of(path) == "attn"
+    for kernel, bucket in zip(scopes.KERNEL_NAMES,
+                              scope_reduce.KERNEL_BUCKETS):
+        local = (f"jit(s)/jvp(M)/layer_0/attn/eva_local/jit(_fwd_launch)/"
+                 f"{kernel}/pallas_call")
+        assert eva_scopes.part_of(local) == "eva_local.kernels"
+        assert eva_scopes.part_of(
+            local.replace("eva_local", "eva_remote")) == "eva_remote"
+        assert scope_reduce.bucket_of(local) == bucket
+    assert eva_scopes.part_of("jit(s)/jvp(M)/layer_0/attn/q_proj/dot") is None
+    assert eva_scopes.part_of("jit(s)/jvp(M)/layer_1/attn_norm/mul") is None
+
+    found = collections.Counter()
+    projections = 0
+    for scope in _OP_NAME.findall(_tiny_evabyte_step_text()):
+        part = eva_scopes.part_of(scope)
+        if part is not None:
+            assert "/attn/" in scope, scope
+            found[part, scope_reduce.phase_of(scope, "")] += 1
+        elif "/attn/q_proj/" in scope:
+            projections += 1
+    assert projections
+    for name in scopes.EVA_SCOPES:
+        assert found[name, "fwd"] and found[name, "bwd"], (name, found)
+
+
+def test_eva_kernels_carry_their_parts_scope_in_both_phases(devices):
+    """On the kernel path (forced through the interpreter) the three flash
+    kernels run under ``eva_local`` and again under ``eva_remote``:
+    ``flash_fwd`` forward and, under remat, once more in the backward
+    pass; the two backward kernels under ``transpose(`` alone."""
+    from unittest import mock
+
+    from benchmarks import eva_scopes
+    from distributeddataparallel_tpu.ops import pallas_attention
+
+    flash = pallas_attention.flash_attention
+
+    def interpreted(q, k, v, causal=True, interpret=False, *args, **kw):
+        return flash(q, k, v, causal, True, *args, **kw)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.object(pallas_attention, "flash_attention",
+                              interpreted):
+        text = _tiny_evabyte_step_text(
+            seq=512, sliding_window=256, eva_chunk=2, attn_impl="auto",
+            num_layers=1, head_dim=16)
+    found = collections.Counter()
+    for scope in _OP_NAME.findall(text):
+        for name in scopes.KERNEL_NAMES:
+            if f"/{name}/" in scope:
+                found[eva_scopes.part_of(scope), name,
+                      scope_reduce.phase_of(scope, "")] += 1
+    fwd, dq, dkv = scopes.KERNEL_NAMES
+    for part in ("eva_local.kernels", "eva_remote"):
+        assert found[part, fwd, "fwd"] and found[part, fwd, "bwd"], found
+        for name in (dq, dkv):
+            assert found[part, name, "bwd"], found
+            assert not found[part, name, "fwd"], found
+
+
 def test_flash_kernels_carry_attn_scope_and_phase_in_a_compiled_step(devices):
     """The backward kernels are launched from a jitted ``_bwd_launch`` (PR
     35) as the forward is from ``_fwd_launch`` (PR 26), inside a
@@ -348,8 +462,8 @@ def test_flash_kernels_carry_attn_scope_and_phase_in_a_compiled_step(devices):
                            lambda q, k, v: q.shape[1] % 128 == 0), \
             mock.patch.object(
                 pallas_attention, "flash_attention",
-                lambda q, k, v, causal, interpret, scale, window: flash(
-                    q, k, v, causal, True, scale, window)):
+                lambda q, k, v, causal, interpret, scale, window, **kw: flash(
+                    q, k, v, causal, True, scale, window, **kw)):
         text = _compiled_step_text(1, False, attn_impl="auto", seq=128)
     buckets = dict(zip(scopes.KERNEL_NAMES, scope_reduce.KERNEL_BUCKETS))
     found = collections.Counter()
