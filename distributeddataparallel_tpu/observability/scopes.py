@@ -7,7 +7,8 @@ and, through it, the device trace.
 What Flax names itself is relied on, not re-wrapped: ``layer_<i>`` (or
 ``layers/block`` under ``scan_layers``), ``attn``, ``mlp`` and the norms.
 Forward and backward need no scope either: JAX writes ``jvp(`` and
-``transpose(jvp(`` into ``op_name``.
+``transpose(jvp(`` into ``op_name``.  Nor does the forward that remat
+runs a second time inside the backward: JAX writes ``RECOMPUTE`` there.
 
 Module-import rule: stdlib only (see schema.py).
 """
@@ -81,6 +82,21 @@ EVA_LOCAL = "eva_local"
 EVA_SUMMARIES = "eva_summaries"
 EVA_REMOTE = "eva_remote"
 EVA_MERGE = "eva_merge"
+
+#: JAX's own name, relied on and never written here (as ``jvp(`` and
+#: ``transpose(jvp(`` are): it marks every operation that ``nn.remat``
+#: (``models/transformer.py``, unrolled blocks and the scanned body) or an
+#: explicit ``jax.checkpoint`` (the worst-case branch of
+#: ``ops/moe.dropless``) runs a second time inside the backward —
+#: ``transpose(jvp(M))/jvp(M)/checkpoint/rematted_computation/layer_0/mlp/...``.
+#: The first forward and the true backward never carry it.  The program's
+#: scopes and the kernels' names nest under it, so the recompute splits by
+#: the same names as the rest of the step.  A fusion counts under its
+#: root's ``op_name``: a recomputed elementwise operation that XLA fuses
+#: into a backward matmul counts as backward, and the reading misses it
+#: (backward work fused under a recomputed root counts the other way).
+#: Read by the benchmark's ``remat_scopes``.
+RECOMPUTE = "rematted_computation"
 
 STEP_SCOPES = (EMBED, HEAD, LOSS, METRICS, GRAD_SYNC, GRAD_CLIP, OPTIMIZER)
 KERNEL_NAMES = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)

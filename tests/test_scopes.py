@@ -6,6 +6,7 @@ profiler's trace as ``ddp:<name>`` and nest per thread, the loader spans
 where the work happens, and ``dpp.py --profile-steps`` captures them."""
 
 import collections
+import functools
 import os
 import re
 import sys
@@ -47,10 +48,12 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 def _compiled_step_text(n_devices: int, scan_layers: bool,
-                        attn_impl: str = "xla", seq: int = 16) -> str:
+                        attn_impl: str = "xla", seq: int = 16,
+                        remat: bool = False) -> str:
     cfg = gpt2_124m(
         vocab_size=128, d_model=32, num_layers=2, num_heads=2, d_ff=64,
         max_seq_len=seq, scan_layers=scan_layers, attn_impl=attn_impl,
+        remat=remat,
     )
     model = TransformerLM(cfg)
     mesh = ddp.make_mesh(("data",), devices=jax.devices()[:n_devices])
@@ -143,19 +146,19 @@ def test_every_scope_constant_falls_in_exactly_one_bucket():
     assert scope_reduce.phase_of("a/optimizer/x", "optimizer") == "update"
 
 
-def _tiny_hybrid_step_text():
+def _tiny_hybrid_step_text(**overrides):
     """The compiled train step of a two-layer hybrid (``mamba``,
     ``attention``) under remat, as HLO text."""
     from distributeddataparallel_tpu.models.transformer import (
         granite_4_0_h_micro,
     )
 
-    cfg = granite_4_0_h_micro(
+    cfg = granite_4_0_h_micro(**{**dict(
         vocab_size=128, num_layers=2, layer_types=("mamba", "attention"),
         d_model=32, num_heads=2, num_kv_heads=1, head_dim=16, d_ff=64,
         max_seq_len=16, ssm_heads=4, ssm_head_dim=16, ssm_state=16,
         ssm_chunk=8, attn_impl="xla", remat=True,
-    )
+    ), **overrides})
     model = TransformerLM(cfg)
     mesh = ddp.make_mesh(("data",), devices=jax.devices()[:1])
 
@@ -250,20 +253,20 @@ def test_mixer_kernels_carry_their_parts_scope_in_both_phases(
     assert found[bwd, "bwd"] and not found[bwd, "fwd"], found
 
 
-def _tiny_afmoe_step_text():
+def _tiny_afmoe_step_text(**overrides):
     """The compiled train step of a three-layer afmoe stack (a dense
     sliding layer, a sliding and a full layer with experts, a share of 4
     of 8 held) under remat, as HLO text."""
     from distributeddataparallel_tpu.models.transformer import trinity_mini
 
-    cfg = trinity_mini(
+    cfg = trinity_mini(**{**dict(
         vocab_size=128, num_layers=3, num_dense_layers=1,
         layer_types=("sliding_attention", "sliding_attention",
                      "full_attention"),
         d_model=32, num_heads=2, num_kv_heads=1, head_dim=16, d_ff=64,
         moe_d_ff=32, max_seq_len=16, sliding_window=4, moe_experts=8,
         moe_top_k=2, moe_experts_held=(0, 4), attn_impl="xla", remat=True,
-    )
+    ), **overrides})
     model = TransformerLM(cfg)
     mesh = ddp.make_mesh(("data",), devices=jax.devices()[:1])
 
@@ -496,6 +499,229 @@ def test_three_pallas_calls_have_three_names():
         scopes.KERNEL_NAMES
     ), kernels
     assert text.count("pallas_call[") == 3
+
+
+# ------------------------------------------ remat's second forward, by name
+
+#: the remat cells' models at a tiny size, and the scopes under which
+#: some recomputed operation must lie.  Of them only GPT-2 scans its
+#: layers (``scan_layers`` runs attention layers only)
+_REMAT_MODELS = {
+    "hybrid": ("attn", "mlp", "mamba") + scopes.MIXER_SCOPES,
+    "afmoe": ("attn", "mlp") + scopes.MOE_SCOPES,
+    "evabyte": ("attn", "mlp") + scopes.EVA_SCOPES,
+    "gpt2.unrolled": ("attn", "mlp", r"layer_\d+"),
+    "gpt2.scan_layers": ("attn", "mlp", "layers"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _remat_step_text(model: str, remat: bool) -> str:
+    if model == "hybrid":
+        return _tiny_hybrid_step_text(remat=remat)
+    if model == "afmoe":
+        return _tiny_afmoe_step_text(remat=remat)
+    if model == "evabyte":
+        return _tiny_evabyte_step_text(remat=remat)
+    return _compiled_step_text(1, model.endswith("scan_layers"), remat=remat)
+
+
+@pytest.mark.parametrize("model", list(_REMAT_MODELS))
+def test_remat_second_forward_carries_the_word_under_every_scope(
+    devices, model
+):
+    """JAX writes ``scopes.RECOMPUTE`` into every operation that remat runs
+    a second time, inside ``transpose(`` (so the accepted phase split
+    counts it as backward), and the model's own scopes nest under it."""
+    from benchmarks import remat_scopes
+
+    # a reduction's own adder runs as no operation of its own and carries
+    # the name from ``checkpoint/`` on: the step's operations start at jit(
+    recomputed = [scope for scope in _OP_NAME.findall(
+        _remat_step_text(model, True))
+        if scopes.RECOMPUTE in scope and scope.startswith("jit(")]
+    assert recomputed
+    for scope in recomputed:
+        assert remat_scopes._RECOMPUTE.search(scope), scope
+        assert scope.index("transpose(") < scope.index(scopes.RECOMPUTE)
+        assert scope_reduce.phase_of(scope, "") == "bwd"
+    for name in _REMAT_MODELS[model]:
+        under = re.compile(scope_reduce._under(name))
+        found = [s for s in recomputed if under.search(s)]
+        assert found, (model, name)
+        assert all(s.index(scopes.RECOMPUTE) < under.search(s).start()
+                   for s in found), (model, name)
+
+
+@pytest.mark.parametrize("model", list(_REMAT_MODELS))
+def test_no_operation_carries_the_word_without_remat(devices, model):
+    text = _remat_step_text(model, False)
+    assert "transpose(jvp(" in text
+    assert scopes.RECOMPUTE not in text
+
+
+def _interpreted_kernels_text(kernel: str) -> str:
+    """A tiny remat step whose ``kernel`` runs as the chip would run it,
+    its Pallas body through the interpreter."""
+    from unittest import mock
+
+    from distributeddataparallel_tpu.ops import (
+        causal_conv,
+        grouped_matmul,
+        pallas_attention,
+        ssd,
+    )
+
+    if kernel in (scopes.SSD_FWD, scopes.CONV_FWD):
+        with mock.patch.object(ssd, "ssd_chunked", functools.partial(
+                ssd.ssd_chunked, _interpret=True)), \
+                mock.patch.object(causal_conv, "causal_conv_silu",
+                                  functools.partial(causal_conv.causal_conv_silu,
+                                                    _interpret=True)):
+            return _tiny_hybrid_step_text()
+    if kernel == scopes.MOE_GMM:
+        # the kernels' row tile wants both weight axes a multiple of 128
+        with mock.patch.object(grouped_matmul, "supported", lambda r, w: True), \
+                mock.patch.object(grouped_matmul, "grouped_matmul",
+                                  functools.partial(grouped_matmul.grouped_matmul,
+                                                    _interpret=True)):
+            return _tiny_afmoe_step_text(d_model=128, moe_d_ff=128)
+    flash = pallas_attention.flash_attention
+
+    def interpreted(q, k, v, causal=True, interpret=False, *args, **kw):
+        return flash(q, k, v, causal, True, *args, **kw)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.object(pallas_attention, "flash_attention", interpreted):
+        return _tiny_evabyte_step_text(
+            seq=512, sliding_window=256, eva_chunk=2, attn_impl="auto",
+            num_layers=1, head_dim=16)
+
+
+@pytest.mark.parametrize("kernel", [
+    scopes.SSD_FWD, scopes.FLASH_FWD, scopes.MOE_GMM,
+])
+def test_a_kernels_second_forward_launch_carries_the_word(devices, kernel):
+    """The forward kernels' launches inside remat's second forward carry
+    the word and their first launches do not; no backward kernel carries
+    it.  ``ssd_fwd`` and ``conv_fwd`` share one step (the hybrid's)."""
+    from benchmarks import remat_scopes
+
+    text = _interpreted_kernels_text(kernel)
+    forward = (scopes.SSD_FWD, scopes.CONV_FWD) if kernel == scopes.SSD_FWD \
+        else (kernel,)
+    backward = {scopes.SSD_FWD: scopes.SSD_KERNEL_NAMES[1:]
+                + scopes.CONV_KERNEL_NAMES[1:],
+                scopes.FLASH_FWD: scopes.KERNEL_NAMES[1:],
+                scopes.MOE_GMM: scopes.MOE_KERNEL_NAMES[1:]}[kernel]
+    found = collections.Counter()
+    for scope in _OP_NAME.findall(text):
+        for name in forward + backward:
+            if f"/{name}/" not in scope:
+                continue
+            again = scopes.RECOMPUTE in scope
+            found[name, again, "transpose(" in scope] += 1
+            if again and name in forward:
+                assert remat_scopes.kernel_of("k.1 custom-call", scope) == name
+    for name in forward:
+        assert found[name, True, True], (name, found)      # the second launch
+        assert found[name, False, False], (name, found)    # the first
+        assert not found[name, True, False], found
+    for name in backward:
+        assert found[name, False, True], (name, found)
+        assert not found[name, True, True] and not found[name, True, False]
+    if kernel != scopes.MOE_GMM:
+        # launched in the backward pass only by remat
+        for name in forward:
+            assert not found[name, False, True], (name, found)
+    else:
+        # ``moe_gmm`` also takes the backward's d rows times the transpose
+        assert found[kernel, False, True], found
+
+
+def test_the_remat_reader_keeps_its_own_copy_of_the_names():
+    from benchmarks import remat_scopes
+
+    assert remat_scopes.RECOMPUTE == scopes.RECOMPUTE
+    assert remat_scopes.KERNELS == (
+        scopes.FLASH_FWD, scopes.SSD_FWD, scopes.CONV_FWD, scopes.MOE_GMM)
+    assert remat_scopes.kernel_of(
+        "fusion.3", "a/rematted_computation/x/flash_fwd/pallas_call") is None
+    assert remat_scopes.kernel_of(
+        "attn.7 custom-call", "a/x/flash_bwd_dq/pallas_call") is None
+
+
+def _hand_scoped_trace(ops):
+    """The scoped form (``scope_reduce.load_xplane``) of one chip running
+    ``ops`` [(event name, scope, ns)] one after another in the window."""
+    names = [["bench:window", ""]] + [[n, scope] for n, scope, _ in ops]
+    events, at = [], 1000
+    for i, (_, _, ns) in enumerate(ops):
+        events.append([i + 1, at, ns])
+        at += ns
+    return {"names": names, "planes": [
+        {"name": "/host:CPU", "lines": [
+            {"name": "main", "events": [[0, 0, at + 1000]]}]},
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": events}]},
+    ]}
+
+
+def test_remat_readers_read_the_recompute_and_are_silent_without_it():
+    from benchmarks import harness, remat_scopes
+
+    fwd = "jit(s)/jvp(M)/"
+    bwd = "jit(s)/transpose(jvp(M))/"
+    again = bwd + "jvp(M)/checkpoint/rematted_computation/"
+    flash = "attn/jit(_fwd_launch)/flash_fwd/pallas_call"
+    gmm = "mlp/moe_experts/jit(_gmm_launch)/moe_gmm/pallas_call"
+    trace = _hand_scoped_trace([
+        ("fusion.1", again + "layer_0/attn/q_proj/dot_general", 3_000_000),
+        ("fusion.2", again + "layer_0/mlp/up_proj/dot_general", 5_000_000),
+        ("attn.3 custom-call", again + "layer_0/" + flash, 2_000_000),
+        ("copy-done.4", again + "layer_0/" + flash, 500_000),
+        ("mlp.5 custom-call", again + "layer_1/" + gmm, 1_000_000),
+        # the first forward, the true backward: not recompute
+        ("attn.6 custom-call", fwd + "layer_0/" + flash, 1_900_000),
+        ("mlp.7 custom-call", fwd + "layer_1/" + gmm, 900_000),
+        ("mlp.8 custom-call", bwd + "layer_1/" + gmm, 1_500_000),
+        ("attn.9 custom-call", bwd + "layer_0/attn/flash_bwd_dq/pallas_call",
+         4_000_000),
+        ("fusion.10", fwd + "layer_0/mlp/up_proj/dot_general", 6_000_000),
+        ("fusion.11", bwd + "layer_0/mlp/up_proj/dot_general", 7_000_000),
+    ])
+    reduced = remat_scopes.reduce(trace, 1)
+    assert reduced["bucket_kind_s"] == {
+        "attn": {"fusion": 0.003},
+        "mlp": {"fusion": 0.005, "mlp custom-call": 0.001},
+        "attn_kernel.fwd": {"attn custom-call": 0.002, "copy-done": 0.0005},
+    }
+    assert reduced["kernel_s"] == {"flash_fwd": 0.002, "moe_gmm": 0.001}
+    assert reduced["first_kernel_s"] == {"flash_fwd": 0.0019, "moe_gmm": 0.0009}
+    assert "flash_fwd" in remat_scopes.table(reduced, steps=2)
+    ctx = {"remat_reduced": reduced, "measured": {"steps": 2}, "chips": 1}
+    metric = lambda name: harness.load_module("layer_metrics", name).read  # noqa: E731
+    assert metric("train_remat_ms")(ctx) == pytest.approx(5.75)
+    assert metric("train_remat_kernels_ms")(ctx) == pytest.approx(1.5)
+    # the same work counted by the accepted phase split: all of it bwd
+    phases = scope_reduce.reduce(trace, 1)["phase_s"]
+    assert phases["bwd"] == pytest.approx(0.024)
+    # a step without remat (cells 1 and 3): no number, no error
+    plain = _hand_scoped_trace([op for op in (
+        ("fusion.10", fwd + "layer_0/mlp/up_proj/dot_general", 6_000_000),
+        ("attn.6 custom-call", fwd + "layer_0/" + flash, 1_900_000))])
+    assert remat_scopes.reduce(plain, 1) is None
+    assert remat_scopes.reduce({"names": [], "planes": []}, 1) is None
+    silent = dict(ctx, remat_reduced=None)
+    for name in ("train_remat_ms", "train_remat_kernels_ms"):
+        assert metric(name)(silent) is None
+    # recompute with no kernel under it: the kernels' reader is silent
+    no_kernel = remat_scopes.reduce(_hand_scoped_trace([
+        ("fusion.1", again + "layer_0/attn/q_proj/dot_general", 3_000_000)]), 1)
+    assert metric("train_remat_kernels_ms")(
+        dict(ctx, remat_reduced=no_kernel)) is None
+    assert metric("train_remat_ms")(
+        dict(ctx, remat_reduced=no_kernel)) == pytest.approx(1.5)
 
 
 # ---------------------------------------------------------------- the tracer
