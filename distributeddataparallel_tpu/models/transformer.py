@@ -23,7 +23,9 @@ TPU-first choices:
   layer trace instead of L, an order-of-magnitude compile-time cut for the
   32-layer 8B config.
 - ``remat``: per-block ``nn.remat`` (checkpoint) trades recompute for HBM,
-  required to fit 8B pure-DP per chip (SURVEY.md §7 hard-part 3).
+  required to fit 8B pure-DP per chip (SURVEY.md §7 hard-part 3).  A
+  ``mamba`` block keeps what its mixer's two projections made
+  (``RematBlock``); every other block keeps only its input.
 - attention dispatches through ``ops.attention.attention`` (Pallas flash
   kernel on TPU when shapes allow, XLA reference otherwise).
 """
@@ -36,6 +38,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from distributeddataparallel_tpu.observability import scopes
 from distributeddataparallel_tpu.ops.attention import (
@@ -1036,7 +1039,9 @@ class Mamba2Mixer(nn.Module):
         )
         with jax.named_scope(scopes.SSM_IN_PROJ):
             # z | x B C | dt; the convolution reads its part in place
-            proj = dense(2 * inner + 2 * bc + H, "in_proj")(u)
+            proj = checkpoint_name(
+                dense(2 * inner + 2 * bc + H, "in_proj")(u), scopes.SSM_PROJ
+            )
             z, dt = proj[..., :inner], proj[..., 2 * inner + 2 * bc:]
         with jax.named_scope(scopes.SSM_CONV):
             taps = self.param(
@@ -1087,7 +1092,12 @@ class DecoderBlock(nn.Module):
 
         if self.kind == "mamba":
             y = _make_norm(cfg, "mamba_norm")(x)
-            x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
+            # the stream with the mixer's branch added, which the MLP's norm
+            # reads: kept under remat (``RematBlock``), so that ``out_proj``
+            # does not run again
+            x = checkpoint_name(
+                add(x, Mamba2Mixer(cfg, name="mamba")(y)), scopes.SSM_RESIDUAL
+            )
         else:
             y = _make_norm(cfg, "attn_norm")(x)
             x = add(x, after(Attention(cfg, self.kind, name="attn")(
@@ -1160,6 +1170,19 @@ class _ScanBlock(nn.Module):
             x, positions, rope, deterministic
         )
         return x, None
+
+
+#: ``DecoderBlock`` under ``nn.remat`` for an unrolled layer.  The policy
+#: keeps only values named in ``scopes.MIXER_SAVED``, which only a
+#: ``mamba`` block names: the mixer's input projection and the stream its
+#: output projection is added to (8,512 + 2,048 values a token at the
+#: published widths), so its second forward runs neither matmul again; the
+#: convolution, the scan, the norms and the MLP are still recomputed.  A
+#: block of any other kind names nothing and keeps only its inputs.
+RematBlock = nn.remat(
+    DecoderBlock, static_argnums=(4,),
+    policy=jax.checkpoint_policies.save_only_these_names(*scopes.MIXER_SAVED),
+)
 
 
 def scanned_layer_cls(cfg: TransformerConfig, length: int | None = None):
@@ -1290,11 +1313,7 @@ class TransformerLM(nn.Module):
                 x, positions, rope, deterministic
             )
         else:
-            block_cls = (
-                nn.remat(DecoderBlock, static_argnums=(4,))
-                if cfg.remat
-                else DecoderBlock
-            )
+            block_cls = RematBlock if cfg.remat else DecoderBlock
             kinds = cfg.layer_types or ("attention",) * cfg.num_layers
             if cfg.num_dense_layers and cfg.moe_experts == 0:
                 raise ValueError("num_dense_layers counts layers before experts")

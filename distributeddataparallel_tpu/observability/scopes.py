@@ -43,6 +43,15 @@ SSM_CONV = "ssm_conv"
 SSD = "ssd"
 SSM_GATE_NORM = "ssm_gate_norm"
 SSM_OUT_PROJ = "ssm_out_proj"
+#: ``jax.ad_checkpoint.checkpoint_name``s of what the mixer's two
+#: projections made: the input projection's ``z | x B C | dt`` and the
+#: residual stream with the output projection's result added (the fusion
+#: that ends ``out_proj`` writes that sum anyway; the MLP's norm reads it).
+#: Remat keeps exactly these (``transformer.RematBlock``), so a ``mamba``
+#: block's second forward runs neither matmul again.  Names of values, not
+#: scopes: nothing in the HLO carries them
+SSM_PROJ = "ssm_proj"
+SSM_RESIDUAL = "ssm_residual"
 #: the two ``pallas_call``s of ``ops/ssd.py``, launched under ``SSD``
 SSD_FWD = "ssd_fwd"
 SSD_BWD = "ssd_bwd"
@@ -101,6 +110,7 @@ RECOMPUTE = "rematted_computation"
 STEP_SCOPES = (EMBED, HEAD, LOSS, METRICS, GRAD_SYNC, GRAD_CLIP, OPTIMIZER)
 KERNEL_NAMES = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
 MIXER_SCOPES = (SSM_IN_PROJ, SSM_CONV, SSD, SSM_GATE_NORM, SSM_OUT_PROJ)
+MIXER_SAVED = (SSM_PROJ, SSM_RESIDUAL)
 SSD_KERNEL_NAMES = (SSD_FWD, SSD_BWD)
 CONV_KERNEL_NAMES = (CONV_FWD, CONV_BWD)
 MOE_KERNEL_NAMES = (MOE_GMM, MOE_TGMM)

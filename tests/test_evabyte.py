@@ -245,6 +245,45 @@ def test_multi_token_cross_entropy():
         multi_token_cross_entropy(logits, ids), np.mean(heads), rtol=1e-6)
 
 
+@pytest.mark.parametrize("family,data,held,total,dots,remats", [
+    ("trinity_mini", "tiny-afmoe.json", (0, 8), 4133, 219, 5),
+    ("evabyte", "tiny-evabyte.json", None, 2415, 175, 4),
+], ids=["trinity", "evabyte"])
+def test_remat_without_a_mixer_traces_the_parents_program(
+        family, data, held, total, dots, remats):
+    """The tiny trinity and EvaByte models under remat, forward and
+    backward, trace to the equation counts of the parent of the commit
+    that let ``mamba`` blocks keep their projections (all equations,
+    ``dot_general``s, ``remat2``s; counted there): the policy reaches no
+    block of another kind, and nothing is named."""
+    import collections
+
+    config = read(os.path.join(DATA, data))
+    extra = {"moe_experts_held": held} if held else {}
+    model = tfm.TransformerLM(getattr(tfm, family)(**{
+        **config["overrides"], "attn_impl": "xla", "remat": True, **extra}))
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    loss = lambda p: model.apply(p, tokens).astype(jnp.float32).mean()  # noqa: E731
+
+    def count(jaxpr, acc):
+        for eqn in jaxpr.eqns:
+            acc[eqn.primitive.name] += 1
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        count(inner, acc)
+        return acc
+
+    acc = count(jax.make_jaxpr(jax.value_and_grad(loss))(params).jaxpr,
+                collections.Counter())
+    assert (sum(acc.values()), acc["dot_general"], acc["remat2"]) == (
+        total, dots, remats)
+    assert acc["name"] == 0
+
+
 def test_what_the_new_kind_refuses():
     _, model = tiny_model(decode=True)
     with pytest.raises(ValueError, match="data-parallel training only"):

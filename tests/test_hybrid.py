@@ -303,6 +303,99 @@ def test_model_matches_the_plain_reference(remat, kernels):
         )
 
 
+#: the wrapper every block had before ``mamba`` blocks kept their
+#: projections: ``nn.remat`` with no policy, which keeps only inputs
+POLICY_FREE = nn.remat(tfm.DecoderBlock, static_argnums=(4,))
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr``, inner jaxprs' included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_a_mamba_blocks_remat_keeps_its_two_projections_alone(capsys):
+    """Under remat a ``mamba`` block keeps its inputs, the mixer's input
+    projection ``z | x B C | dt`` (B, S, 296) and the stream with the
+    output projection's result added (B, S, 64), and nothing else; its
+    backward's recompute (the ``remat2`` equation) runs two
+    ``dot_general``s fewer than the policy-free wrapper's.  The same
+    wrapper gives an attention block the policy-free wrapper's residuals
+    and recompute: nothing there carries a name."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from distributeddataparallel_tpu.observability import scopes
+
+    _, model = tiny_model()
+    cfg = model.cfg
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, cfg.d_model))
+
+    def residuals_and_recompute(cls, kind):
+        block = cls(cfg, kind)
+        params = block.init(jax.random.PRNGKey(1), x, None, None, True)
+        f = lambda p, x: block.apply(p, x, None, None, True)  # noqa: E731
+        print_saved_residuals(f, params, x)
+        saved = [line for line in capsys.readouterr().out.splitlines()
+                 if line and "from the argument" not in line]
+        grad = jax.make_jaxpr(jax.grad(lambda p: f(p, x).sum()))(params)
+        again = [
+            sum(e.primitive.name == "dot_general"
+                for e in _equations(eqn.params["jaxpr"]))
+            for eqn in grad.jaxpr.eqns if eqn.primitive.name == "remat2"]
+        named = [eqn.params["name"] for eqn in _equations(
+            jax.make_jaxpr(lambda p: f(p, x))(params).jaxpr)
+            if eqn.primitive.name == "name"]
+        return saved, again, named
+
+    saved, again, named = residuals_and_recompute(tfm.RematBlock, "mamba")
+    plain_saved, plain_again, _ = residuals_and_recompute(POLICY_FREE, "mamba")
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    width = 2 * inner + 2 * cfg.ssm_groups * cfg.ssm_state + cfg.ssm_heads
+    assert [line.split()[0] for line in saved] == [
+        f"f32[2,16,{width}]", f"f32[2,16,{cfg.d_model}]"], saved
+    assert "Mamba2Mixer" in saved[0] and "DecoderBlock" in saved[1], saved
+    assert plain_saved == []
+    assert named == list(scopes.MIXER_SAVED)
+    assert len(again) == len(plain_again) == 1
+    assert again[0] == plain_again[0] - 2, (again, plain_again)
+    # an attention block under the same wrapper is the policy-free one's
+    assert residuals_and_recompute(tfm.RematBlock, "attention") == (
+        residuals_and_recompute(POLICY_FREE, "attention"))
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["jnp", "kernels"])
+def test_keeping_the_projections_changes_no_number(kernels):
+    """The tiny hybrid (m m a m, f32) under remat: its loss and every leaf
+    of its gradient equal the policy-free remat's to 1e-6 — the backward
+    reads what the first forward's projections made instead of making it
+    again."""
+    _, model = tiny_model(remat=True)
+    params = tiny_weights(model)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 37), 0, 256)
+
+    def loss(p):
+        logp = jax.nn.log_softmax(model.apply({"params": p}, tokens[:, :-1]))
+        return -jnp.mean(
+            jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1))
+
+    with on_the_kernel_path() if kernels else contextlib.nullcontext():
+        kept = jax.value_and_grad(loss)(params)
+        with mock.patch.object(tfm, "RematBlock", POLICY_FREE):
+            plain = jax.value_and_grad(loss)(params)
+    np.testing.assert_allclose(kept[0], plain[0], rtol=0, atol=1e-6)
+    kept, plain = harness.flatten(kept[1]), harness.flatten(plain[1])
+    assert set(kept) == set(plain)
+    for leaf, want in plain.items():
+        assert float(jnp.abs(want).max()) > 0, leaf
+        np.testing.assert_allclose(kept[leaf], want, rtol=0, atol=1e-6,
+                                   err_msg=leaf)
+
+
 def test_layer_types_are_checked():
     with pytest.raises(ValueError, match="layer_types"):
         tfm.granite_4_0_h_micro(num_layers=3)

@@ -146,12 +146,27 @@ def test_every_scope_constant_falls_in_exactly_one_bucket():
     assert scope_reduce.phase_of("a/optimizer/x", "optimizer") == "update"
 
 
-def _tiny_hybrid_step_text(**overrides):
+def _tiny_hybrid_step_text(recompute_all=False, **overrides):
     """The compiled train step of a two-layer hybrid (``mamba``,
-    ``attention``) under remat, as HLO text."""
+    ``attention``) under remat, as HLO text.  ``recompute_all``: the
+    ``mamba`` block under the policy-free wrapper too, so that its second
+    forward runs every part again.  With the projections kept, the CPU's
+    XLA merges the second ``conv_fwd`` launch, which reads the same kept
+    ``proj``, into the first (the chip's compile keeps both:
+    ``tests/test_attention.py``)."""
+    from unittest import mock
+
+    import flax.linen as nn
+
+    from distributeddataparallel_tpu.models import transformer
     from distributeddataparallel_tpu.models.transformer import (
         granite_4_0_h_micro,
     )
+
+    if recompute_all:
+        with mock.patch.object(transformer, "RematBlock", nn.remat(
+                transformer.DecoderBlock, static_argnums=(4,))):
+            return _tiny_hybrid_step_text(**overrides)
 
     cfg = granite_4_0_h_micro(**{**dict(
         vocab_size=128, num_layers=2, layer_types=("mamba", "attention"),
@@ -228,7 +243,12 @@ def test_mixer_kernels_carry_their_parts_scope_in_both_phases(
     ``ssd_scan_roofline`` and ``train_ssm_conv_ms`` look for them — the
     forward in the forward pass (and again under remat), the backward
     under ``transpose(``.  The kernels are forced through the interpreter;
-    on the chip each is one custom call under the same name."""
+    on the chip each is one custom call under the same name.  The ``ssd``
+    case reads the step the model runs; the ``conv`` case the policy-free
+    wrapper's (``_tiny_hybrid_step_text``), and the model's own step on
+    the chip holds ``conv_fwd``'s second launches
+    (``tests/test_attention.py::
+    test_the_granite_step_holds_the_conv_kernels_and_no_copy_round_them``)."""
     import functools
     import importlib
     from unittest import mock
@@ -241,7 +261,7 @@ def test_mixer_kernels_carry_their_parts_scope_in_both_phases(
     with mock.patch.object(
         op, entry, functools.partial(getattr(op, entry), _interpret=True)
     ):
-        text = _tiny_hybrid_step_text()
+        text = _tiny_hybrid_step_text(recompute_all=module == "causal_conv")
     fwd, bwd = names
     found = collections.Counter()
     for scope in _OP_NAME.findall(text):
@@ -507,7 +527,11 @@ def test_three_pallas_calls_have_three_names():
 #: some recomputed operation must lie.  Of them only GPT-2 scans its
 #: layers (``scan_layers`` runs attention layers only)
 _REMAT_MODELS = {
-    "hybrid": ("attn", "mlp", "mamba") + scopes.MIXER_SCOPES,
+    # a ``mamba`` block keeps the stream its output projection is added
+    # to: nothing of ``out_proj`` is run again
+    # (``test_a_mamba_blocks_second_forward_runs_no_projection``)
+    "hybrid": ("attn", "mlp", "mamba") + tuple(
+        s for s in scopes.MIXER_SCOPES if s != scopes.SSM_OUT_PROJ),
     "afmoe": ("attn", "mlp") + scopes.MOE_SCOPES,
     "evabyte": ("attn", "mlp") + scopes.EVA_SCOPES,
     "gpt2.unrolled": ("attn", "mlp", r"layer_\d+"),
@@ -553,6 +577,21 @@ def test_remat_second_forward_carries_the_word_under_every_scope(
                    for s in found), (model, name)
 
 
+def test_a_mamba_blocks_second_forward_runs_no_projection(devices):
+    """Under remat a ``mamba`` block keeps what its mixer's two
+    projections made: no recomputed ``dot`` lies under ``ssm_in_proj`` or
+    ``ssm_out_proj``, while the MLP's and the attention's run again."""
+    recomputed = [
+        scope for line in _remat_step_text("hybrid", True).splitlines()
+        if " dot(" in line and scopes.RECOMPUTE in line
+        for scope in _OP_NAME.findall(line)]
+    assert not [s for s in recomputed
+                if f"/{scopes.SSM_IN_PROJ}/" in s
+                or f"/{scopes.SSM_OUT_PROJ}/" in s], recomputed
+    for under in ("/mlp/", "/attn/"):
+        assert any(under in s for s in recomputed), (under, recomputed)
+
+
 @pytest.mark.parametrize("model", list(_REMAT_MODELS))
 def test_no_operation_carries_the_word_without_remat(devices, model):
     text = _remat_step_text(model, False)
@@ -573,12 +612,16 @@ def _interpreted_kernels_text(kernel: str) -> str:
     )
 
     if kernel in (scopes.SSD_FWD, scopes.CONV_FWD):
+        # ``conv_fwd``'s second launch needs the policy-free wrapper on the
+        # CPU (``_tiny_hybrid_step_text``); ``ssd_fwd``'s is read on the
+        # step the model runs
         with mock.patch.object(ssd, "ssd_chunked", functools.partial(
                 ssd.ssd_chunked, _interpret=True)), \
                 mock.patch.object(causal_conv, "causal_conv_silu",
                                   functools.partial(causal_conv.causal_conv_silu,
                                                     _interpret=True)):
-            return _tiny_hybrid_step_text()
+            return _tiny_hybrid_step_text(
+                recompute_all=kernel == scopes.CONV_FWD)
     if kernel == scopes.MOE_GMM:
         # the kernels' row tile wants both weight axes a multiple of 128
         with mock.patch.object(grouped_matmul, "supported", lambda r, w: True), \
@@ -599,19 +642,21 @@ def _interpreted_kernels_text(kernel: str) -> str:
 
 
 @pytest.mark.parametrize("kernel", [
-    scopes.SSD_FWD, scopes.FLASH_FWD, scopes.MOE_GMM,
+    scopes.SSD_FWD, scopes.FLASH_FWD, scopes.MOE_GMM, scopes.CONV_FWD,
 ])
 def test_a_kernels_second_forward_launch_carries_the_word(devices, kernel):
     """The forward kernels' launches inside remat's second forward carry
     the word and their first launches do not; no backward kernel carries
-    it.  ``ssd_fwd`` and ``conv_fwd`` share one step (the hybrid's)."""
+    it.  ``ssd_fwd`` and ``conv_fwd`` are read on the hybrid's step; the
+    chip's compile of the granite step holds both second launches
+    (``tests/test_attention.py::
+    test_the_granite_step_holds_the_conv_kernels_and_no_copy_round_them``)."""
     from benchmarks import remat_scopes
 
     text = _interpreted_kernels_text(kernel)
-    forward = (scopes.SSD_FWD, scopes.CONV_FWD) if kernel == scopes.SSD_FWD \
-        else (kernel,)
-    backward = {scopes.SSD_FWD: scopes.SSD_KERNEL_NAMES[1:]
-                + scopes.CONV_KERNEL_NAMES[1:],
+    forward = (kernel,)
+    backward = {scopes.SSD_FWD: scopes.SSD_KERNEL_NAMES[1:],
+                scopes.CONV_FWD: scopes.CONV_KERNEL_NAMES[1:],
                 scopes.FLASH_FWD: scopes.KERNEL_NAMES[1:],
                 scopes.MOE_GMM: scopes.MOE_KERNEL_NAMES[1:]}[kernel]
     found = collections.Counter()
